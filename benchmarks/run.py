@@ -23,6 +23,9 @@ def main() -> None:
                             pagerank_engine_bench, precision_bench,
                             resilience_bench, roofline, serve_bench,
                             table1_design)
+    from repro.launch.compile_cache import use_compile_cache
+
+    print(f"# compile cache: {use_compile_cache()}")
 
     smoke = "--smoke" in sys.argv
     quick = "--quick" in sys.argv or smoke

@@ -1,6 +1,6 @@
-"""Pod-scale distributed PageRank through the one engine front door — the
-paper's fabric schedule as real collectives, on 16 simulated devices (the
-same code path the 512-chip dry-run compiles).
+"""Distributed PageRank through the one engine front door — the paper's
+fabric schedule as real collectives over every device the process sees,
+arranged as a near-square 2-D mesh (2x2 on a four-chip host).
 
 The vertical bus is the ``P('model')`` layout of the rank vector, the
 horizontal bus is the ``psum`` over the mesh row, and the adder-column
@@ -10,16 +10,16 @@ whole thing is a :class:`~repro.pagerank.engine.PageRankEngine` backend:
 compiles the 100-iteration schedule into a single dispatch; the same
 engine serves query-sharded batched PPR to ``PageRankQueryEngine``.
 
-Run:  PYTHONPATH=src python examples/distributed_pagerank.py
+Run on a multi-chip host:  PYTHONPATH=src python examples/distributed_pagerank.py
+On the CPU, give it virtual devices first:
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/distributed_pagerank.py
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-
 import time
 
+import jax
 import numpy as np
 
-from repro.launch.mesh import make_mesh
 from repro.graph import generators as gen
 from repro.pagerank import PageRankEngine
 from repro.serve import PageRankQueryEngine
@@ -27,13 +27,13 @@ from repro.serve import PageRankQueryEngine
 
 def main() -> None:
     n, iters = 1024, 100
-    mesh = make_mesh((4, 4), ("data", "model"))
-    print(f"mesh: {mesh.shape} over {mesh.size} devices")
-
     src, dst = gen.protein_network(n, seed=3)
-    eng = PageRankEngine(src, dst, n, backend="dense_sharded", mesh=mesh)
+    # no mesh given: the engine lays every visible device out as a
+    # near-square (row, col) mesh
+    eng = PageRankEngine(src, dst, n, backend="dense_sharded")
+    print(f"mesh: {dict(eng.mesh.shape)} over {eng.mesh.size} devices")
     H_sharded = eng.operands[0]
-    print(f"H: {H_sharded.shape} sharded P('data','model') -> "
+    print(f"H: {H_sharded.shape} sharded P('row','col') -> "
           f"{H_sharded.sharding.shard_shape(H_sharded.shape)} per device "
           f"[{eng.layout}]")
 
@@ -48,7 +48,7 @@ def main() -> None:
     txt = eng.lower_run(n_iters=iters).compile().as_text()
     n_ar = txt.count(" all-reduce(") + txt.count(" all-reduce-start(")
     print(f"{iters} fabric-schedule iterations: {dt * 1e3:.1f} ms "
-          f"(16 simulated devices, CPU)")
+          f"({jax.device_count()} {jax.devices()[0].platform} devices)")
     print(f"collectives in compiled HLO: all-reduce x{n_ar} "
           f"(horizontal bus + diagonal re-injection)")
     print(f"distributed == single-device reference: OK")

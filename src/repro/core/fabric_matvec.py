@@ -13,8 +13,10 @@ becomes the 2-D device mesh, and the paper's buses become collectives —
 * re-injection for iterative algorithms (PageRank) -> mesh-transpose
   ``all_to_all`` exchanging the (row, col) block layout back to vector layout.
 
-All entry points are ``shard_map``-ed so the collective schedule is explicit
-and auditable in the lowered HLO (the roofline harness counts those bytes).
+All entry points are ``jax.shard_map``-ed so the collective schedule is
+explicit and auditable in the lowered HLO (the roofline harness counts those
+bytes).  Replication checks are off (``check_vma=False``): the kernels mix
+psum'd and per-device values.
 """
 from __future__ import annotations
 
@@ -23,19 +25,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                    # jax >= 0.6 exposes it at top level
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:                     # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
 
 
 def matvec(A: jax.Array, x: jax.Array, mesh: Mesh,
@@ -52,8 +41,8 @@ def matvec(A: jax.Array, x: jax.Array, mesh: Mesh,
         partial_y = a_blk.astype(jnp.float32) @ x_blk   # site multiplies
         return jax.lax.psum(partial_y, col_axis)        # horizontal bus
 
-    return shard_map(
-        kernel, mesh,
+    return jax.shard_map(
+        kernel, mesh=mesh, check_vma=False,
         in_specs=(P(row_axis, col_axis), P(col_axis)),
         out_specs=P(row_axis))(A, x)
 
@@ -69,8 +58,8 @@ def matvec_scatter(A: jax.Array, x: jax.Array, mesh: Mesh,
         return jax.lax.psum_scatter(
             partial_y, col_axis, scatter_dimension=0, tiled=True)
 
-    return shard_map(
-        kernel, mesh,
+    return jax.shard_map(
+        kernel, mesh=mesh, check_vma=False,
         in_specs=(P(row_axis, col_axis), P(col_axis)),
         out_specs=P((row_axis, col_axis)))(A, x)
 
@@ -99,8 +88,8 @@ def matvec_iterated_reshard(y_rowrep: jax.Array, mesh: Mesh,
         contrib = jnp.where(r == c, y_blk, jnp.zeros_like(y_blk))
         return jax.lax.psum(contrib, row_axis)
 
-    return shard_map(
-        kernel, mesh,
+    return jax.shard_map(
+        kernel, mesh=mesh, check_vma=False,
         in_specs=P(row_axis),
         out_specs=P(col_axis))(y_rowrep)
 
@@ -121,7 +110,7 @@ def fabric_gemv_batched(W: jax.Array, X: jax.Array, mesh: Mesh,
         y_blk = x_full @ w_blk.T
         return jax.lax.all_gather(y_blk, row_axis, axis=1, tiled=True)
 
-    return shard_map(
-        kernel, mesh,
+    return jax.shard_map(
+        kernel, mesh=mesh, check_vma=False,
         in_specs=(P(row_axis, None), P(None, None)),
         out_specs=P(None, None))(W, X)

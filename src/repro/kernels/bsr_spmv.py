@@ -13,8 +13,13 @@ the *index data* steers the dataflow, no host intervention.
 Layout (built by ``graph.sparse.BSRMatrix``):
   ``blocks``     (nb_r, mb, bs, bs) f32 — zero-padded block budget
   ``block_cols`` (nb_r, mb) i32        — padded entries -> block-col 0, zero block
-  ``x``          (nb_c * bs,)          -> reshaped (nb_c, bs)
-  ``y``          (nb_r * bs,)
+  ``x``          (nb_c * bs,)          -> viewed (nb_c, 1, bs)
+  ``y``          (nb_r * bs,)          <- computed (nb_r, 1, bs)
+
+The vectors carry a unit middle axis so every block is ``(1, 1, bs)``: its
+last two dimensions are the array's full size-1 axis and a multiple of 128,
+the tiling Mosaic accepts (a ``(1, bs)`` block of an ``(nb, bs)`` array is
+not).
 """
 from __future__ import annotations
 
@@ -25,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import F32_DOT
+
 
 def _kernel(cols_ref, blk_ref, x_ref, y_ref):
     j = pl.program_id(1)
@@ -33,11 +40,14 @@ def _kernel(cols_ref, blk_ref, x_ref, y_ref):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    # (bs, bs) @ (bs,) -> (bs,); padded blocks are all-zero => safe
+    # (1, bs) x (bs, bs)^T -> (1, bs); padded blocks are all-zero => safe
     # accumulate.  Blocks may be stored reduced-precision (bf16/f16/int8):
     # upcast in-register, accumulate f32 (no-op on f32 blocks).
-    y_ref[0, :] += jnp.dot(blk_ref[0, 0].astype(jnp.float32), x_ref[0, :],
-                           preferred_element_type=jnp.float32)
+    y_ref[0] += jax.lax.dot_general(
+        x_ref[0], blk_ref[0, 0].astype(jnp.float32),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=F32_DOT,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -49,21 +59,21 @@ def bsr_spmv(blocks: jax.Array, block_cols: jax.Array, x: jax.Array, *,
     xp = x
     if x.shape[0] % bs:
         xp = jnp.pad(x, (0, bs - x.shape[0] % bs))
-    xb = xp.reshape(-1, bs)
+    xb = xp.reshape(-1, 1, bs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb_r, mb),
         in_specs=[
             pl.BlockSpec((1, 1, bs, bs), lambda i, j, cols: (i, j, 0, 0)),
-            pl.BlockSpec((1, bs), lambda i, j, cols: (cols[i, j], 0)),
+            pl.BlockSpec((1, 1, bs), lambda i, j, cols: (cols[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bs), lambda i, j, cols: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, bs), lambda i, j, cols: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb_r, bs), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb_r, 1, bs), jnp.float32),
         interpret=interpret,
     )(block_cols, blocks, xb)
     return out.reshape(nb_r * bs)

@@ -1,15 +1,12 @@
 """Public jit'd entry points for the kernel layer.
 
 ``interpret`` is resolved **per call** by :func:`default_interpret`: the
-Pallas kernels compile to Mosaic on TPU and fall back to interpret mode
+Pallas kernels compile to Mosaic on TPU and run in interpret mode
 everywhere else, so the same process can mix compiled and interpreted
-paths (e.g. a TPU engine next to a CPU unit test).  Set
-``REPRO_PALLAS_INTERPRET=0``/``1`` to force either mode globally, or pass
-``interpret=`` explicitly.
+paths (e.g. a TPU engine next to a CPU unit test).  Pass ``interpret=``
+explicitly to choose a mode.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,11 +19,8 @@ from repro.kernels.streaming_matvec import streaming_matvec
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default for this call: env override, else derived
-    from the active device (compiled only on TPU)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
+    """Interpret-mode default for this call, derived from the active
+    device: compiled on TPU, interpreted everywhere else."""
     return jax.default_backend() != "tpu"
 
 

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import F32_DOT
+
 
 def _kernel(t_ref, h_ref, x_ref, y_ref, *, d: float, m_steps: int):
     j = pl.program_id(1)
@@ -44,6 +46,7 @@ def _kernel(t_ref, h_ref, x_ref, y_ref, *, d: float, m_steps: int):
     y_ref[...] += jax.lax.dot_general(
         x_ref[...], h_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32)
 
     @pl.when(j == m_steps - 1)
@@ -108,6 +111,7 @@ def _fused_kernel(t_ref, h_ref, x_ref, dang_ref, *rest,
     y_ref[...] += jax.lax.dot_general(
         x_ref[...], h_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32)
 
     @pl.when(j == m_steps - 1)
@@ -121,8 +125,9 @@ def _fused_kernel(t_ref, h_ref, x_ref, dang_ref, *rest,
         y_ref[...] = y
         # dangling-leak reduction over the *new* rank block, while the
         # block is still resident — the second pass ops.pagerank_iteration
-        # pays per step happens here for free.
-        leak_ref[0, 0] += jnp.sum(y * dang_ref[...])
+        # pays per step happens here for free.  Accumulated as a (1, 1)
+        # vector: Mosaic cannot store a scalar into VMEM.
+        leak_ref[...] += jnp.sum(y * dang_ref[...], keepdims=True)
 
 
 @functools.partial(jax.jit,

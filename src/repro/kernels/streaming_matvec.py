@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import F32_DOT
+
 
 def _kernel(x_ref, w_ref, y_ref, *, n_steps_m: int):
     j = pl.program_id(1)
@@ -39,6 +41,7 @@ def _kernel(x_ref, w_ref, y_ref, *, n_steps_m: int):
     y_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 

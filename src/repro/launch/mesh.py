@@ -7,17 +7,10 @@ never touches jax device state — smoke tests see 1 device; only
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: make_mesh has no
-    AxisType = None                     # axis_types parameter
+from jax.sharding import AxisType, Mesh
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

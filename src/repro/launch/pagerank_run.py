@@ -27,6 +27,7 @@ from repro.configs.pagerank_5k import full as pagerank_cfg
 from repro.core import timing
 from repro.graph import generators as gen
 from repro.graph import transition as tr
+from repro.launch.compile_cache import use_compile_cache
 from repro.pagerank import PageRankEngine
 from repro.pagerank.sparse import top_k_proteins
 
@@ -52,6 +53,7 @@ def run(argv=None):
     args = ap.parse_args(argv)
 
     n, iters, d = args.nodes, args.iters, args.damping
+    print(f"compile cache: {use_compile_cache()}")
     n_dev = jax.device_count()
     print(f"protein network: {n} nodes (BA scale-free + noise), "
           f"{iters} iterations, d={d}, {n_dev} device(s)")

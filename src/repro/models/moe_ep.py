@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.fabric_matvec import shard_map
 from repro.sharding.partition import current_mesh, current_rules
 
 
@@ -164,7 +163,9 @@ def moe_ep(params, x: jax.Array, cfg: ModelConfig):
         y = jax.lax.psum(y, "model")
         return y.reshape(x_blk.shape).astype(dtype), aux, dropped
 
-    y, aux, dropped = shard_map(body, mesh, in_specs, out_specs)(
+    y, aux, dropped = jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)(
         x, router, wi_g, wi_u, wo)
     return y, {"aux_loss": aux, "dropped_frac": dropped}
 

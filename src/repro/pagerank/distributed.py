@@ -1,9 +1,8 @@
 """Pod-scale distributed PageRank — the paper's workload on the TPU mesh.
 
 Two production layouts, each in a fixed-schedule and a tolerance-terminated
-variant, plus the query-sharded batched-PPR schedules that back the
-``dense_sharded`` / ``ell_sharded`` tiers of
-:class:`repro.pagerank.engine.PageRankEngine`:
+variant, plus the batched-PPR schedules that back the ``dense_sharded`` /
+``ell_sharded`` tiers of :class:`repro.pagerank.engine.PageRankEngine`:
 
 * :func:`pagerank_distributed` / :func:`pagerank_distributed_tol` — dense H
   sharded ``P(row, col)`` over the 2-D mesh, iterating the paper's fabric
@@ -12,10 +11,12 @@ variant, plus the query-sharded batched-PPR schedules that back the
   Fig. 3/Fig. 4 and what the dry-run lowers for the ``pagerank_65k`` config.
 
 * :func:`pagerank_distributed_sparse` /
-  :func:`pagerank_distributed_sparse_tol` — ELL rows sharded over the
-  flattened mesh (1-D row distribution), rank vector replicated, one
-  ``all_gather`` per iteration.  This is the realistic layout for sparse
-  interactomes where N >> nnz/N.
+  :func:`pagerank_distributed_sparse_tol` — a sliced-ELL layout
+  (:mod:`repro.pagerank.sell`) row-sharded over the flattened mesh (1-D
+  row distribution), rank vector replicated, one ``all_gather`` per
+  iteration.  This is the realistic layout for sparse interactomes where
+  N >> nnz/N: each device sweeps its own rows in degree tiers, so no row
+  is padded to the maximum degree.
 
 * :func:`push_distributed_tol` / :func:`push_distributed_sparse_tol` — the
   Gauss–Southwell frontier push of the dynamic-refresh path run shard-local
@@ -26,11 +27,12 @@ variant, plus the query-sharded batched-PPR schedules that back the
   collective at all).
 
 * :func:`ppr_distributed_dense` / :func:`ppr_distributed_sparse` — the
-  batched (N, Q) personalized-PageRank matrix sharded over the **query**
-  axis, so a multi-user serve batch spreads across the mesh; the dense
-  variant also row-parallelizes the sweep (one row-axis ``all_gather`` per
-  iteration), the sparse variant replicates the small ELL operands and runs
-  with zero per-iteration collectives.
+  batched (N, Q) personalized-PageRank matrix.  The dense variant shards
+  it over the **query** axis and row-parallelizes the sweep (one row-axis
+  ``all_gather`` per iteration); the sparse variant leaves the layout
+  row-sharded where it is, sweeps every query over each device's own rows
+  and re-assembles the (N, Q) image with one ``all_gather`` per iteration
+  (:func:`sell_mv_sharded`), so no device holds the whole layout.
 
 Uneven shapes are handled by zero-padding: every entry point takes
 ``n_true`` (the real node count) and keeps the PageRank arithmetic —
@@ -48,9 +50,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import fabric_matvec as fm
-from repro.core.fabric_matvec import shard_map
 from repro.obs.trace import instrumented_tol_loop
+from repro.pagerank.sell import n_rows, sell_rows
 from repro.pagerank.steps import ppr_step_batched
+
+
+def _shard_map(kernel, mesh: Mesh, in_specs, out_specs):
+    # replication checks off: the kernels mix all_gather'd and per-device
+    # values
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _pr0(n: int, n_true: int, dtype=jnp.float32) -> jax.Array:
@@ -152,73 +161,58 @@ def pagerank_distributed_tol(H: jax.Array, mesh: Mesh, tol: float = 1e-6,
 
 # --------------------------------------------------------------------------- #
 # sparse row-sharded schedule (flattened mesh)                                #
+#                                                                             #
+# ``layout`` is a SELL operand pytree built with ``shards`` = the mesh size   #
+# (repro.pagerank.sell): every leaf is row-sharded ``P(axes)``, and each      #
+# device's block is a self-contained SELL of its own rows.                    #
 # --------------------------------------------------------------------------- #
-def _ell_block_iter(data_blk, idx_blk, pr, dang_full, axes, d, nt,
-                    scale_blk=None):
-    """Canonical row-sharded ELL iteration (local rows -> leak -> damp ->
-    tiled all_gather), shared by the fixed and tolerance variants.
-    ``data_blk`` may be stored reduced-precision — products and the rowwise
-    reduce run in f32 (a no-op upcast on f32 data); ``scale_blk`` is the
-    optional row-sharded per-row f32 dequantization vector of an int8
-    layout, folded into the local row sums before damping."""
-    y_blk = jnp.sum(data_blk.astype(jnp.float32) * pr[idx_blk], axis=1)
-    if scale_blk is not None:
-        y_blk = y_blk * scale_blk
+def _sell_block_iter(layout_blk, pr, dang_full, axes, d, nt):
+    """Canonical row-sharded iteration (local rows -> leak -> damp ->
+    tiled all_gather), shared by the fixed and tolerance variants and the
+    shard-local push.  Reduced-precision tiers are upcast in the row sums,
+    where an int8 layout's per-row scales are folded in too."""
+    y_blk = sell_rows(layout_blk, pr)
     leak = jnp.sum(pr * dang_full) / nt
     y_blk = d * (y_blk + leak) + (1.0 - d) / nt
     return jax.lax.all_gather(y_blk, axes, tiled=True)
 
 
-def pagerank_distributed_sparse(ell_data: jax.Array, ell_idx: jax.Array,
-                                mesh: Mesh, n_iters: int = 100,
+def _dangling(dangling: jax.Array | None, n: int) -> jax.Array:
+    return (jnp.zeros((n,), jnp.float32) if dangling is None
+            else jnp.asarray(dangling, jnp.float32))
+
+
+def pagerank_distributed_sparse(layout, mesh: Mesh, n_iters: int = 100,
                                 d: float = 0.85,
                                 dangling: jax.Array | None = None,
                                 axes: tuple[str, ...] = ("data", "model"),
-                                n_true: int | None = None,
-                                scales: jax.Array | None = None
-                                ) -> jax.Array:
-    """Row-sharded ELL PageRank.  ``ell_data``/``ell_idx``: (N, K) sharded
-    over rows on the flattened mesh axes; PR replicated.  One tiled
-    ``all_gather`` of the fresh row-shards per iteration.  ``scales``: an
-    int8 layout's (N,) per-row dequantization vector, row-sharded like the
-    ELL operands."""
-    n = ell_data.shape[0]
+                                n_true: int | None = None) -> jax.Array:
+    """Row-sharded SELL PageRank; PR replicated.  One tiled ``all_gather``
+    of the fresh row-shards per iteration."""
+    n = n_rows(layout)
     nt = int(n if n_true is None else n_true)
-    dang = (jnp.zeros((n,), jnp.float32) if dangling is None
-            else jnp.asarray(dangling, jnp.float32))
 
-    def kernel(data_blk, idx_blk, dang_full, *rest):
-        scale_blk = rest[0] if rest else None
-
+    def kernel(layout_blk, dang_full):
         def one_iter(pr, _):
-            return _ell_block_iter(data_blk, idx_blk, pr, dang_full,
-                                   axes, d, nt, scale_blk), None
+            return _sell_block_iter(layout_blk, pr, dang_full, axes, d,
+                                    nt), None
 
         pr, _ = jax.lax.scan(one_iter, _pr0(n, nt), None, length=n_iters)
         return pr
 
-    in_specs = (P(axes), P(axes), P())
-    operands = (ell_data, ell_idx, dang)
-    if scales is not None:
-        in_specs += (P(axes),)
-        operands += (scales,)
-    return shard_map(
-        kernel, mesh,
-        in_specs=in_specs,
-        out_specs=P())(*operands)
+    return _shard_map(kernel, mesh, in_specs=(P(axes), P()),
+                      out_specs=P())(layout, _dangling(dangling, n))
 
 
-def pagerank_distributed_sparse_tol(ell_data: jax.Array, ell_idx: jax.Array,
-                                    mesh: Mesh, tol: float = 1e-6,
+def pagerank_distributed_sparse_tol(layout, mesh: Mesh, tol: float = 1e-6,
                                     max_iters: int = 1000, d: float = 0.85,
                                     dangling: jax.Array | None = None,
                                     axes: tuple[str, ...] = ("data", "model"),
                                     n_true: int | None = None,
                                     x0: jax.Array | None = None,
                                     watchdog: bool = True,
-                                    trace: bool = False,
-                                    scales: jax.Array | None = None):
-    """Tolerance-terminated row-sharded ELL PageRank.  After each
+                                    trace: bool = False):
+    """Tolerance-terminated row-sharded SELL PageRank.  After each
     iteration's ``all_gather`` every device holds the full fresh vector, so
     the residual (and the exit decision — including the convergence
     watchdog's abort on NaN/Inf or sustained residual growth, armed by
@@ -230,19 +224,15 @@ def pagerank_distributed_sparse_tol(ell_data: jax.Array, ell_idx: jax.Array,
     across devices).  ``x0`` (padded to N, zeros on the pad tail)
     warm-starts the loop; it rides into the kernel as a replicated operand
     like the dangling mask."""
-    n = ell_data.shape[0]
+    n = n_rows(layout)
     nt = int(n if n_true is None else n_true)
-    dang = (jnp.zeros((n,), jnp.float32) if dangling is None
-            else jnp.asarray(dangling, jnp.float32))
     pr0 = _pr0(n, nt) if x0 is None else jnp.asarray(x0, jnp.float32)
 
-    def kernel(data_blk, idx_blk, dang_full, pr0_full, *rest):
-        scale_blk = rest[0] if rest else None
+    def kernel(layout_blk, dang_full, pr0_full):
         mask = _real_mask(n, nt)
 
         def step(pr):
-            new = _ell_block_iter(data_blk, idx_blk, pr, dang_full,
-                                  axes, d, nt, scale_blk)
+            new = _sell_block_iter(layout_blk, pr, dang_full, axes, d, nt)
             return new, jnp.sum(jnp.abs(new - pr) * mask)
 
         pr, iters, res, grow, ring = instrumented_tol_loop(
@@ -251,15 +241,9 @@ def pagerank_distributed_sparse_tol(ell_data: jax.Array, ell_idx: jax.Array,
         return ((pr, iters, res, grow, ring) if trace
                 else (pr, iters, res, grow))
 
-    in_specs = (P(axes), P(axes), P(), P())
-    operands = (ell_data, ell_idx, dang, pr0)
-    if scales is not None:
-        in_specs += (P(axes),)
-        operands += (scales,)
-    out = shard_map(
-        kernel, mesh,
-        in_specs=in_specs,
-        out_specs=(P(),) * (5 if trace else 4))(*operands)
+    out = _shard_map(kernel, mesh, in_specs=(P(axes), P(), P()),
+                     out_specs=(P(),) * (5 if trace else 4))(
+        layout, _dangling(dangling, n), pr0)
     return out if trace else (*out, None)
 
 
@@ -310,37 +294,31 @@ def push_distributed_tol(H: jax.Array, mesh: Mesh, x0: jax.Array,
     return x, sweeps, res, grow, ring
 
 
-def push_distributed_sparse_tol(ell_data: jax.Array, ell_idx: jax.Array,
-                                mesh: Mesh, x0: jax.Array, tol: float = 1e-6,
-                                max_pushes: int = 1000, d: float = 0.85,
+def push_distributed_sparse_tol(layout, mesh: Mesh, x0: jax.Array,
+                                tol: float = 1e-6, max_pushes: int = 1000,
+                                d: float = 0.85,
                                 dangling: jax.Array | None = None,
                                 axes: tuple[str, ...] = ("data", "model"),
                                 n_true: int | None = None,
-                                watchdog: bool = True, trace: bool = False,
-                                scales: jax.Array | None = None):
-    """Frontier push on the row-sharded ELL layout, as a ``shard_map``
+                                watchdog: bool = True, trace: bool = False):
+    """Frontier push on the row-sharded SELL layout, as a ``shard_map``
     kernel mirroring :func:`pagerank_distributed_sparse_tol`: each device
     sweeps its own row block and the per-sweep ``all_gather`` re-assembles
     the fresh operator image — after which the residual (and the frontier
     mask, the watchdog verdict and the while_loop exit) is computed
     identically on every device from the replicated vector, with no extra
-    collective.  Pad rows have zero ELL data and a zero x0 tail, so their
+    collective.  Pad rows have zero data and a zero x0 tail, so their
     masked residual is identically zero and the frontier never touches
     them.  Returns ``(x, sweeps, residual, grow, ring)``."""
-    n = ell_data.shape[0]
+    n = n_rows(layout)
     nt = int(n if n_true is None else n_true)
-    dang = (jnp.zeros((n,), jnp.float32) if dangling is None
-            else jnp.asarray(dangling, jnp.float32))
-    x0 = jnp.asarray(x0, jnp.float32)
 
-    def kernel(data_blk, idx_blk, dang_full, x0_full, *rest):
-        scale_blk = rest[0] if rest else None
+    def kernel(layout_blk, dang_full, x0_full):
         mask = _real_mask(n, nt)
         thresh = jnp.float32(tol) / nt
 
         def residual(x):
-            new = _ell_block_iter(data_blk, idx_blk, x, dang_full, axes,
-                                  d, nt, scale_blk)
+            new = _sell_block_iter(layout_blk, x, dang_full, axes, d, nt)
             return (new - x) * mask
 
         def step(state):
@@ -356,20 +334,14 @@ def push_distributed_sparse_tol(ell_data: jax.Array, ell_idx: jax.Array,
         return ((x, sweeps, res, grow, ring) if trace
                 else (x, sweeps, res, grow))
 
-    in_specs = (P(axes), P(axes), P(), P())
-    operands = (ell_data, ell_idx, dang, x0)
-    if scales is not None:
-        in_specs += (P(axes),)
-        operands += (scales,)
-    out = shard_map(
-        kernel, mesh,
-        in_specs=in_specs,
-        out_specs=(P(),) * (5 if trace else 4))(*operands)
+    out = _shard_map(kernel, mesh, in_specs=(P(axes), P(), P()),
+                     out_specs=(P(),) * (5 if trace else 4))(
+        layout, _dangling(dangling, n), jnp.asarray(x0, jnp.float32))
     return out if trace else (*out, None)
 
 
 # --------------------------------------------------------------------------- #
-# query-sharded batched personalized PageRank                                 #
+# batched personalized PageRank                                               #
 # --------------------------------------------------------------------------- #
 def ppr_distributed_dense(H: jax.Array, dang: jax.Array, V: jax.Array,
                           mesh: Mesh, n_iters: int = 100, d: float = 0.85,
@@ -405,48 +377,38 @@ def ppr_distributed_dense(H: jax.Array, dang: jax.Array, V: jax.Array,
     if scales is not None:
         in_specs += (P(row_axis),)
         operands += (scales,)
-    return shard_map(
-        kernel, mesh,
-        in_specs=in_specs,
-        out_specs=P(None, col_axis))(*operands)
+    return _shard_map(kernel, mesh, in_specs=in_specs,
+                      out_specs=P(None, col_axis))(*operands)
 
 
-def ppr_distributed_sparse(ell_data: jax.Array, ell_idx: jax.Array,
-                           dang: jax.Array, V: jax.Array, mesh: Mesh,
-                           n_iters: int = 100, d: float = 0.85,
-                           axes: tuple[str, ...] = ("data", "model"),
-                           scales: jax.Array | None = None) -> jax.Array:
-    """Batched PPR over replicated ELL operands, (N, Q) sharded over the
-    query axis on the flattened mesh — each device propagates its own query
-    block end-to-end with zero per-iteration collectives (the ELL operands
-    of a sparse interactome are small enough to replicate; the dense-H
-    variant above is the one that shards the sweep itself)."""
+def sell_mv_sharded(layout, X: jax.Array, mesh: Mesh,
+                    axes: tuple[str, ...]) -> jax.Array:
+    """``H @ X`` over a row-sharded SELL layout for a replicated (N, Q)
+    batch: each device sweeps its own rows for every query, and one tiled
+    ``all_gather`` re-assembles the replicated (N, Q) image.  Callable
+    inside ``jit`` — the batched PPR below and the landmark hub push
+    (:mod:`repro.pagerank.landmarks`) both iterate it."""
+    return _shard_map(
+        lambda blk, X: jax.lax.all_gather(sell_rows(blk, X), axes,
+                                          tiled=True),
+        mesh, in_specs=(P(axes), P()), out_specs=P())(layout, X)
 
-    def kernel(data_full, idx_full, dang_full, v_blk, *rest):
-        scale_full = rest[0] if rest else None
 
-        def mv(PR):                     # ELL matmat, fully local
-            y = jnp.sum(data_full.astype(jnp.float32)[..., None]
-                        * PR[idx_full], axis=1)
-            if scale_full is not None:
-                y = y * scale_full[:, None]
-            return y
+def ppr_distributed_sparse(layout, dang: jax.Array, V: jax.Array,
+                           mesh: Mesh, n_iters: int = 100, d: float = 0.85,
+                           axes: tuple[str, ...] = ("data", "model")
+                           ) -> jax.Array:
+    """Batched PPR over the row-sharded SELL layout: the (N, Q) iterate is
+    replicated, and every iteration each device sweeps its own rows for
+    all Q queries (:func:`sell_mv_sharded`).  The layout is never copied:
+    each device holds its 1/D share of it, as the global solve does."""
+    def one_iter(PR, _):
+        return ppr_step_batched(lambda X: sell_mv_sharded(layout, X, mesh,
+                                                          axes),
+                                PR, V, dang, d), None
 
-        def one_iter(pr_blk, _):
-            return ppr_step_batched(mv, pr_blk, v_blk, dang_full, d), None
-
-        pr, _ = jax.lax.scan(one_iter, v_blk, None, length=n_iters)
-        return pr
-
-    in_specs = (P(), P(), P(), P(None, axes))
-    operands = (ell_data, ell_idx, dang, V)
-    if scales is not None:
-        in_specs += (P(),)
-        operands += (scales,)
-    return shard_map(
-        kernel, mesh,
-        in_specs=in_specs,
-        out_specs=P(None, axes))(*operands)
+    PR, _ = jax.lax.scan(one_iter, V, None, length=n_iters)
+    return PR
 
 
 def make_sharded_inputs_dense(H, mesh: Mesh, row_axis="data",

@@ -31,20 +31,16 @@ the prepared arrays, never a rebuild:
   recomputed host-side and written with one ``H.at[:, cols].set`` scatter
   (the pre-padded Pallas layout keeps its padding; the dangling row mask is
   patched alongside).
-* **ell** — the dynamic ELL tier is a two-bucket *sliced* ELLPACK (SELL):
-  rows are permuted into a low tier (per-row budget ``k_low`` ≈ the 90th
-  degree percentile + slack) and a hub tier (``k_high`` = max degree +
-  slack), so the sweep is two dense gathers and **no** ``segment_sum`` —
-  measurably faster per iteration than the static engine's split layout —
-  and every affected row is rewritten with one row-scatter per tier.  The
-  capacity slack means small deltas never change any array shape; a row
-  outgrowing its tier triggers the rebuild fallback.
-* **ell_sharded** — the full-K row layout is built with ``maxdeg + slack``
-  columns of headroom, and every affected row is rewritten shard-local: the
-  row scatter lands on whichever device owns the row under the existing
-  ``NamedSharding`` (a ``with_sharding_constraint`` on the scatter output
-  keeps XLA from resharding), the replicated dangling mask is patched
-  everywhere, and the lazily replicated PPR operand copy is invalidated.
+* **ell / ell_sharded** — both are a *sliced* ELLPACK
+  (:mod:`repro.pagerank.sell`): rows grouped into degree tiers of doubling
+  width, each padded to its width plus ``slack``, one device-local SELL
+  per shard on ``ell_sharded``.  The sweep is one dense gather per tier
+  and **no** ``segment_sum``, and every affected row is rewritten with one
+  row-scatter per tier; on ``ell_sharded`` the scatter output is pinned to
+  the tier's row ``NamedSharding`` (a ``with_sharding_constraint``), so
+  each write lands on the device owning the row.  The capacity slack means
+  small deltas never change any array shape; a row outgrowing its tier
+  triggers the rebuild fallback.
 * **dense_sharded** — the changed columns are scattered under the 2-D
   fabric ``P(row, col)`` sharding, so each write lands on the mesh column
   that owns it; the padded tail rows/columns stay zero.
@@ -85,8 +81,8 @@ from repro.graph.delta import GraphDelta, edge_keys
 from repro.kernels.streaming_matvec import streaming_matvec
 from repro.obs.trace import SolveTrace, instrumented_tol_loop
 from repro.pagerank import distributed as dist
+from repro.pagerank import sell
 from repro.pagerank.engine import PageRankEngine, _dedupe_edges, _matvec
-from repro.pagerank.precision import quantize_int8, rowmax_scales
 from repro.pagerank.resilience import EngineSnapshot, make_solve_info
 
 __all__ = ["DynamicPageRankEngine", "UpdateInfo", "PATCHABLE_BACKENDS"]
@@ -321,10 +317,10 @@ def _push_dense_sharded(H, dang, tol, x0, *, mesh, axes, n_true, max_pushes,
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_pushes",
                                    "d", "trace"))
-def _push_ell_sharded(data, idx, dang, tol, x0, *, mesh, axes, n_true,
+def _push_ell_sharded(layout, dang, tol, x0, *, mesh, axes, n_true,
                       max_pushes, d, trace: bool = False):
     x, sweeps, res, grow, ring = dist.push_distributed_sparse_tol(
-        data, idx, mesh, x0, tol=tol, max_pushes=max_pushes, d=d,
+        layout, mesh, x0, tol=tol, max_pushes=max_pushes, d=d,
         dangling=dang, axes=axes, n_true=n_true, trace=trace)
     return x[:n_true], sweeps, res, grow, ring
 
@@ -337,7 +333,7 @@ class DynamicPageRankEngine(PageRankEngine):
 
     Same constructor, same ``run`` / ``run_tol`` / ``ppr`` surface (the
     ``ell`` backend transparently swaps in the patchable SELL layout; the
-    ``ell_sharded`` layout is built with ``maxdeg + slack`` columns of row
+    ``ell_sharded`` SELL tiers are built with ``slack`` slots of row
     headroom; ``bsr`` keeps a host block-structure map for in-block value
     patches), plus:
 
@@ -375,22 +371,12 @@ class DynamicPageRankEngine(PageRankEngine):
 
     # --------------------------- layout prep --------------------------- #
     def _prepare_layout(self, src: np.ndarray, dst: np.ndarray) -> None:
-        if self.backend == "ell_sharded":
-            # reserve patch headroom: the engine treats ``_ell_k`` as a
-            # MINIMUM row capacity (never a truncation), so building with
-            # maxdeg + slack keeps every array shape fixed across small
-            # deltas; a row outgrowing K escalates update() to rebuild
-            indeg = np.bincount(np.asarray(dst, np.int64),
-                                minlength=self.n)
-            maxdeg = int(indeg.max()) if len(indeg) else 0
-            self._ell_k = maxdeg + max(4, self._slack)
-            super()._prepare_layout(src, dst)
-            return
         if self.backend == "bsr":
             super()._prepare_layout(src, dst)
             self._bsr_index(src, dst)
             return
         if self.backend != "ell":
+            # ell_sharded builds its SELL with this engine's ``_slack``
             super()._prepare_layout(src, dst)
             return
         n = self.n
@@ -398,64 +384,14 @@ class DynamicPageRankEngine(PageRankEngine):
         self.mesh = None
         self._axes = ()
         self._n_pad = n
-        self._ppr_operands = None
         self._scales = None
-        self._ppr_scales = None
         self._mv_backend = "sell"     # engine._matvec's tag for this layout
-        csr = tr.build_transition_csr(src, dst, n)
-        counts = np.diff(np.asarray(csr.indptr))
-        # tier threshold at the 90th degree percentile; capacities sit
-        # ``slack`` (low) / ≥16 rounded-to-32 (high) ABOVE the largest row
-        # they hold, so every row has patch headroom — a row outgrowing its
-        # tier is what escalates update() to the rebuild path
-        thresh = max(4, int(np.percentile(counts, 90)) if len(counts)
-                     else 0)
-        k_low = thresh + self._slack
-        maxdeg = int(counts.max()) if len(counts) else 0
-        k_high = -(-(max(maxdeg, k_low) + max(16, self._slack)) // 32) * 32
-        high = counts > thresh
-        low_rows = np.where(~high)[0]
-        high_rows = np.where(high)[0]
-        perm = np.concatenate([low_rows, high_rows])
-        self._sell_k = (k_low, k_high)
-        self._sell_n_low = len(low_rows)
-        self._sell_pos = np.empty(n, np.int64)       # row -> index in tier
-        self._sell_pos[low_rows] = np.arange(len(low_rows))
-        self._sell_pos[high_rows] = np.arange(len(high_rows))
-        self._sell_high = high
-        inv = np.empty(n, np.int64)
-        inv[perm] = np.arange(n)
-        dl = np.zeros((len(low_rows), k_low), np.float32)
-        il = np.zeros((len(low_rows), k_low), np.int32)
-        dh = np.zeros((len(high_rows), k_high), np.float32)
-        ih = np.zeros((len(high_rows), k_high), np.int32)
-        rows, pos = csr.row_positions()
-        cols = np.asarray(csr.indices)
-        vals = np.asarray(csr.data)
-        in_low = ~high[rows]
-        r_l = self._sell_pos[rows[in_low]]
-        dl[r_l, pos[in_low]] = vals[in_low]
-        il[r_l, pos[in_low]] = cols[in_low]
-        r_h = self._sell_pos[rows[~in_low]]
-        dh[r_h, pos[~in_low]] = vals[~in_low]
-        ih[r_h, pos[~in_low]] = cols[~in_low]
-        if self.precision == "int8":
-            # per-row scales per tier, appended to the operand tuple (the
-            # 7-tuple traces engine._matvec's scaled SELL program)
-            sl = rowmax_scales(np.abs(dl).max(axis=1, initial=0.0))
-            sh = rowmax_scales(np.abs(dh).max(axis=1, initial=0.0))
-            self._operands = (
-                jnp.asarray(quantize_int8(dl, sl[:, None])), jnp.asarray(il),
-                jnp.asarray(quantize_int8(dh, sh[:, None])), jnp.asarray(ih),
-                jnp.asarray(inv, jnp.int32), jnp.asarray(sl),
-                jnp.asarray(sh))
-        else:
-            dtype = self.storage_dtype
-            self._operands = (jnp.asarray(dl).astype(dtype), jnp.asarray(il),
-                              jnp.asarray(dh).astype(dtype), jnp.asarray(ih),
-                              jnp.asarray(inv, jnp.int32))
-        self.layout = (f"sell(k_low={k_low}, k_high={k_high}, "
-                       f"n_high={len(high_rows)}, slack={self._slack})")
+        # the capacity slack is what lets a delta patch rows in place — a
+        # row outgrowing its tier is what escalates update() to rebuild
+        self._operands, self._sell = sell.build(
+            tr.build_transition_csr(src, dst, n), n, slack=self._slack,
+            precision=self.precision)
+        self.layout = self._sell.describe(self._slack)
         if self.precision != "f32":
             self.layout = f"{self.layout}[{self.precision}]"
         self._record_layout_bytes()
@@ -705,11 +641,7 @@ class DynamicPageRankEngine(PageRankEngine):
                 parts.append(_key_slice(self._keys, int(u), n))
                 parts.append(_key_slice(new_keys, int(u), n))
             rows = np.unique(np.concatenate(parts))
-            if self.backend == "ell":
-                k_low, k_high = self._sell_k
-                cap = np.where(self._sell_high[rows], k_high, k_low)
-            else:           # full-K sharded rows: one capacity for all
-                cap = self._operands[0].shape[1]
+            cap = np.asarray(self._sell.widths)[self._sell.tier[rows]]
             overflow = bool((indeg[rows] > cap).any())
         elif self.backend == "bsr":
             # per changed column: its old and new out-neighbor sets (both
@@ -796,24 +728,6 @@ class DynamicPageRankEngine(PageRankEngine):
         if self.backend == "bsr":
             self._patch_bsr(plan)
             return 0, len(cols)
-        if self.backend == "ell_sharded":
-            # rewrite every affected full-K row shard-local: the scatter
-            # output is pinned to the existing row NamedSharding, so each
-            # write lands on the device owning the row
-            rows = plan["rows"]
-            data_op, idx_op = self._operands
-            data, idx = self._rebuild_rows(rows, int(data_op.shape[1]))
-            pos, dat, ix = _stack_chunks(rows, data, idx, cap=64)
-            sharding = NamedSharding(self.mesh, P(self._axes))
-            pos = jnp.asarray(pos)
-            data_op = _scatter_rows(data_op, pos,
-                                    jnp.asarray(dat).astype(data_op.dtype),
-                                    sharding=sharding)
-            idx_op = _scatter_rows(idx_op, pos, jnp.asarray(ix),
-                                   sharding=sharding)
-            self._operands = (data_op, idx_op)
-            self._ppr_operands = None   # lazily replicated PPR copy: stale
-            return len(rows), len(cols)
         if self.backend == "pallas_dense":
             Hp, dangp = self._operands
             mat = np.stack([self._column(int(u), fix_dangling=False)
@@ -825,28 +739,29 @@ class DynamicPageRankEngine(PageRankEngine):
                 dangp = dangp.at[0, jnp.asarray(ci)].set(jnp.asarray(f))
             self._operands = (Hp, dangp)
             return 0, len(cols)
-        # ell: rewrite every affected SELL row in its tier (vectorized: one
-        # gather over the reverse key set builds all rows at once)
+        # ell / ell_sharded: rewrite every affected SELL row in its tier
+        # (vectorized: one gather over the reverse key set builds all rows
+        # at once); on the mesh each scatter stays on its tier's row
+        # sharding, so every write lands on the device owning the row
         rows = plan["rows"]
-        k_low, k_high = self._sell_k
-        dl, il, dh, ih, inv = self._operands
-        for tier, k, cap in ((False, k_low, 512), (True, k_high, 64)):
-            sel = rows[self._sell_high[rows] == tier]
+        inv, tiers = self._operands
+        tiers = list(tiers)
+        for t, k in enumerate(self._sell.widths):
+            sel = rows[self._sell.tier[rows] == t]
             if len(sel) == 0:
                 continue
             data, idx = self._rebuild_rows(sel, k)
-            pos, dat, ix = _stack_chunks(self._sell_pos[sel], data, idx,
-                                         cap=cap)
+            pos, dat, ix = _stack_chunks(self._sell.pos[sel], data, idx,
+                                         cap=512 if t == 0 else 64)
             pos = jnp.asarray(pos)
-            if tier:
-                dh = _scatter_rows(dh, pos,
-                                   jnp.asarray(dat).astype(dh.dtype))
-                ih = _scatter_rows(ih, pos, jnp.asarray(ix))
-            else:
-                dl = _scatter_rows(dl, pos,
-                                   jnp.asarray(dat).astype(dl.dtype))
-                il = _scatter_rows(il, pos, jnp.asarray(ix))
-        self._operands = (dl, il, dh, ih, inv)
+            d, i = tiers[t]
+            sharding = None if self.mesh is None else d.sharding
+            tiers[t] = (_scatter_rows(d, pos,
+                                      jnp.asarray(dat).astype(d.dtype),
+                                      sharding=sharding),
+                        _scatter_rows(i, pos, jnp.asarray(ix),
+                                      sharding=sharding))
+        self._operands = (inv, tuple(tiers))
         return len(rows), len(cols)
 
     def _patch_bsr(self, plan: dict) -> None:
@@ -918,7 +833,7 @@ class DynamicPageRankEngine(PageRankEngine):
                 max_pushes=max_pushes, d=self.d, trace=trace)
         if self.backend == "ell_sharded":
             return _push_ell_sharded(
-                *self._operands, self._dang, jnp.float32(tol),
+                self._operands, self._dang, jnp.float32(tol),
                 self._pad_x0(jnp.asarray(x0, jnp.float32)),
                 mesh=self.mesh, axes=self._axes, n_true=self.n,
                 max_pushes=max_pushes, d=self.d, trace=trace)

@@ -29,12 +29,13 @@ with no host intervention; :class:`PageRankEngine` is the JAX analogue:
 * **Sharded multi-device tiers** — ``dense_sharded`` runs the paper's
   fabric schedule (:mod:`repro.pagerank.distributed` over
   :mod:`repro.core.fabric_matvec`) with H blocked ``P(row, col)`` over a
-  2-D device mesh; ``ell_sharded`` row-shards the ELL layout over the
-  flattened mesh with one ``all_gather`` per iteration.  Both build their
-  ``NamedSharding`` layouts once at construction, keep tolerance-based
-  early exit working across the mesh (the residual is a replicated
-  scalar), and shard the batched (N, Q) PPR matrix over the query axis so
-  a multi-user serve batch spreads across devices.
+  2-D device mesh; ``ell_sharded`` row-shards a sliced-ELL layout
+  (:mod:`repro.pagerank.sell`) over the flattened mesh with one
+  ``all_gather`` per iteration.  Both build their ``NamedSharding``
+  layouts once at construction and keep tolerance-based early exit
+  working across the mesh (the residual is a replicated scalar); batched
+  (N, Q) PPR shards the query axis (``dense_sharded``) or the rows, as the
+  global solve does (``ell_sharded``).
 
 The canonical per-iteration step functions live in
 :mod:`repro.pagerank.steps` and are shared with ``repro.pagerank.dense`` /
@@ -57,14 +58,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.graph import delta as delta_mod
 from repro.graph import transition as tr
-from repro.graph.sparse import BSRMatrix, ELLMatrix
+from repro.graph.sparse import BSRMatrix
 from repro.kernels import ops as kops
-from repro.kernels.common import upcast_f32
+from repro.kernels.common import ell_rows, upcast_f32
 from repro.kernels.pagerank_step import (pad_pagerank_operands,
                                          pagerank_step_fused)
 from repro.kernels.streaming_matvec import streaming_matvec
 from repro.launch.mesh import make_mesh
 from repro.pagerank import distributed as dist
+from repro.pagerank import sell
 from repro.obs.registry import default_registry
 from repro.obs.trace import SolveTrace, instrumented_tol_loop
 from repro.pagerank.dense import pagerank_dense, pagerank_dense_fixed
@@ -84,9 +86,9 @@ BACKENDS = ("dense", "ell", "bsr", "pallas_dense", "dense_sharded",
             "ell_sharded")
 SHARDED_BACKENDS = ("dense_sharded", "ell_sharded")
 
-# auto-selection thresholds on nnz / n^2
-DENSE_DENSITY = 0.25    # at/above: blocked-dense sweeps beat index chasing
-BSR_DENSITY = 0.02      # at/below (sparsity >= 98%): block-sparse rows win
+# auto-selection threshold on nnz / n^2: at/above it, blocked-dense sweeps
+# beat index chasing; below it every device takes the ELL gather
+DENSE_DENSITY = 0.25
 
 
 def select_backend(n: int, density: float, device: str | None = None,
@@ -114,10 +116,9 @@ def select_backend(n: int, density: float, device: str | None = None,
                 else "ell_sharded")
     if density >= DENSE_DENSITY:
         return "pallas_dense" if device == "tpu" else "dense"
-    if device == "tpu" and density <= BSR_DENSITY and n >= 256:
-        # sparsity >= 98%: MXU-aligned blocks + scalar-prefetch SpMV; on
-        # CPU the block einsum loses to the ELL gather, so TPU-only
-        return "bsr"
+    # sparse graphs go to ELL on every device: the bsr tier builds and
+    # stores N^2 values (graph.transition.build_transition_bsr), so it is
+    # selectable by name only
     return "ell"
 
 
@@ -198,32 +199,16 @@ def _matvec(backend: str, operands, x: jax.Array) -> jax.Array:
     if backend == "ell":
         data, idx, ov_r, ov_c, ov_v = operands[:5]
         scales = operands[5] if len(operands) == 6 else None
-        data, ov_v = upcast_f32(data), upcast_f32(ov_v)
-        n = data.shape[0]
-        if x.ndim == 1:
-            y = jnp.sum(data * x[idx], axis=1)
-            tail = jax.ops.segment_sum(ov_v * x[ov_c], ov_r,
-                                       num_segments=n)
-        else:
-            y = jnp.sum(data[..., None] * x[idx], axis=1)
-            tail = jax.ops.segment_sum(ov_v[:, None] * x[ov_c], ov_r,
-                                       num_segments=n)
+        ov_v = upcast_f32(ov_v)
+        y = ell_rows(data, idx, x)
+        tail = jax.ops.segment_sum(
+            (ov_v if x.ndim == 1 else ov_v[:, None]) * x[ov_c], ov_r,
+            num_segments=data.shape[0])
         return _row_scale(y + tail, scales)
     if backend == "sell":
-        # two-bucket sliced ELLPACK (the dynamic engine's patchable ELL
-        # tier, repro.pagerank.dynamic): rows permuted into a low tier and
-        # a hub tier, two dense gathers, no segment_sum
-        dl, il, dh, ih, inv = operands[:5]
-        sl, sh = operands[5:7] if len(operands) == 7 else (None, None)
-        dl, dh = upcast_f32(dl), upcast_f32(dh)
-        if x.ndim == 1:
-            yl = jnp.sum(dl * x[il], axis=1)
-            yh = jnp.sum(dh * x[ih], axis=1)
-        else:
-            yl = jnp.sum(dl[..., None] * x[il], axis=1)
-            yh = jnp.sum(dh[..., None] * x[ih], axis=1)
-        return jnp.concatenate([_row_scale(yl, sl), _row_scale(yh, sh)],
-                               axis=0)[inv]
+        # sliced ELLPACK, the dynamic engine's patchable ELL tier: the
+        # layout's own module builds and reads it
+        return sell.sell_rows(operands, x)
     if backend == "bsr":
         # BSRMatrix upcasts its own blocks and owns its row_scales field
         bsr = operands[0]
@@ -327,32 +312,29 @@ def _run_ppr_dense_sharded(H, dang, V, scales=None, *, mesh, axes, n_true,
 
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_fixed_ell_sharded(data, idx, dang, scales=None, *, mesh, axes,
-                           n_true, n_iters, d):
-    pr = dist.pagerank_distributed_sparse(data, idx, mesh, n_iters=n_iters,
+def _run_fixed_ell_sharded(layout, dang, *, mesh, axes, n_true, n_iters, d):
+    pr = dist.pagerank_distributed_sparse(layout, mesh, n_iters=n_iters,
                                           d=d, dangling=dang, axes=axes,
-                                          n_true=n_true, scales=scales)
+                                          n_true=n_true)
     return pr[:n_true]
 
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_iters",
                                    "d", "watchdog", "trace"))
-def _run_tol_ell_sharded(data, idx, dang, tol, x0, scales=None, *, mesh,
-                         axes, n_true, max_iters, d, watchdog: bool = True,
+def _run_tol_ell_sharded(layout, dang, tol, x0, *, mesh, axes, n_true,
+                         max_iters, d, watchdog: bool = True,
                          trace: bool = False):
     pr, iters, res, grow, ring = dist.pagerank_distributed_sparse_tol(
-        data, idx, mesh, tol=tol, max_iters=max_iters, d=d, dangling=dang,
-        axes=axes, n_true=n_true, x0=x0, watchdog=watchdog, trace=trace,
-        scales=scales)
+        layout, mesh, tol=tol, max_iters=max_iters, d=d, dangling=dang,
+        axes=axes, n_true=n_true, x0=x0, watchdog=watchdog, trace=trace)
     return pr[:n_true], iters, res, grow, ring
 
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_ppr_ell_sharded(data, idx, dang, V, scales=None, *, mesh, axes,
-                         n_true, n_iters, d):
-    PR = dist.ppr_distributed_sparse(data, idx, dang, V, mesh,
-                                     n_iters=n_iters, d=d, axes=axes,
-                                     scales=scales)
+def _run_ppr_ell_sharded(layout, dang, V, *, mesh, axes, n_true, n_iters,
+                         d):
+    PR = dist.ppr_distributed_sparse(layout, dang, V, mesh,
+                                     n_iters=n_iters, d=d, axes=axes)
     return PR[:n_true]
 
 
@@ -445,8 +427,9 @@ class PageRankEngine:
     * ``"dense_sharded"``— dangling-unfixed dense H blocked P(row, col)
       over a 2-D device mesh, iterated with the paper's fabric schedule
       (one psum + one re-injection per iteration); explicit scalar leak.
-    * ``"ell_sharded"``  — full-K ELL rows sharded over the flattened
-      mesh, rank vector replicated, one tiled all_gather per iteration.
+    * ``"ell_sharded"``  — sliced-ELL rows (degree tiers, no row padded
+      to the maximum degree) sharded over the flattened mesh, rank vector
+      replicated, one tiled all_gather per iteration.
     * ``"auto"``         — :func:`select_backend` by density + device
       topology (multi-device processes pick the sharded tiers).
 
@@ -455,6 +438,10 @@ class PageRankEngine:
     into real ranks and results are sliced back to N.  Duplicate directed
     edges are collapsed up front so every tier sees the same graph.
     """
+
+    # row headroom of each SELL tier (``ell_sharded``); the dynamic engine
+    # reserves more so edge deltas patch rows in place
+    _slack = 0
 
     def __init__(self, src: np.ndarray, dst: np.ndarray, n: int, *,
                  d: float = 0.85, backend: str = "auto",
@@ -517,12 +504,10 @@ class PageRankEngine:
         self.mesh = None
         self._axes: tuple[str, ...] = ()
         self._n_pad = self.n
-        self._ppr_operands: tuple | None = None
-        # int8 per-row dequantization scales of the pallas/sharded tiers
-        # (the XLA tiers append theirs to the operand tuple instead);
-        # always None for float precisions
+        # int8 per-row dequantization scales of the pallas/dense_sharded
+        # tiers (the XLA tiers append theirs to the operand tuple, SELL
+        # keeps one per tier); always None for float precisions
         self._scales = None
-        self._ppr_scales = None
         # the layout tag the generic jitted runners dispatch _matvec on —
         # normally the backend itself; the dynamic engine's patchable SELL
         # tier overrides it while keeping backend == "ell"
@@ -605,38 +590,16 @@ class PageRankEngine:
             self._axes = tuple(self.mesh.axis_names)
             ndev = self.mesh.size
             self._n_pad = -(-self.n // ndev) * ndev
-            # full-K ELL (not the split layout): row blocks must be
-            # self-contained so each device sweeps its rows with one gather.
-            # ``ell_k`` here is a MINIMUM row capacity, never a truncation:
-            # the dynamic engine passes maxdeg + slack so in-place row
-            # patches have headroom without any array shape changing
-            csr = tr.build_transition_csr(src, dst, n)
-            counts = np.diff(np.asarray(csr.indptr))
-            maxdeg = int(counts.max()) if len(counts) else 0
-            k = maxdeg if ell_k is None else max(int(ell_k), maxdeg)
-            ell = ELLMatrix.from_csr(csr, k=k)
-            data = np.zeros((self._n_pad, ell.k), np.float32)
-            idx = np.zeros((self._n_pad, ell.k), np.int32)
-            data[:n] = np.asarray(ell.data)
-            idx[:n] = np.asarray(ell.indices)
-            rows = NamedSharding(self.mesh, P(self._axes))
-            if self.precision == "int8":
-                scales = rowmax_scales(np.abs(data).max(axis=1,
-                                                        initial=0.0))
-                data_dev = jax.device_put(
-                    quantize_int8(data, scales[:, None]), rows)
-                # row-sharded like the ELL operands: _ell_block_iter folds
-                # the local scale block into its local row sums
-                self._scales = jax.device_put(scales, rows)
-            elif self.precision != "f32":
-                data_dev = jax.device_put(
-                    jnp.asarray(data).astype(self.storage_dtype), rows)
-            else:
-                data_dev = jax.device_put(data, rows)
-            self._operands = (data_dev, jax.device_put(idx, rows))
+            # sliced ELL, not one block padded to the maximum degree: each
+            # device holds a self-contained SELL of its own rows, so it
+            # sweeps them with one gather per degree tier
+            self._operands, self._sell = sell.build(
+                tr.build_transition_csr(src, dst, n), self._n_pad,
+                shards=ndev, slack=self._slack, precision=self.precision,
+                sharding=NamedSharding(self.mesh, P(self._axes)))
             self._dang = self._pad_replicated(self._dang)
-            self.layout = (f"ell_sharded(k={ell.k}, shards={ndev}, "
-                           f"n_pad={self._n_pad})")
+            self.layout = (f"ell_sharded({self._sell.describe(self._slack)}"
+                           f", shards={ndev}, n_pad={self._n_pad})")
         else:                                   # pallas_dense
             H = tr.build_transition_dense(src, dst, n, fix_dangling=False)
             Hp, dangp, bn, bm = pad_pagerank_operands(
@@ -707,8 +670,8 @@ class PageRankEngine:
                 n_iters=n_iters, d=self.d)
         if self.backend == "ell_sharded":
             return _run_fixed_ell_sharded.lower(
-                *self._operands, self._dang, self._scales, mesh=self.mesh,
-                axes=self._axes, n_true=self.n, n_iters=n_iters, d=self.d)
+                self._operands, self._dang, mesh=self.mesh, axes=self._axes,
+                n_true=self.n, n_iters=n_iters, d=self.d)
         if self.backend == "dense" and self.precision == "f32":
             return pagerank_dense_fixed.lower(
                 self._operands[0], n_iters=n_iters, d=self.d)
@@ -731,8 +694,8 @@ class PageRankEngine:
                 n_iters=n_iters, d=self.d)
         if self.backend == "ell_sharded":
             return _run_fixed_ell_sharded(
-                *self._operands, self._dang, self._scales, mesh=self.mesh,
-                axes=self._axes, n_true=self.n, n_iters=n_iters, d=self.d)
+                self._operands, self._dang, mesh=self.mesh, axes=self._axes,
+                n_true=self.n, n_iters=n_iters, d=self.d)
         if self.backend == "pallas_dense":
             Hp, dangp = self._operands
             return _run_fixed_pallas(
@@ -794,10 +757,10 @@ class PageRankEngine:
                     d=self.d, watchdog=watchdog, trace=trace)
             elif self.backend == "ell_sharded":
                 out = _run_tol_ell_sharded(
-                    *self._operands, self._dang, tol_f32,
-                    self._pad_x0(x0), self._scales, mesh=self.mesh,
-                    axes=self._axes, n_true=self.n, max_iters=max_iters,
-                    d=self.d, watchdog=watchdog, trace=trace)
+                    self._operands, self._dang, tol_f32, self._pad_x0(x0),
+                    mesh=self.mesh, axes=self._axes, n_true=self.n,
+                    max_iters=max_iters, d=self.d, watchdog=watchdog,
+                    trace=trace)
             elif self.backend == "pallas_dense":
                 Hp, dangp = self._operands
                 out = _run_tol_pallas(
@@ -865,9 +828,10 @@ class PageRankEngine:
         """Batched personalized PageRank: one (N, Q) propagation for Q
         per-user seed sets; returns the (N, Q) rank matrix.
 
-        On the sharded tiers the query axis is sharded across the mesh
-        (padded up to the shard count with zero columns, sliced back), so a
-        multi-user serve flush spreads over devices unchanged."""
+        On ``dense_sharded`` the query axis is sharded across the mesh
+        (padded up to the shard count with zero columns, sliced back); on
+        ``ell_sharded`` every device sweeps its own rows for all queries,
+        so a multi-user serve flush spreads over devices either way."""
         with self.metrics.span("ppr", backend=self.backend,
                                q=len(seed_sets)):
             self.metrics.counter("engine.ppr_queries").inc(len(seed_sets))
@@ -876,37 +840,23 @@ class PageRankEngine:
     def _ppr(self, seed_sets: Sequence[np.ndarray],
              n_iters: int) -> jax.Array:
         V = seed_matrix(self.n, seed_sets)
-        if self.backend in SHARDED_BACKENDS:
+        if self.backend == "dense_sharded":
             q = V.shape[1]
-            q_shards = (self.mesh.shape[self._axes[1]]
-                        if self.backend == "dense_sharded" else
-                        self.mesh.size)
+            q_shards = self.mesh.shape[self._axes[1]]
             q_pad = -(-q // q_shards) * q_shards
             Vp = np.zeros((self._n_pad, q_pad), np.float32)
             Vp[:self.n, :q] = V
-            if self.backend == "dense_sharded":
-                PR = _run_ppr_dense_sharded(
-                    self._operands[0], self._dang, jnp.asarray(Vp),
-                    self._scales, mesh=self.mesh, axes=self._axes,
-                    n_true=self.n, n_iters=n_iters, d=self.d)
-            else:
-                if self._ppr_operands is None:
-                    # PPR propagates query blocks against *replicated*
-                    # operands; the copy is placed once, on first use, so
-                    # serve flushes never re-gather the layout and
-                    # run-only engines never pay the replicated memory
-                    rep = NamedSharding(self.mesh, P())
-                    self._ppr_operands = tuple(
-                        jax.device_put(np.asarray(o), rep)
-                        for o in self._operands)
-                    if self._scales is not None:
-                        self._ppr_scales = jax.device_put(
-                            np.asarray(self._scales), rep)
-                PR = _run_ppr_ell_sharded(
-                    *self._ppr_operands, self._dang, jnp.asarray(Vp),
-                    self._ppr_scales, mesh=self.mesh, axes=self._axes,
-                    n_true=self.n, n_iters=n_iters, d=self.d)
+            PR = _run_ppr_dense_sharded(
+                self._operands[0], self._dang, jnp.asarray(Vp),
+                self._scales, mesh=self.mesh, axes=self._axes,
+                n_true=self.n, n_iters=n_iters, d=self.d)
             return PR[:, :q]
+        if self.backend == "ell_sharded":
+            Vp = np.zeros((self._n_pad, V.shape[1]), np.float32)
+            Vp[:self.n] = V
+            return _run_ppr_ell_sharded(
+                self._operands, self._dang, jnp.asarray(Vp), mesh=self.mesh,
+                axes=self._axes, n_true=self.n, n_iters=n_iters, d=self.d)
         if self.backend == "pallas_dense":
             Hp, dangp = self._operands
             Vp = np.zeros((V.shape[1], Hp.shape[1]), np.float32)

@@ -39,12 +39,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.common import upcast_f32
 from repro.kernels.streaming_matvec import streaming_matvec
 from repro.obs.registry import default_registry
 from repro.obs.trace import instrumented_tol_loop
+from repro.pagerank.distributed import sell_mv_sharded
 from repro.pagerank.engine import SHARDED_BACKENDS, _matvec, _row_scale
 from repro.pagerank.steps import ppr_step_batched, seed_matrix
 
@@ -84,9 +84,10 @@ def _batched_push(Ab, X0, tol, n, max_pushes):
     return X, jnp.sum(jnp.abs(R), axis=0), iters, res, grow
 
 
-@partial(jax.jit, static_argnames=("backend", "n", "max_pushes", "d"))
+@partial(jax.jit, static_argnames=("backend", "n", "max_pushes", "d",
+                                   "mesh", "axes"))
 def _hub_push(operands, dang, scales, V, X0, tol, *, backend: str, n: int,
-              max_pushes: int, d: float):
+              max_pushes: int, d: float, mesh=None, axes=()):
     if backend == "dense":
         # the f32 dense operand is dangling-FIXED; masking the dangling
         # columns reconstructs the unfixed H (a no-op on the reduced
@@ -98,10 +99,8 @@ def _hub_push(operands, dang, scales, V, X0, tol, *, backend: str, n: int,
         # stored dangling-unfixed; GSPMD propagates the P(row, col) layout
         mv = lambda X: _row_scale(upcast_f32(operands[0]) @ X, scales)
     elif backend == "ell_sharded":
-        # replicated full-K ELL operands (the engine's PPR copy)
-        data, idx = operands
-        mv = lambda X: _row_scale(
-            jnp.sum(upcast_f32(data)[..., None] * X[idx], axis=1), scales)
+        # every device sweeps its own SELL rows for all query columns
+        mv = lambda X: sell_mv_sharded(operands, X, mesh, axes)
     else:
         mv = lambda X: _matvec(backend, operands, X)
 
@@ -313,27 +312,14 @@ class LandmarkIndex:
                 interpret=e.interpret)
             return np.asarray(X), np.asarray(res_col), int(sweeps)
         if e.backend in SHARDED_BACKENDS:
-            operands, scales = e._operands, e._scales
-            if e.backend == "ell_sharded":
-                # the push propagates query columns against replicated
-                # operands, sharing the engine's lazily-placed PPR copy
-                if e._ppr_operands is None:
-                    rep = NamedSharding(e.mesh, P())
-                    e._ppr_operands = tuple(
-                        jax.device_put(np.asarray(o), rep)
-                        for o in e._operands)
-                    if e._scales is not None:
-                        e._ppr_scales = jax.device_put(
-                            np.asarray(e._scales), rep)
-                operands, scales = e._ppr_operands, e._ppr_scales
             n_pad, q = e._n_pad, V.shape[1]
             Vp = np.zeros((n_pad, q), np.float32)
             X0p = np.zeros((n_pad, q), np.float32)
             Vp[:e.n], X0p[:e.n] = V, X0
             X, res_col, sweeps, _, _ = _hub_push(
-                operands, e._dang, scales, jnp.asarray(Vp),
+                e._operands, e._dang, e._scales, jnp.asarray(Vp),
                 jnp.asarray(X0p), tol, backend=e.backend, n=e.n,
-                max_pushes=max_pushes, d=e.d)
+                max_pushes=max_pushes, d=e.d, mesh=e.mesh, axes=e._axes)
             return np.asarray(X)[:e.n], np.asarray(res_col), int(sweeps)
         X, res_col, sweeps, _, _ = _hub_push(
             e._operands, e._dang, None, jnp.asarray(V), jnp.asarray(X0),

@@ -39,6 +39,7 @@ import time
 from collections import deque
 from typing import Iterable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -484,25 +485,19 @@ class FaultInjector:
     # ------------------------------ layout ----------------------------- #
     def corrupt_layout(self, engine, kind: str = "nan", k: int = 4) -> None:
         """Poison ``k`` values of the first float array in the engine's
-        prepared layout (the dense H, the ELL/SELL data tier, the BSR
+        prepared layout pytree (the dense H, the ELL/SELL data tier, the BSR
         blocks, or a sharded operand — whichever the backend prepared).
         ``kind="huge"`` plants finite-but-absurd values and
         ``kind="scale"`` multiplies the whole array by 32 — a spectral
         radius ≫ 1, the deterministic way to exercise the residual-growth
         (``diverged``) watchdog rather than the NaN/Inf check; device
         sharding is preserved on the write-back."""
-        ops = list(engine._operands)
-        target = None
-        for i, op in enumerate(ops):
-            arr = getattr(op, "blocks", op)     # BSRMatrix stores .blocks
-            if np.issubdtype(np.asarray(arr).dtype, np.floating):
-                target = i
-                break
+        leaves, treedef = jax.tree.flatten(engine._operands)
+        target = next((i for i, a in enumerate(leaves)
+                       if jnp.issubdtype(a.dtype, jnp.floating)), None)
         if target is None:
             raise ValueError("no float layout array to corrupt")
-        op = ops[target]
-        is_bsr = hasattr(op, "blocks")
-        arr = np.asarray(op.blocks if is_bsr else op).copy()
+        arr = np.asarray(leaves[target]).copy()
         flat = arr.reshape(-1)
         if kind == "scale":
             arr *= 32.0
@@ -514,16 +509,8 @@ class FaultInjector:
             # matter; whether it trips diverged or nonfinite depends on
             # how fast the corrupt entries feed back
             flat[idx] = {"nan": np.nan, "inf": np.inf, "huge": 1e4}[kind]
-        if is_bsr:
-            ops[target] = dataclasses.replace(op, blocks=jnp.asarray(arr))
-        else:
-            sharding = getattr(op, "sharding", None)
-            new = jnp.asarray(arr)
-            if sharding is not None:
-                import jax
-                new = jax.device_put(new, sharding)
-            ops[target] = new
-        engine._operands = tuple(ops)
+        leaves[target] = jax.device_put(arr, leaves[target].sharding)
+        engine._operands = jax.tree.unflatten(treedef, leaves)
         self.log.append(f"layout:{kind}(k={len(idx)},operand={target})")
 
     # --------------------------- update failures ----------------------- #

@@ -1,35 +1,31 @@
 """Test-suite bootstrap.
 
-Two jobs, both of which must run before anything imports ``jax``:
+Both settings below must be in the environment before anything imports
+``jax``; conftest is imported before every test module, and child
+processes that a test starts inherit them.
 
-1. **Virtual multi-device CPU.**  The sharded engine tiers
-   (``dense_sharded`` / ``ell_sharded``) need a real device mesh; on CPU CI
-   we get one by injecting ``--xla_force_host_platform_device_count=8``
-   into ``XLA_FLAGS`` here, before the jax backend initializes (conftest is
-   imported before every test module).  Single-device code paths are
-   unaffected — unsharded arrays live on device 0.  Opt out with
+1. **CPU only.**  ``JAX_PLATFORMS`` defaults to ``cpu``, so neither pytest
+   nor any child it starts takes an accelerator: a chip belongs to one
+   process at a time, and the chip is the job of ``chip_smoke.py``.  A
+   caller that sets ``JAX_PLATFORMS`` itself keeps its value.
+
+2. **Virtual multi-device CPU.**  The sharded engine tiers
+   (``dense_sharded`` / ``ell_sharded``) need a real device mesh; on the
+   CPU we get one by injecting ``--xla_force_host_platform_device_count=8``
+   into ``XLA_FLAGS``.  Single-device code paths are unaffected —
+   unsharded arrays live on device 0.  Opt out with
    ``REPRO_SINGLE_DEVICE=1``; tests that genuinely need the mesh take the
    ``multi_device`` fixture, which skips (rather than fails) if the
    injection could not take effect (e.g. jax was already initialized by a
    plugin).
-
-2. **Hypothesis stand-in.**  The container may lack ``hypothesis``; without
-   it several test modules error at *collection*, taking the whole tier-1
-   run down with them.  When the real library is absent we install a
-   minimal deterministic stand-in covering the API surface these tests use
-   (``given`` / ``settings`` / ``strategies``: integers, floats,
-   sampled_from, sets, lists, booleans).  Each ``@given`` test then runs a
-   fixed number of seeded pseudo-random examples — far weaker than real
-   property testing, but the invariants still get exercised and the suite
-   stays green on bare containers.  With ``hypothesis`` installed the stub
-   is never registered.
 """
 from __future__ import annotations
 
 import os
-import sys
 
 import pytest
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 if (os.environ.get("REPRO_SINGLE_DEVICE") != "1"
         and "xla_force_host_platform_device_count"
@@ -50,129 +46,3 @@ def multi_device():
         pytest.skip("sharded tiers need >1 device; XLA_FLAGS injection "
                     "did not take effect")
     return n
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import functools
-    import inspect
-    import random
-    import types
-    import zlib
-
-    _N_EXAMPLES = 10
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example(self, rng: random.Random):
-            return self._draw(rng)
-
-    def _integers(min_value=0, max_value=1_000_000):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _floats(min_value=None, max_value=None, width=64, allow_nan=True,
-                allow_infinity=None):
-        lo = -1e6 if min_value is None else min_value
-        hi = 1e6 if max_value is None else max_value
-
-        def draw(rng):
-            v = rng.uniform(lo, hi)
-            if width == 32:
-                import numpy as np
-                v = float(np.float32(v))
-            return v
-
-        return _Strategy(draw)
-
-    def _sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda rng: rng.choice(elements))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _lists(elements, min_size=0, max_size=None, unique=False):
-        cap = min_size + 8 if max_size is None else max_size
-
-        def draw(rng):
-            size = rng.randint(min_size, cap)
-            if not unique:
-                return [elements.example(rng) for _ in range(size)]
-            out: list = []
-            for _ in range(200):
-                if len(out) >= size:
-                    break
-                v = elements.example(rng)
-                if v not in out:
-                    out.append(v)
-            return out
-
-        return _Strategy(draw)
-
-    def _sets(elements, min_size=0, max_size=None):
-        cap = min_size + 8 if max_size is None else max_size
-
-        def draw(rng):
-            size = rng.randint(min_size, cap)
-            out = set()
-            for _ in range(200):
-                if len(out) >= size:
-                    break
-                out.add(elements.example(rng))
-            while len(out) < min_size:
-                out.add(elements.example(rng))
-            return out
-
-        return _Strategy(draw)
-
-    def _given(*gargs, **gkwargs):
-        if gargs and not gkwargs:
-            raise TypeError("stub hypothesis.given supports kwargs only")
-
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                # read at call time so @settings works above or below @given
-                max_examples = getattr(wrapper, "_stub_max_examples",
-                                       _N_EXAMPLES)
-                rng = random.Random(zlib.crc32(fn.__qualname__.encode()))
-                for _ in range(min(max_examples, _N_EXAMPLES)):
-                    drawn = {k: s.example(rng) for k, s in gkwargs.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            # hide the drawn parameters from pytest's fixture resolution
-            sig = inspect.signature(fn)
-            params = [p for name, p in sig.parameters.items()
-                      if name not in gkwargs]
-            wrapper.__signature__ = sig.replace(parameters=params)
-            del wrapper.__wrapped__
-            wrapper.is_hypothesis_test = True
-            return wrapper
-
-        return deco
-
-    def _settings(max_examples=_N_EXAMPLES, deadline=None, **_ignored):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.sampled_from = _sampled_from
-    _st.sets = _sets
-    _st.lists = _lists
-    _st.booleans = _booleans
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__stub__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -264,7 +264,7 @@ def test_ell_row_overflow_escalates_to_rebuild(net):
     # the capacity slack, so the patch path must refuse and rebuild
     deg = np.bincount(src, minlength=n)
     w = int(np.argmin(np.where(deg > 0, deg, n)))
-    nbrs = [v for v in range(n) if v != w][:dyn._sell_k[0] + 2]
+    nbrs = [v for v in range(n) if v != w][:dyn._sell.widths[0] + 2]
     delta = GraphDelta.inserts([w] * len(nbrs), nbrs)
     pr, info = dyn.update(delta)
     assert info.overflow and info.strategy == "rebuild"
@@ -333,7 +333,7 @@ def test_overflow_coercion_is_recorded(net):
     dyn.run_tol(1e-7, max_iters=500)
     deg = np.bincount(src, minlength=n)
     w = int(np.argmin(np.where(deg > 0, deg, n)))
-    nbrs = [v for v in range(n) if v != w][:dyn._sell_k[0] + 2]
+    nbrs = [v for v in range(n) if v != w][:dyn._sell.widths[0] + 2]
     pr, info = dyn.update(GraphDelta.inserts([w] * len(nbrs), nbrs))
     assert info.overflow and info.strategy == "rebuild"
     assert info.coerced_from == "push"
@@ -403,22 +403,23 @@ def test_sharded_patch_preserves_shardings(net, backend, multi_device):
 
 
 def test_sharded_capacity_overflow_escalates(net, multi_device):
-    """Burying a node past the ell_sharded row capacity (maxdeg + slack)
-    escalates to a rebuild with the coercion recorded — and the rebuilt
-    layout regrows its capacity."""
+    """Burying a node past the ell_sharded row capacity (its SELL tier's
+    width) escalates to a rebuild with the coercion recorded — and the
+    rebuilt layout regrows its capacity."""
     n, src, dst = net
     dyn = DynamicPageRankEngine(src, dst, n, backend="ell_sharded",
                                 slack=2, rebuild_frac=1.0)
     dyn.run_tol(1e-7, max_iters=500)
-    cap = int(dyn.operands[0].shape[1])
     indeg = np.bincount(dst, minlength=n)
-    w = int(np.argmax(indeg))            # cap - indeg[w] is smallest here
+    caps = np.asarray(dyn._sell.widths)[dyn._sell.tier[:n]]
+    w = int(np.argmin(caps - indeg))     # the row closest to its capacity
+    cap = int(caps[w])
     have = set(dst[src == w].tolist()) | {w}
     nbrs = [v for v in range(n) if v not in have][:cap - indeg[w] + 2]
     pr, info = dyn.update(GraphDelta.inserts([w] * len(nbrs), nbrs))
     assert info.overflow and info.strategy == "rebuild"
     assert info.coerced_from == "push"
-    assert int(dyn.operands[0].shape[1]) > cap
+    assert dyn._sell.widths[dyn._sell.tier[w]] > cap
     delta = GraphDelta.inserts([w] * len(nbrs), nbrs)
     assert _l1(pr, _scratch_ranks(src, dst, n, delta)) <= 1e-5
 

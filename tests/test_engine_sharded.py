@@ -123,7 +123,7 @@ def test_select_backend_device_count_dimension(multi_device):
     assert select_backend(1000, 0.4, n_devices=8) == "dense_sharded"
     assert select_backend(1000, 0.4, device="tpu", n_devices=2) == \
         "dense_sharded"
-    assert select_backend(5000, 0.004, device="tpu", n_devices=1) == "bsr"
+    assert select_backend(5000, 0.004, device="tpu", n_devices=1) == "ell"
     # default n_devices follows jax.device_count() (8 under conftest)
     assert select_backend(5000, 0.004) == "ell_sharded"
 

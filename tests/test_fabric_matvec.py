@@ -1,8 +1,8 @@
 """Distributed fabric-MV (shard_map) tests.
 
-In-process tests run on a trivial 1x1 mesh (this container has one CPU
-device); the full 16-device semantics (real collectives, block permutation)
-run in a subprocess with ``--xla_force_host_platform_device_count=16``.
+In-process tests run on a trivial 1x1 mesh; the multi-device semantics
+(real collectives, block permutation) run in a subprocess on a 2x2 mesh of
+four virtual CPU devices, the shape of a four-chip host.
 """
 import os
 import subprocess
@@ -53,14 +53,14 @@ def test_gemv_batched_single_device():
 
 _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import sys; sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import fabric_matvec as fm
 
     from repro.launch.mesh import make_mesh
-    mesh = make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     N = 32
     A = jax.random.normal(jax.random.PRNGKey(0), (N, N))
     x = jax.random.normal(jax.random.PRNGKey(1), (N,))

@@ -6,7 +6,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.graph.sparse import BSRMatrix
-from repro.kernels import ops, ref
+from repro.kernels import common, ops, ref
 from repro.kernels.bsr_spmv import bsr_spmv
 from repro.kernels.pagerank_step import pagerank_step
 from repro.kernels.streaming_matvec import streaming_matvec
@@ -159,3 +159,39 @@ def test_full_pagerank_via_kernel_matches_dense():
     want = pagerank_dense_fixed(H, n_iters=30)
     np.testing.assert_allclose(np.asarray(pr), np.asarray(want), rtol=1e-4,
                                atol=1e-7)
+
+
+# --------------------------------------------------------------------------- #
+# ell_rows: the chunked sweep past the gather budget                          #
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("q", [None, 3])
+@pytest.mark.parametrize("storage", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_ell_rows_chunked_matches_one_shot(monkeypatch, storage, q):
+    """Past ``ELL_GATHER_BUDGET`` the rows are swept in chunks plus a
+    remainder; every row sum must equal the one-shot gather's, for a
+    vector and for an (n, Q) batch, in every storage dtype."""
+    rows, k, n = 100, 5, 70
+    rng = np.random.default_rng(q or 0)
+    data = rng.integers(-9, 10, (rows, k)) if storage == jnp.int8 \
+        else rng.normal(size=(rows, k))
+    data = jnp.asarray(data).astype(storage)
+    idx = jnp.asarray(rng.integers(0, n, (rows, k)), jnp.int32)
+    x = jnp.asarray(rng.normal(size=(n,) if q is None else (n, q)),
+                    jnp.float32)
+    def sweep():
+        # a fresh function: jit's trace cache must not hand back the
+        # program traced under the other budget
+        return jax.jit(lambda *a: common.ell_rows(*a))
+
+    one_shot = sweep()(data, idx, x)
+    # 12 KiB holds 24 vector rows or 8 batched rows: 100 rows leave a
+    # remainder either way
+    monkeypatch.setattr(common, "ELL_GATHER_BUDGET", 12 * 1024)
+    chunked = sweep()(data, idx, x)
+    assert "while" in sweep().lower(data, idx, x).as_text()
+    d64 = np.asarray(data.astype(jnp.float32), np.float64)
+    x64 = np.asarray(x, np.float64)
+    want = (d64 if q is None else d64[..., None]) * x64[np.asarray(idx)]
+    np.testing.assert_allclose(np.asarray(chunked), want.sum(axis=1),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(chunked), np.asarray(one_shot))

@@ -101,7 +101,7 @@ def test_hub_nodes_rank_highest():
 
 _DIST_SCRIPT = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import sys; sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -115,19 +115,19 @@ _DIST_SCRIPT = textwrap.dedent("""
     src, dst = gen.protein_network(n, seed=11)
     H = np.asarray(tr.build_transition_dense(src, dst, n))
     from repro.launch.mesh import make_mesh
-    mesh = make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     Hd = make_sharded_inputs_dense(jnp.asarray(H), mesh)
     pr = pagerank_distributed(Hd, mesh, n_iters=60)
     ref = pagerank_dense_fixed(jnp.asarray(H), n_iters=60)
     np.testing.assert_allclose(np.asarray(pr), np.asarray(ref), rtol=2e-4,
                                atol=1e-7)
 
-    ell = tr.build_transition_ell(src, dst, n, k=64)
+    from repro.pagerank import sell
     dang = jnp.asarray(tr.dangling_mask(src, n).astype(np.float32))
     spec = NamedSharding(mesh, P(("data", "model")))
-    data = jax.device_put(ell.data, spec)
-    idx = jax.device_put(ell.indices, spec)
-    pr2 = pagerank_distributed_sparse(data, idx, mesh, n_iters=60,
+    layout, _ = sell.build(tr.build_transition_csr(src, dst, n), n,
+                           shards=4, sharding=spec)
+    pr2 = pagerank_distributed_sparse(layout, mesh, n_iters=60,
                                       dangling=dang)
     np.testing.assert_allclose(np.asarray(pr2), np.asarray(ref), rtol=2e-4,
                                atol=1e-7)
@@ -136,7 +136,9 @@ _DIST_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_distributed_pagerank_16dev_subprocess():
+def test_distributed_pagerank_4dev_subprocess():
+    """Fabric and row-sharded schedules on a 2x2 mesh of four virtual CPU
+    devices (the shape of a four-chip host) vs the dense reference."""
     src_dir = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env = dict(os.environ)

@@ -93,13 +93,14 @@ def test_batched_ppr_pallas_matches_xla(net):
 
 
 def test_backend_auto_selection():
-    """Density/device routing: BSR above the sparsity threshold on TPU,
-    ELL for mid-sparsity, dense tiers for dense graphs.  n_devices pinned
-    to 1 — the suite runs under 8 virtual devices (conftest), where auto
-    picks the sharded tiers (tests/test_engine_sharded.py)."""
-    # sparsity >= 98% on TPU -> block-sparse rows
-    assert select_backend(5000, 0.004, device="tpu", n_devices=1) == "bsr"
-    assert select_backend(5000, 0.019, device="tpu", n_devices=1) == "bsr"
+    """Density/device routing: ELL for every sparse graph on every device
+    (bsr is selectable by name only), dense tiers for dense graphs.
+    n_devices pinned to 1 — the suite runs under 8 virtual devices
+    (conftest), where auto picks the sharded tiers
+    (tests/test_engine_sharded.py)."""
+    # very sparse graphs on TPU -> ELL, never the N^2-sized BSR layout
+    assert select_backend(5000, 0.004, device="tpu", n_devices=1) == "ell"
+    assert select_backend(5000, 0.019, device="tpu", n_devices=1) == "ell"
     # below the sparsity threshold (denser): ELL
     assert select_backend(5000, 0.05, device="tpu", n_devices=1) == "ell"
     # CPU: the block einsum loses to the ELL gather
@@ -124,15 +125,10 @@ def test_engine_auto_uses_selector(net):
         PageRankEngine(src, dst, n, backend="nope")
 
 
-def test_interpret_derived_from_device(net, monkeypatch):
+def test_interpret_derived_from_device(net):
     n, src, dst, _ = net
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     assert PageRankEngine(src, dst, n).interpret == (
         jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert PageRankEngine(src, dst, n).interpret is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert PageRankEngine(src, dst, n).interpret is True
 
 
 def test_containers_matmat_matches_matvec_columns(net):

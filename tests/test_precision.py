@@ -20,6 +20,7 @@ The contract under test:
 """
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -151,7 +152,7 @@ def test_dynamic_insert_then_delete_restores_bf16_bitexact(backend, net):
     def arrays():
         ops = (eng.operands if backend != "bsr"
                else (eng.operands[0].blocks, eng.operands[0].block_cols))
-        return [np.asarray(o) for o in ops]
+        return [np.asarray(o) for o in jax.tree.leaves(ops)]
 
     before = arrays()
     assert any(a.dtype == jnp.bfloat16 for a in before)
@@ -195,8 +196,8 @@ def test_dynamic_bf16_sell_push_parity_64_edges():
     pr, info = eng.update(delta, tol=1e-7)
     assert info.strategy == "push" and info.coerced_from is None
     assert info.n_inserted + info.n_deleted <= 64
-    # storage stayed bf16 through the patch
-    assert eng.operands[0].dtype == jnp.bfloat16
+    # storage stayed bf16 through the patch: every SELL tier's data
+    assert all(data.dtype == jnp.bfloat16 for data, _ in eng.operands[1])
 
     keys = eng._keys
     oracle = DynamicPageRankEngine((keys // n).astype(np.int32),

@@ -16,6 +16,7 @@ Layered like the subsystem itself:
 """
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -278,13 +279,13 @@ def test_snapshot_restore_roundtrip(net):
     dyn = DynamicPageRankEngine(src, dst, n, backend="ell")
     dyn.run_tol(1e-7, max_iters=500)
     snap = dyn.snapshot()
-    before = [np.asarray(o) for o in dyn.operands]
+    before = [np.asarray(o) for o in jax.tree.leaves(dyn.operands)]
     edges_before = dyn.n_edges
     iu, iv = _absent_pairs(src, dst, n, 2, seed=5)
     dyn.update(GraphDelta.inserts(iu, iv))
     dyn.restore(snap)
     assert dyn.n_edges == edges_before
-    for a, b in zip(before, dyn.operands):
+    for a, b in zip(before, jax.tree.leaves(dyn.operands)):
         np.testing.assert_array_equal(a, np.asarray(b))
     assert _l1(dyn.ranks, snap.ranks) == 0.0
 
