@@ -5,7 +5,8 @@ Reads the event stream a :class:`repro.obs.registry.MetricsRegistry`
 wrote (``jsonl_path=`` live appends or ``dump_jsonl``) and derives the
 serving story back out of it: query counts by freshness status, the
 refresh-ladder outcomes, dead-letter quarantines, solve verdicts, and the
-serve-latency distribution.
+serve-latency distribution, the spans (latency, and self time: a span's
+duration less its child spans'), and the compiles by the span they ran in.
 
 The latency quantiles are recomputed by feeding the ``serve`` events'
 ``ms`` values through the *same* :class:`repro.obs.registry.Histogram`
@@ -30,7 +31,12 @@ from collections import Counter
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
-from repro.obs.registry import DEFAULT_WINDOW, Histogram  # noqa: E402
+from repro.obs.registry import (DEFAULT_WINDOW,  # noqa: E402
+                                EVENT_SCHEMA_VERSION, Histogram)
+
+# slack on the containment test of a child span in its parent: start_ms
+# and t_ms are rounded to the microsecond
+_SPAN_SLACK_MS = 2e-3
 
 
 def load_events(path: str) -> list[dict]:
@@ -53,6 +59,11 @@ def derive(events: list[dict], window: int = DEFAULT_WINDOW) -> dict:
     batch_ms = Histogram(window)
     last_lag = None
     spans = {}
+    span_self_ms = Counter()
+    # spans whose parent has not ended yet, by the parent's name:
+    # (start_ms, end_ms, ms); a span ends before the span around it does
+    open_children: dict[str, list[tuple[float, float, float]]] = {}
+    compiles = Counter()
     # result-cache story (serve events carry the per-flush cache fields
     # only when a cache is attached; cache_invalidate events ride every
     # cache-aware refresh) — "active" flips when either appears
@@ -90,12 +101,34 @@ def derive(events: list[dict], window: int = DEFAULT_WINDOW) -> dict:
         elif kind == "span":
             spans.setdefault(ev["name"], Histogram(window)).observe(
                 ev["ms"])
+            span_self_ms[ev["name"]] += _self_ms(ev, open_children)
+        elif kind == "compile":
+            compiles[ev.get("span") or "(no span)"] += 1
     return {"queries": dict(queries), "refreshes": dict(refreshes),
             "solves": dict(solves), "dead_letters": dead_letters,
             "dead_reasons": dict(dead_reasons),
             "batch_ms": batch_ms, "freshness_lag_s": last_lag,
-            "spans": spans, "cache": cache,
+            "spans": spans, "span_self_ms": dict(span_self_ms),
+            "compiles": dict(compiles), "cache": cache,
             "cache_hit_ms": cache_hit_ms, "cache_miss_ms": cache_miss_ms}
+
+
+def _self_ms(ev: dict, open_children: dict) -> float:
+    """``ev``'s duration less that of the spans that ran inside it; a
+    schema-1 span, with no start or parent recorded, is all self time."""
+    ms = ev["ms"]
+    if "start_ms" not in ev:
+        return ms
+    start, end = ev["start_ms"], ev["start_ms"] + ms
+    inside, other = [], []
+    for c in open_children.pop(ev["name"], []):
+        (inside if c[0] >= start - _SPAN_SLACK_MS
+         and c[1] <= end + _SPAN_SLACK_MS else other).append(c)
+    if other:               # a same-named span open on another thread
+        open_children[ev["name"]] = other
+    if ev["parent"] is not None:
+        open_children.setdefault(ev["parent"], []).append((start, end, ms))
+    return ms - sum(c[2] for c in inside)
 
 
 def _fmt_hist(h: Histogram) -> str:
@@ -147,7 +180,12 @@ def render(d: dict) -> str:
     if d["spans"]:
         lines.append("-- spans --")
         for name in sorted(d["spans"]):
-            lines.append(f"  {name:<16} {_fmt_hist(d['spans'][name])}")
+            lines.append(f"  {name:<18} {_fmt_hist(d['spans'][name])}  "
+                         f"self_sum={d['span_self_ms'][name]:.3f}ms")
+    if d["compiles"]:
+        lines.append("-- compiles, by the span open --")
+        for name in sorted(d["compiles"]):
+            lines.append(f"  {name:<18} {d['compiles'][name]}")
     return "\n".join(lines)
 
 
@@ -209,8 +247,11 @@ def main(argv: list[str] | None = None) -> int:
                     help="histogram window the registry used")
     args = ap.parse_args(argv)
     events = load_events(args.jsonl)
-    bad = [e for e in events if e.get("v") != 1 or "t_ms" not in e
-           or "kind" not in e]
+    bad = [e for e in events
+           if e.get("v") not in range(1, EVENT_SCHEMA_VERSION + 1)
+           or "t_ms" not in e or "kind" not in e
+           or (e["kind"] == "span" and e["v"] >= 2
+               and not {"start_ms", "parent"} <= e.keys())]
     if bad:
         print(f"error: {len(bad)} malformed event(s), e.g. {bad[0]}",
               file=sys.stderr)

@@ -6,6 +6,14 @@ tiles may be stored in a reduced dtype (bf16 / f16 / int8), but every
 multiply-accumulate happens in float32.  :data:`F32_DOT` is the precision
 of the kernels' dots; :func:`ell_rows` is the one ELL row-sum every ELL
 layout (split, sliced, row-sharded) sweeps with.
+
+The hot path's kernels run under fixed ``jax.named_scope`` names
+(``pagerank.ell_gather`` here; ``pagerank.coo_tail``, ``pagerank.sell_order``,
+``pagerank.vector``, ``pagerank.push`` and ``pagerank.row_patch`` in the
+engine, SELL, step and dynamic modules).  A scope is op metadata only: the
+compiled instructions do not change, and a profiler trace names each
+device op by its innermost ``pagerank.*`` scope whatever number the
+compiler gave its fusion.
 """
 from __future__ import annotations
 
@@ -48,26 +56,30 @@ def ell_rows(data: jax.Array, idx: jax.Array, x: jax.Array) -> jax.Array:
     chip, so past :data:`ELL_GATHER_BUDGET` bytes the rows are swept in
     chunks that each fit it.  Below the budget the program is the one-shot
     form, unchanged."""
-    rows, k = data.shape
+    with jax.named_scope("pagerank.ell_gather"):
+        rows, k = data.shape
 
-    def part(d, i):
-        d = upcast_f32(d)
-        return jnp.sum((d if x.ndim == 1 else d[..., None]) * x[i], axis=1)
+        def part(d, i):
+            d = upcast_f32(d)
+            return jnp.sum((d if x.ndim == 1 else d[..., None]) * x[i],
+                           axis=1)
 
-    row_bytes = 4 * (_tile(k, 128) if x.ndim == 1
-                     else _tile(k, 8) * _tile(x.shape[1], 128))
-    r = max(8, ELL_GATHER_BUDGET // max(row_bytes, 1) // 8 * 8)
-    if r >= rows:
-        return part(data, idx)
-    n_full = rows // r
+        row_bytes = 4 * (_tile(k, 128) if x.ndim == 1
+                         else _tile(k, 8) * _tile(x.shape[1], 128))
+        r = max(8, ELL_GATHER_BUDGET // max(row_bytes, 1) // 8 * 8)
+        if r >= rows:
+            return part(data, idx)
+        n_full = rows // r
 
-    def body(b, y):
-        d = jax.lax.dynamic_slice_in_dim(data, b * r, r)
-        i = jax.lax.dynamic_slice_in_dim(idx, b * r, r)
-        return jax.lax.dynamic_update_slice_in_dim(y, part(d, i), b * r, 0)
+        def body(b, y):
+            d = jax.lax.dynamic_slice_in_dim(data, b * r, r)
+            i = jax.lax.dynamic_slice_in_dim(idx, b * r, r)
+            return jax.lax.dynamic_update_slice_in_dim(y, part(d, i),
+                                                       b * r, 0)
 
-    y = jax.lax.fori_loop(0, n_full, body,
-                          jnp.zeros((rows,) + x.shape[1:], jnp.float32))
-    if rows % r:
-        y = y.at[n_full * r:].set(part(data[n_full * r:], idx[n_full * r:]))
-    return y
+        y = jax.lax.fori_loop(0, n_full, body,
+                              jnp.zeros((rows,) + x.shape[1:], jnp.float32))
+        if rows % r:
+            y = y.at[n_full * r:].set(part(data[n_full * r:],
+                                           idx[n_full * r:]))
+        return y

@@ -14,7 +14,6 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.models import model as M
-from repro.obs.registry import default_registry
 from repro.serve import Request, ServeEngine
 
 
@@ -45,7 +44,6 @@ def run(argv=None):
     t0 = time.perf_counter()
     engine.serve(reqs, n_slots=args.slots)
     dt = time.perf_counter() - t0
-    default_registry().histogram("launch.serve_batch_ms").observe(dt * 1e3)
     total_tokens = sum(len(r.output) for r in reqs)
     print(f"served {len(reqs)} requests, {total_tokens} tokens "
           f"in {dt:.2f}s ({total_tokens / dt:.1f} tok/s)")

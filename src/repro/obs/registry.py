@@ -19,11 +19,14 @@ observability layer (the on-device half is :mod:`repro.obs.trace`):
   :meth:`~MetricsRegistry.span` context manager (wall-time via
   ``perf_counter`` into a ``span.<name>`` histogram + a ``span`` event,
   optionally forwarding to ``jax.profiler.TraceAnnotation`` so spans land
-  in device profiles too), and an append-only event log with monotonic
-  timestamps — written live to a JSONL file when ``jsonl_path`` is given.
-* :class:`NullRegistry` — the same surface as no-ops: the uninstrumented
-  baseline ``benchmarks/observability_bench.py`` measures against, and the
-  zero-overhead opt-out for latency-critical deployments.
+  in device profiles too; each ``span`` event records its start and the
+  span that was open around it), a compile watcher
+  (:meth:`~MetricsRegistry.watch_compiles`: a ``compiles`` counter and a
+  ``compile`` event naming the span that compiled), and an append-only
+  event log with monotonic timestamps — written live to a JSONL file when
+  ``jsonl_path`` is given.
+* :class:`NullRegistry` — the same surface as no-ops: the zero-overhead
+  opt-out for latency-critical deployments.
 
 Every instrument is exported by :meth:`MetricsRegistry.as_dict` as a
 stable, ``json.dumps``-safe dict (sorted names, plain scalars), so
@@ -33,7 +36,9 @@ from __future__ import annotations
 
 import json
 import math
+import threading
 import time
+import weakref
 from collections import deque
 from contextlib import contextmanager
 
@@ -43,7 +48,14 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
 
 DEFAULT_WINDOW = 2048          # histogram ring size (last-K observations)
 MAX_EVENTS = 100_000           # in-memory event bound (JSONL file unbounded)
-EVENT_SCHEMA_VERSION = 1       # bump when an event's key set changes
+EVENT_SCHEMA_VERSION = 2       # bump when an event's key set changes
+
+# JAX's monitoring events around a compile: the backend-compile duration
+# fires when a program was compiled OR loaded from the persistent cache,
+# and a cache hit is reported (before it, on the same thread) for the
+# latter, so real compiles are the first less the second
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class Counter:
@@ -131,8 +143,8 @@ class MetricsRegistry:
 
     ``profiler_annotations=True`` additionally wraps every :meth:`span` in
     ``jax.profiler.TraceAnnotation`` so host spans show up in device
-    traces; off by default (it is free only when no profiler is attached,
-    and the observability bench measures the default configuration).
+    traces, on the device trace's clock; off by default (it is free only
+    when no profiler is attached).
     """
 
     def __init__(self, jsonl_path: str | None = None,
@@ -149,6 +161,8 @@ class MetricsRegistry:
         self._t0 = time.monotonic()
         self.jsonl_path = jsonl_path
         self._fh = None
+        # the open spans of each thread, innermost last
+        self._open = threading.local()
 
     # ---------------------------- instruments --------------------------- #
     def counter(self, name: str) -> Counter:
@@ -193,16 +207,29 @@ class MetricsRegistry:
             self._fh.flush()
         return ev
 
+    def _stack(self) -> list[str]:
+        stack = getattr(self._open, "names", None)
+        if stack is None:
+            stack = self._open.names = []
+        return stack
+
     @contextmanager
     def span(self, name: str, **fields):
         """Time a block into the ``span.<name>`` histogram + a ``span``
         event (recorded even if the block raises, so failed refreshes and
-        aborted solves still leave a latency sample)."""
+        aborted solves still leave a latency sample).  The event carries
+        ``start_ms`` (on the event clock of ``t_ms``) and ``parent``: the
+        name of the span open around this one on the same thread, or
+        ``None``."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        stack.append(name)
         ann = None
         if self.profiler_annotations:
             from jax.profiler import TraceAnnotation
             ann = TraceAnnotation(name)
             ann.__enter__()
+        start_ms = round((time.monotonic() - self._t0) * 1e3, 3)
         t0 = time.perf_counter()
         try:
             yield
@@ -210,8 +237,26 @@ class MetricsRegistry:
             if ann is not None:
                 ann.__exit__(None, None, None)
             ms = (time.perf_counter() - t0) * 1e3
+            stack.pop()
             self.histogram(f"span.{name}").observe(ms)
-            self.event("span", name=name, ms=ms, **fields)
+            self.event("span", name=name, ms=ms, start_ms=start_ms,
+                       parent=parent, **fields)
+
+    def watch_compiles(self) -> None:
+        """Count every XLA compile of the process from now on into the
+        ``compiles`` counter (a program loaded from the persistent
+        compilation cache is not a compile), with one ``compile`` event
+        each naming the function and the innermost span open on the
+        compiling thread.  Idempotent; the counter exists (at 0) from the
+        first call, so a window with no compile reads 0."""
+        self.counter("compiles")
+        _watch(self)
+
+    def _on_compile(self, fun: str | None, seconds: float) -> None:
+        stack = self._stack()
+        self.counter("compiles").inc()
+        self.event("compile", fun=fun, s=seconds,
+                   span=stack[-1] if stack else None)
 
     # ------------------------------ export ------------------------------ #
     def as_dict(self) -> dict:
@@ -285,6 +330,42 @@ class NullRegistry(MetricsRegistry):
     @contextmanager
     def span(self, name: str, **fields):
         yield
+
+    def watch_compiles(self) -> None:
+        pass
+
+
+# JAX's monitoring listeners are process-wide and are never removed, so
+# one pair is installed, on the first watch, for every watching registry
+_watching: "weakref.WeakSet[MetricsRegistry]" = weakref.WeakSet()
+_listening = False
+_compile_tls = threading.local()      # cache hits not yet matched
+
+
+def _watch(reg: MetricsRegistry) -> None:
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_listener(_on_jax_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _listening = True
+    _watching.add(reg)
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        _compile_tls.hits = getattr(_compile_tls, "hits", 0) + 1
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    if getattr(_compile_tls, "hits", 0):
+        _compile_tls.hits -= 1            # loaded from the cache
+        return
+    for reg in list(_watching):
+        reg._on_compile(kw.get("fun_name"), duration)
 
 
 _default: MetricsRegistry | None = None

@@ -190,9 +190,24 @@ def _scatter_rows(A, pos, rows, *, sharding=None):
         p, r = args
         return A.at[p].set(r), None
 
-    A, _ = jax.lax.scan(body, A, (pos, rows))
-    return (A if sharding is None
-            else jax.lax.with_sharding_constraint(A, sharding))
+    with jax.named_scope("pagerank.row_patch"):
+        A, _ = jax.lax.scan(body, A, (pos, rows))
+        return (A if sharding is None
+                else jax.lax.with_sharding_constraint(A, sharding))
+
+
+@jax.jit
+def _scatter_mask(mask, ci, flags):
+    """mask[..., ci_c] = flags_c for every chunk c; ci, flags (k, cap):
+    the dangling-mask patch, of a (n,) mask or the Pallas tier's (1, Mp)
+    one."""
+    def body(m, args):
+        i, f = args
+        return m.at[..., i].set(f), None
+
+    with jax.named_scope("pagerank.row_patch"):
+        mask, _ = jax.lax.scan(body, mask, (ci, flags))
+    return mask
 
 
 @partial(jax.jit, static_argnames=("n", "sharding"))
@@ -206,9 +221,10 @@ def _scatter_cols(H, ci, mats, *, n: int, sharding=None):
         i, m = args
         return H.at[:n, i].set(m.T), None
 
-    H, _ = jax.lax.scan(body, H, (ci, mats))
-    return (H if sharding is None
-            else jax.lax.with_sharding_constraint(H, sharding))
+    with jax.named_scope("pagerank.row_patch"):
+        H, _ = jax.lax.scan(body, H, (ci, mats))
+        return (H if sharding is None
+                else jax.lax.with_sharding_constraint(H, sharding))
 
 
 @jax.jit
@@ -220,7 +236,8 @@ def _scatter_block_vals(B, br, sl, lr, lc, vals):
         b, s, r, c, v = args
         return B.at[b, s, r, c].set(v), None
 
-    B, _ = jax.lax.scan(body, B, (br, sl, lr, lc, vals))
+    with jax.named_scope("pagerank.row_patch"):
+        B, _ = jax.lax.scan(body, B, (br, sl, lr, lc, vals))
     return B
 
 
@@ -251,11 +268,13 @@ def _push_loop(Ab, x0, tol, n, max_pushes, trace=False):
 
     def step(state):
         x, r = state
-        x = x + r * (jnp.abs(r) >= thresh).astype(x.dtype)
-        r = Ab(x) - x
-        return (x, r), jnp.sum(jnp.abs(r))
+        with jax.named_scope("pagerank.push"):
+            x = x + r * (jnp.abs(r) >= thresh).astype(x.dtype)
+            r = Ab(x) - x
+            return (x, r), jnp.sum(jnp.abs(r))
 
-    r0 = Ab(x0) - x0
+    with jax.named_scope("pagerank.push"):
+        r0 = Ab(x0) - x0
     (x, _), iters, res, grow, ring = instrumented_tol_loop(
         step, (x0, r0), tol=tol, max_iters=max_pushes, watchdog=True,
         trace=trace, res0=jnp.sum(jnp.abs(r0)))
@@ -276,8 +295,9 @@ def _push_tol(operands, dang, d, tol, x0, *, backend: str, n: int,
             return d * (operands[0] @ x) + (1.0 - d) / n
     else:
         def Ab(x):
-            return d * (_matvec(backend, operands, x)
-                        + jnp.sum(x * dang) / n) + (1.0 - d) / n
+            with jax.named_scope("pagerank.vector"):
+                return d * (_matvec(backend, operands, x)
+                            + jnp.sum(x * dang) / n) + (1.0 - d) / n
 
     return _push_loop(Ab, x0, tol, n, max_pushes, trace=trace)
 
@@ -491,9 +511,12 @@ class DynamicPageRankEngine(PageRankEngine):
 
         Every update lands in the engine's metrics registry: an
         ``update.<strategy>`` counter (``noop`` included), the overall
-        ``span.update`` latency histogram, per-strategy
-        ``span.update.patch`` / ``span.update.rebuild`` layout timings,
-        and one ``update`` event with the delta size and solve verdict.
+        ``span.update`` latency histogram, the host planning spans
+        ``update.plan`` (children ``update.plan.keys`` and
+        ``update.plan.rows``) and ``update.commit``, per-strategy
+        ``span.update.patch`` (child ``update.patch.rows``, the host row
+        rebuild) / ``span.update.rebuild`` layout timings, and one
+        ``update`` event with the delta size and solve verdict.
         When capacity overflow forces the auto policy to rebuild where the
         size policy wanted a patch, the coercion is recorded on
         ``UpdateInfo.coerced_from`` plus an ``update.coerced`` counter and
@@ -520,7 +543,8 @@ class DynamicPageRankEngine(PageRankEngine):
                 ) -> tuple[jax.Array, UpdateInfo]:
         if strategy not in ("auto", "push", "warm", "rebuild"):
             raise ValueError(f"unknown strategy {strategy!r}")
-        plan = self._plan(delta)
+        with self.metrics.span("update.plan"):
+            plan = self._plan(delta)
         if plan is None:
             if self._pr is None:
                 self.run_tol(tol=tol, max_iters=max_iters)
@@ -563,7 +587,8 @@ class DynamicPageRankEngine(PageRankEngine):
         # replaced, never mutated in place, on the update path.
         state = dict(self.__dict__)
         try:
-            self._commit(plan)
+            with self.metrics.span("update.commit"):
+                self._commit(plan)
             if strategy == "rebuild":
                 with self.metrics.span("update.rebuild"):
                     self._rebuild()
@@ -605,66 +630,75 @@ class DynamicPageRankEngine(PageRankEngine):
         """Canonicalize the delta against the current edge set and compute
         the patch plan (affected rows/columns, post-delta key sets and
         degrees, overflow flag) WITHOUT touching any engine state — or
-        return ``None`` for an effective no-op.  ``_commit`` applies it."""
-        n = self.n
-        delta = delta.canonical(n, symmetric=self.symmetric)
-        ins = edge_keys(delta.insert_src, delta.insert_dst, n)
-        dels = edge_keys(delta.delete_src, delta.delete_dst, n)
-        eff_ins = ins[~_in_sorted(self._keys, ins)]
-        eff_del = dels[_in_sorted(self._keys, dels)]
-        eff_del = eff_del[~_in_sorted(ins, eff_del)]   # delete-then-insert
-        changed = np.concatenate([eff_ins, eff_del])
-        if len(changed) == 0:
-            return None
-        new_keys = np.union1d(
-            np.setdiff1d(self._keys, eff_del, assume_unique=True), eff_ins)
-        rkey = lambda k: (k % n) * np.int64(n) + k // n
-        new_rkeys = np.union1d(
-            np.setdiff1d(self._rkeys, rkey(eff_del), assume_unique=True),
-            rkey(eff_ins))
-        outdeg, indeg = self._outdeg.copy(), self._indeg.copy()
-        np.add.at(outdeg, (eff_ins // n), 1)
-        np.add.at(outdeg, (eff_del // n), -1)
-        np.add.at(indeg, (eff_ins % n), 1)
-        np.add.at(indeg, (eff_del % n), -1)
+        return ``None`` for an effective no-op.  ``_commit`` applies it.
 
-        cols = np.unique(changed // n)
-        rows = np.empty(0, np.int64)
-        overflow = False
-        extra: dict = {}
-        if self.backend in ("ell", "ell_sharded"):
-            # only the row-major layouts patch rows (dense tiers rewrite
-            # whole columns, BSR individual block entries), so only they
-            # pay the neighbor scans
-            parts = [changed % n]
-            for u in cols:
-                parts.append(_key_slice(self._keys, int(u), n))
-                parts.append(_key_slice(new_keys, int(u), n))
-            rows = np.unique(np.concatenate(parts))
-            cap = np.asarray(self._sell.widths)[self._sell.tier[rows]]
-            overflow = bool((indeg[rows] > cap).any())
-        elif self.backend == "bsr":
-            # per changed column: its old and new out-neighbor sets (both
-            # sorted — _key_slice walks the sorted keys).  Every entry the
-            # patch touches lives in block (v//bs, u//bs); old entries are
-            # in existing blocks by construction, so only the post-delta
-            # sets can demand a block the structure doesn't hold — that is
-            # the genuine structure change that forces a rebuild.
-            bs = int(self._operands[0].block_size)
-            old_nbrs = [_key_slice(self._keys, int(u), n) for u in cols]
-            new_nbrs = [_key_slice(new_keys, int(u), n) for u in cols]
-            need = [(vv // bs) * np.int64(self._bsr_nbc) + int(u) // bs
-                    for u, vv in zip(cols, new_nbrs) if len(vv)]
-            if need:
-                need = np.unique(np.concatenate(need))
-                overflow = not bool(_in_sorted(self._bsr_pairs, need).all())
-            extra = {"bsr_old": old_nbrs, "bsr_new": new_nbrs}
-        return {"cols": cols, "rows": rows, "overflow": overflow,
-                "n_ins": len(eff_ins), "n_del": len(eff_del),
-                "n_changed": len(changed),
-                "n_edges_before": len(self._keys),
-                "keys": new_keys, "rkeys": new_rkeys,
-                "outdeg": outdeg, "indeg": indeg, **extra}
+        Its two host steps are the spans ``update.plan.keys`` (the delta's
+        effective edges and the post-delta key sets and degrees) and
+        ``update.plan.rows`` (the rows or blocks each changed column
+        touches, and the capacity test)."""
+        n = self.n
+        with self.metrics.span("update.plan.keys"):
+            delta = delta.canonical(n, symmetric=self.symmetric)
+            ins = edge_keys(delta.insert_src, delta.insert_dst, n)
+            dels = edge_keys(delta.delete_src, delta.delete_dst, n)
+            eff_ins = ins[~_in_sorted(self._keys, ins)]
+            eff_del = dels[_in_sorted(self._keys, dels)]
+            eff_del = eff_del[~_in_sorted(ins, eff_del)]  # delete-then-insert
+            changed = np.concatenate([eff_ins, eff_del])
+            if len(changed) == 0:
+                return None
+            new_keys = np.union1d(
+                np.setdiff1d(self._keys, eff_del, assume_unique=True),
+                eff_ins)
+            rkey = lambda k: (k % n) * np.int64(n) + k // n
+            new_rkeys = np.union1d(
+                np.setdiff1d(self._rkeys, rkey(eff_del), assume_unique=True),
+                rkey(eff_ins))
+            outdeg, indeg = self._outdeg.copy(), self._indeg.copy()
+            np.add.at(outdeg, (eff_ins // n), 1)
+            np.add.at(outdeg, (eff_del // n), -1)
+            np.add.at(indeg, (eff_ins % n), 1)
+            np.add.at(indeg, (eff_del % n), -1)
+        with self.metrics.span("update.plan.rows"):
+            cols = np.unique(changed // n)
+            rows = np.empty(0, np.int64)
+            overflow = False
+            extra: dict = {}
+            if self.backend in ("ell", "ell_sharded"):
+                # only the row-major layouts patch rows (dense tiers
+                # rewrite whole columns, BSR individual block entries), so
+                # only they pay the neighbor scans
+                parts = [changed % n]
+                for u in cols:
+                    parts.append(_key_slice(self._keys, int(u), n))
+                    parts.append(_key_slice(new_keys, int(u), n))
+                rows = np.unique(np.concatenate(parts))
+                cap = np.asarray(self._sell.widths)[self._sell.tier[rows]]
+                overflow = bool((indeg[rows] > cap).any())
+            elif self.backend == "bsr":
+                # per changed column: its old and new out-neighbor sets
+                # (both sorted — _key_slice walks the sorted keys).  Every
+                # entry the patch touches lives in block (v//bs, u//bs); old
+                # entries are in existing blocks by construction, so only
+                # the post-delta sets can demand a block the structure
+                # doesn't hold — that is the genuine structure change that
+                # forces a rebuild.
+                bs = int(self._operands[0].block_size)
+                old_nbrs = [_key_slice(self._keys, int(u), n) for u in cols]
+                new_nbrs = [_key_slice(new_keys, int(u), n) for u in cols]
+                need = [(vv // bs) * np.int64(self._bsr_nbc) + int(u) // bs
+                        for u, vv in zip(cols, new_nbrs) if len(vv)]
+                if need:
+                    need = np.unique(np.concatenate(need))
+                    overflow = not bool(
+                        _in_sorted(self._bsr_pairs, need).all())
+                extra = {"bsr_old": old_nbrs, "bsr_new": new_nbrs}
+            return {"cols": cols, "rows": rows, "overflow": overflow,
+                    "n_ins": len(eff_ins), "n_del": len(eff_del),
+                    "n_changed": len(changed),
+                    "n_edges_before": len(self._keys),
+                    "keys": new_keys, "rkeys": new_rkeys,
+                    "outdeg": outdeg, "indeg": indeg, **extra}
 
     def _commit(self, plan: dict) -> None:
         """Swap in the post-delta bookkeeping computed by ``_plan`` (only
@@ -699,9 +733,9 @@ class DynamicPageRankEngine(PageRankEngine):
         n = self.n
         cols = plan["cols"]
         flags = (self._outdeg[cols] == 0).astype(np.float32)
-        dang = self._dang
-        for ci, f in _chunks(cols, flags, cap=32):
-            dang = dang.at[jnp.asarray(ci)].set(jnp.asarray(f))
+        mask = tuple(jnp.asarray(a)
+                     for a in _stack_chunks(cols, flags, cap=32))
+        dang = _scatter_mask(self._dang, *mask)
         if self.mesh is not None:
             # the sharded tiers keep the dangling mask replicated; pin the
             # patched copy back to P() so no runner pays a reshard
@@ -735,14 +769,14 @@ class DynamicPageRankEngine(PageRankEngine):
             ci, mats = _stack_chunks(cols, mat, cap=32)
             Hp = _scatter_cols(Hp, jnp.asarray(ci),
                                jnp.asarray(mats).astype(Hp.dtype), n=n)
-            for ci, f in _chunks(cols, flags, cap=32):
-                dangp = dangp.at[0, jnp.asarray(ci)].set(jnp.asarray(f))
-            self._operands = (Hp, dangp)
+            self._operands = (Hp, _scatter_mask(dangp, *mask))
             return 0, len(cols)
         # ell / ell_sharded: rewrite every affected SELL row in its tier
         # (vectorized: one gather over the reverse key set builds all rows
         # at once); on the mesh each scatter stays on its tier's row
-        # sharding, so every write lands on the device owning the row
+        # sharding, so every write lands on the device owning the row.
+        # Each tier's host rebuild is an ``update.patch.rows`` span; the
+        # scatters it then dispatches run under ``update.patch`` itself
         rows = plan["rows"]
         inv, tiers = self._operands
         tiers = list(tiers)
@@ -750,9 +784,10 @@ class DynamicPageRankEngine(PageRankEngine):
             sel = rows[self._sell.tier[rows] == t]
             if len(sel) == 0:
                 continue
-            data, idx = self._rebuild_rows(sel, k)
-            pos, dat, ix = _stack_chunks(self._sell.pos[sel], data, idx,
-                                         cap=512 if t == 0 else 64)
+            with self.metrics.span("update.patch.rows"):
+                data, idx = self._rebuild_rows(sel, k)
+                pos, dat, ix = _stack_chunks(self._sell.pos[sel], data, idx,
+                                             cap=512 if t == 0 else 64)
             pos = jnp.asarray(pos)
             d, i = tiers[t]
             sharding = None if self.mesh is None else d.sharding

@@ -201,9 +201,10 @@ def _matvec(backend: str, operands, x: jax.Array) -> jax.Array:
         scales = operands[5] if len(operands) == 6 else None
         ov_v = upcast_f32(ov_v)
         y = ell_rows(data, idx, x)
-        tail = jax.ops.segment_sum(
-            (ov_v if x.ndim == 1 else ov_v[:, None]) * x[ov_c], ov_r,
-            num_segments=data.shape[0])
+        with jax.named_scope("pagerank.coo_tail"):
+            tail = jax.ops.segment_sum(
+                (ov_v if x.ndim == 1 else ov_v[:, None]) * x[ov_c], ov_r,
+                num_segments=data.shape[0])
         return _row_scale(y + tail, scales)
     if backend == "sell":
         # sliced ELLPACK, the dynamic engine's patchable ELL tier: the
@@ -242,7 +243,8 @@ def _run_tol(operands, dang, d, tol, x0, *, backend: str, n: int,
     def step(pr):
         new = sparse_step(lambda v: _matvec(backend, operands, v),
                           pr, dang, d, n)
-        return new, jnp.sum(jnp.abs(new - pr))
+        with jax.named_scope("pagerank.vector"):
+            return new, jnp.sum(jnp.abs(new - pr))
 
     return instrumented_tol_loop(step, pr0, tol=tol, max_iters=max_iters,
                                  watchdog=watchdog, trace=trace)
@@ -484,8 +486,9 @@ class PageRankEngine:
         self.last_solve_info = None
         self._warned_nonconverged = False
         # metrics sink: the process default registry unless injected (a
-        # NullRegistry injects the uninstrumented overhead baseline)
+        # NullRegistry records nothing)
         self.metrics = metrics if metrics is not None else default_registry()
+        self.metrics.watch_compiles()
         with self.metrics.span("prepare", backend=self.backend):
             self._prepare_layout(src, dst)
 
@@ -831,8 +834,11 @@ class PageRankEngine:
         On ``dense_sharded`` the query axis is sharded across the mesh
         (padded up to the shard count with zero columns, sliced back); on
         ``ell_sharded`` every device sweeps its own rows for all queries,
-        so a multi-user serve flush spreads over devices either way."""
-        with self.metrics.span("ppr", backend=self.backend,
+        so a multi-user serve flush spreads over devices either way.
+
+        The result is not waited for, so its ``ppr.dispatch`` span times
+        the dispatch alone; the caller's host read ends the solve."""
+        with self.metrics.span("ppr.dispatch", backend=self.backend,
                                q=len(seed_sets)):
             self.metrics.counter("engine.ppr_queries").inc(len(seed_sets))
             return self._ppr(seed_sets, n_iters)

@@ -204,8 +204,6 @@ class LandmarkIndex:
             self._hub_pos[hubs] = np.arange(k)
             self.hubs = hubs
             self.built_version = int(version)
-        self.metrics.counter("landmarks.builds").inc()
-        self.metrics.gauge("landmarks.hubs").set(k)
 
     # ---------------------------- estimate ----------------------------- #
     def estimate(self, seed_sets) -> tuple[np.ndarray, list[float]]:
@@ -289,7 +287,6 @@ class LandmarkIndex:
                     int(bad.size))
             X = np.clip(X, 0.0, None)
             X /= X.sum(axis=0, keepdims=True)
-        self.metrics.counter("landmarks.queries").inc(q)
         bad_set = set(int(j) for j in bad)
         return X, {"sweeps": int(sweeps), "fallbacks": int(bad.size),
                    "paths": ["exact" if j in bad_set else "hub"

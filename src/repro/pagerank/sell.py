@@ -123,4 +123,5 @@ def sell_rows(layout, x: jax.Array) -> jax.Array:
         if scale:
             y = y * (scale[0] if y.ndim == 1 else scale[0][:, None])
         ys.append(y)
-    return jnp.concatenate(ys, axis=0)[inv]
+    with jax.named_scope("pagerank.sell_order"):
+        return jnp.concatenate(ys, axis=0)[inv]

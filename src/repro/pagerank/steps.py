@@ -23,16 +23,21 @@ def dense_step(H: jax.Array, pr: jax.Array, d: float) -> jax.Array:
 
 def sparse_step(matvec: Callable[[jax.Array], jax.Array], pr: jax.Array,
                 dang: jax.Array, d: float, n: int) -> jax.Array:
-    """One power iteration with the explicit dangling-leak correction."""
-    leak = jnp.sum(pr * dang) / n
-    return d * (matvec(pr) + leak) + (1.0 - d) / n
+    """One power iteration with the explicit dangling-leak correction.
+
+    The vector passes (leak, damping) run under the ``pagerank.vector``
+    scope; the ELL and SELL matvecs open their own scopes inside it."""
+    with jax.named_scope("pagerank.vector"):
+        leak = jnp.sum(pr * dang) / n
+        return d * (matvec(pr) + leak) + (1.0 - d) / n
 
 
 def ppr_step(matvec: Callable[[jax.Array], jax.Array], pr: jax.Array,
              v: jax.Array, dang: jax.Array, d: float) -> jax.Array:
     """One personalized step: teleport (and leak) flow to ``v``, not 1/n."""
-    leak = jnp.sum(pr * dang)
-    return d * (matvec(pr) + leak * v) + (1.0 - d) * v
+    with jax.named_scope("pagerank.vector"):
+        leak = jnp.sum(pr * dang)
+        return d * (matvec(pr) + leak * v) + (1.0 - d) * v
 
 
 def ppr_step_batched(matvec: Callable[[jax.Array], jax.Array],
@@ -40,8 +45,9 @@ def ppr_step_batched(matvec: Callable[[jax.Array], jax.Array],
                      d: float) -> jax.Array:
     """Batched personalized step: ``PR``/``V`` are (N, Q); Q queries share
     the single sweep over H inside ``matvec``."""
-    leak = jnp.sum(PR * dang[:, None], axis=0)            # (Q,)
-    return d * (matvec(PR) + V * leak[None, :]) + (1.0 - d) * V
+    with jax.named_scope("pagerank.vector"):
+        leak = jnp.sum(PR * dang[:, None], axis=0)        # (Q,)
+        return d * (matvec(PR) + V * leak[None, :]) + (1.0 - d) * V
 
 
 def seed_matrix(n: int, seed_sets: Sequence[np.ndarray]) -> np.ndarray:

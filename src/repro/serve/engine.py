@@ -497,8 +497,8 @@ class PageRankQueryEngine:
                 m.histogram("serve.cache.hit_ms").observe(st["hit_ms"])
             if st.get("miss_ms") is not None:
                 m.histogram("serve.cache.miss_ms").observe(st["miss_ms"])
-            # additive optional fields: the event schema stays v=1 and
-            # cache-less logs are byte-identical to before
+            # additive optional fields: cache-less serve events keep the
+            # key set they always had
             extra = dict(cache_hits=st.get("hits", 0),
                          cache_misses=st.get("misses", 0),
                          cache_evictions=st.get("evictions", 0),

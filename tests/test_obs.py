@@ -78,9 +78,12 @@ def test_jsonl_event_schema_golden(tmp_path):
     reg = MetricsRegistry(jsonl_path=path)
     reg.event("serve", ms=1.25, batch=4, status="fresh")
     reg.event("refresh", status="ok", applied=True)
+    with reg.span("work", tag="x"):
+        pass
     reg.close()
     lines = [json.loads(line) for line in open(path)]
-    assert len(lines) == 2
+    assert len(lines) == 3
+    assert EVENT_SCHEMA_VERSION == 2
     for ev in lines:
         # golden schema: version, monotonic relative timestamp, kind, then
         # the caller's fields in sorted key order
@@ -91,6 +94,10 @@ def test_jsonl_event_schema_golden(tmp_path):
         assert isinstance(ev["t_ms"], (int, float)) and ev["t_ms"] >= 0
     assert lines[0]["kind"] == "serve" and lines[0]["batch"] == 4
     assert lines[1]["t_ms"] >= lines[0]["t_ms"]      # monotonic
+    # schema 2: a span records its start and the span open around it
+    assert list(lines[2]) == ["v", "t_ms", "kind", "ms", "name", "parent",
+                              "start_ms", "tag"]
+    assert lines[2]["parent"] is None
     # the in-memory log and the file agree
     assert reg.events == lines
 
@@ -270,7 +277,7 @@ def test_engine_metrics_counters_and_events():
     assert d["counters"]["update.push"] == 1
     assert d["counters"]["engine.ppr_queries"] == 2
     for span in ("span.prepare", "span.solve", "span.update",
-                 "span.update.patch", "span.ppr"):
+                 "span.update.patch", "span.ppr.dispatch"):
         assert d["histograms"][span]["count"] >= 1, span
     kinds = [e["kind"] for e in reg.events]
     assert "solve" in kinds and "update" in kinds
@@ -360,3 +367,213 @@ def test_engine_default_registry_shared_with_serve():
     eng = PageRankEngine(src, dst, n, backend="dense", metrics=reg)
     server = PageRankQueryEngine(eng, n_iters=5)
     assert server.metrics is reg
+
+
+# --------------------------------------------------------------------------- #
+# span records, refresh spans, compile watch, named scopes                    #
+# --------------------------------------------------------------------------- #
+def test_span_event_records_start_and_parent():
+    reg = MetricsRegistry()
+    with reg.span("outer"):
+        with reg.span("inner"):
+            pass
+        with reg.span("inner"):
+            pass
+    with reg.span("after"):
+        pass
+    spans = [e for e in reg.events if e["kind"] == "span"]
+    assert [(e["name"], e["parent"]) for e in spans] == [
+        ("inner", "outer"), ("inner", "outer"), ("outer", None),
+        ("after", None)]
+    inner, inner2, outer, after = spans
+    # start_ms is on the event clock: a span starts inside its parent and
+    # ends (t_ms) no earlier than it started plus its duration, rounded
+    assert outer["start_ms"] <= inner["start_ms"] <= inner2["start_ms"]
+    assert inner2["start_ms"] + inner2["ms"] <= outer["t_ms"] + 1e-3
+    assert after["start_ms"] >= outer["t_ms"] - 1e-3
+    for e in spans:
+        assert e["t_ms"] >= e["start_ms"]
+
+
+def test_span_stack_survives_a_raise():
+    reg = MetricsRegistry()
+    with pytest.raises(RuntimeError):
+        with reg.span("fails"):
+            raise RuntimeError("x")
+    with reg.span("next"):
+        pass
+    assert [(e["name"], e["parent"]) for e in reg.events] == [
+        ("fails", None), ("next", None)]
+
+
+def test_update_emits_refresh_spans_with_parents():
+    n = 48
+    src, dst = _graph(n)
+    reg = MetricsRegistry()
+    eng = DynamicPageRankEngine(src, dst, n, backend="ell", metrics=reg)
+    eng.run_tol(1e-6)
+    have = set(zip(src.tolist(), dst.tolist()))
+    new = [(u, v) for u in range(n) for v in range(n)
+           if u != v and (u, v) not in have][:2]
+    start = len(reg.events)
+    _, info = eng.update(GraphDelta.inserts([u for u, _ in new],
+                                            [v for _, v in new]))
+    assert info.strategy == "push"
+    parents = {}
+    for e in reg.events[start:]:
+        if e["kind"] == "span":
+            parents.setdefault(e["name"], set()).add(e["parent"])
+    assert parents["update"] == {None}
+    assert parents["update.plan"] == {"update"}
+    assert parents["update.plan.keys"] == {"update.plan"}
+    assert parents["update.plan.rows"] == {"update.plan"}
+    assert parents["update.commit"] == {"update"}
+    assert parents["update.patch"] == {"update"}
+    # one host row rebuild per SELL tier the delta touches
+    assert parents["update.patch.rows"] == {"update.patch"}
+    assert parents["solve"] == {"update"}
+
+
+def test_watch_compiles_counts_real_compiles_and_names_the_span(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    reg = MetricsRegistry()
+    reg.watch_compiles()
+    reg.watch_compiles()                        # idempotent
+    assert reg.as_dict()["counters"]["compiles"] == 0
+    null = NullRegistry()
+    null.watch_compiles()
+
+    def fresh():
+        # a new function object: never in JAX's in-memory caches
+        return jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
+
+    x = (jnp.arange(8.0) + 1).block_until_ready()
+    count = lambda: reg.as_dict()["counters"]["compiles"]
+    c0, e0 = count(), len(reg.events)
+    with reg.span("outer"):
+        f = fresh()
+        f(x).block_until_ready()
+    assert count() == c0 + 1
+    ev = [e for e in reg.events[e0:] if e["kind"] == "compile"]
+    assert len(ev) == 1 and ev[0]["span"] == "outer"
+    assert ev[0]["s"] >= 0
+    f(x).block_until_ready()                    # in-memory hit
+    assert count() == c0 + 1
+    # a program loaded from the persistent cache is not a compile
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        fresh()(x).block_until_ready()          # compiled, written
+        assert count() == c0 + 2
+        jax.clear_caches()
+        fresh()(x).block_until_ready()          # read back
+        assert count() == c0 + 2
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert len([e for e in reg.events[e0:] if e["kind"] == "compile"]) == 2
+    assert null.as_dict()["counters"] == {} and null.events == []
+
+
+def test_named_scopes_change_only_metadata(monkeypatch):
+    """The ``pagerank.*`` scopes are op metadata: the compiled program of
+    a tolerance solve is the unscoped one, instruction for instruction."""
+    import contextlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.pagerank.engine import _run_tol
+
+    n = 48
+    src, dst = _graph(n)
+    eng = PageRankEngine(src, dst, n, backend="ell", ell_k=2,
+                         metrics=NullRegistry())
+    assert "overflow(nnz=0)" not in eng.layout     # the COO tail runs
+
+    def compiled() -> str:
+        jax.clear_caches()
+        return _run_tol.lower(eng._operands, eng._dang, eng.d,
+                              jnp.float32(1e-6), None, backend="ell", n=n,
+                              max_iters=100).compile().as_text()
+
+    def instructions(text: str) -> list[str]:
+        text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+        # the stack-frame tables the metadata points into
+        return [line for line in text.splitlines() if not re.match(
+            r"^(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)",
+            line)]
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert set(re.findall(r"pagerank\.\w+", scoped)) == {
+        "pagerank.ell_gather", "pagerank.coo_tail", "pagerank.vector"}
+    assert "pagerank." not in plain
+    assert instructions(scoped) == instructions(plain)
+
+
+def test_ppr_span_times_the_dispatch():
+    """``engine.ppr`` returns before the device finishes, so its span is
+    named for what it times: ``ppr.dispatch``."""
+    n = 32
+    src, dst = _graph(n)
+    reg = MetricsRegistry()
+    eng = PageRankEngine(src, dst, n, backend="ell", metrics=reg)
+    eng.ppr([np.array([0])], n_iters=3)
+    hists = reg.as_dict()["histograms"]
+    assert hists["span.ppr.dispatch"]["count"] == 1
+    assert "span.ppr" not in hists
+
+
+def test_obs_report_span_self_time_and_compiles(tmp_path):
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    import obs_report
+
+    def span(name, start, ms, parent):
+        return {"v": 2, "t_ms": start + ms, "kind": "span", "ms": ms,
+                "name": name, "parent": parent, "start_ms": start}
+
+    events = [span("update.plan.keys", 1.0, 3.0, "update.plan"),
+              span("update.plan.rows", 4.0, 1.0, "update.plan"),
+              span("update.plan", 0.5, 5.0, "update"),
+              {"v": 2, "t_ms": 6.0, "kind": "compile", "fun": "f",
+               "s": 0.1, "span": "update.patch"},
+              span("update.patch", 6.0, 2.0, "update"),
+              span("update", 0.0, 10.0, None),
+              {"v": 1, "t_ms": 11.0, "kind": "span", "ms": 4.0,
+               "name": "legacy"}]
+    d = obs_report.derive(events)
+    assert d["span_self_ms"] == pytest.approx(
+        {"update.plan.keys": 3.0, "update.plan.rows": 1.0,
+         "update.plan": 1.0, "update.patch": 2.0, "update": 3.0,
+         "legacy": 4.0})
+    assert d["compiles"] == {"update.patch": 1}
+    text = obs_report.render(d)
+    assert "self_sum=1.000ms" in text and "update.patch" in text
+    path = tmp_path / "events.jsonl"
+    path.write_text("".join(json.dumps(e) + "\n" for e in events))
+    assert obs_report.main([str(path)]) == 0
+    # a schema-2 span without its start and parent is malformed
+    bad = dict(events[0])
+    del bad["parent"]
+    path.write_text(json.dumps(bad) + "\n")
+    assert obs_report.main([str(path)]) == 2
