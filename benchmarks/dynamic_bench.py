@@ -114,7 +114,7 @@ def run(n: int = 5000, reps: int = 7, delta_edges: int = 10,
         # update solves to (1e-6; 1e-8 is below the f32 residual floor at
         # this size, so the oracle above runs to max_iters), re-timed so
         # the per-delta XLA recompile the static engine pays for its
-        # shape-unstable overflow tail is already cached
+        # layout's new tier shapes is already cached
         _rebuild_and_rerun(cur[0], cur[1], n, 1e-6)
         t0 = time.time()
         _rebuild_and_rerun(cur[0], cur[1], n, 1e-6)

@@ -65,8 +65,7 @@ class CSRMatrix:
 
     def row_positions(self) -> tuple[np.ndarray, np.ndarray]:
         """Host-side (row, position-within-row) of every nnz — the scatter
-        coordinates shared by the ELL builders and the engine's split-ELL
-        layout prep."""
+        coordinates of :meth:`ELLMatrix.from_csr`."""
         indptr = np.asarray(self.indptr)
         counts = np.diff(indptr)
         rows = np.repeat(np.arange(self.shape[0]), counts)

@@ -4,13 +4,13 @@ Importable from anywhere — the graph containers, the Pallas kernels and the
 engine all use :func:`upcast_f32` for the mixed-precision contract: operand
 tiles may be stored in a reduced dtype (bf16 / f16 / int8), but every
 multiply-accumulate happens in float32.  :data:`F32_DOT` is the precision
-of the kernels' dots; :func:`ell_rows` is the one ELL row-sum every ELL
-layout (split, sliced, row-sharded) sweeps with.
+of the kernels' dots; :func:`ell_rows` is the one ELL row-sum every tier
+of the sliced ELL layout (single-device or row-sharded) sweeps with.
 
 The hot path's kernels run under fixed ``jax.named_scope`` names
-(``pagerank.ell_gather`` here; ``pagerank.coo_tail``, ``pagerank.sell_order``,
+(``pagerank.ell_gather`` here; ``pagerank.sell_order``,
 ``pagerank.vector``, ``pagerank.push`` and ``pagerank.row_patch`` in the
-engine, SELL, step and dynamic modules).  A scope is op metadata only: the
+SELL, step, engine and dynamic modules).  A scope is op metadata only: the
 compiled instructions do not change, and a profiler trace names each
 device op by its innermost ``pagerank.*`` scope whatever number the
 compiler gave its fusion.

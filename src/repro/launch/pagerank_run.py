@@ -3,7 +3,7 @@ one front door.
 
 Every execution tier goes through :class:`~repro.pagerank.engine.
 PageRankEngine` (layout prepared once, whole power iteration in one
-compiled dispatch): the dense reference tier, the split-ELL tier, the
+compiled dispatch): the dense reference tier, the sliced-ELL tier, the
 fused-Pallas tier, and — when the process sees more than one JAX device —
 the sharded mesh tiers (``dense_sharded`` fabric schedule and the
 row-sharded ``ell_sharded``).  The analytical fabric timing model (the
@@ -67,7 +67,7 @@ def run(argv=None):
     eng_dense = PageRankEngine(src, dst, n, d=d, backend="dense")
     results["engine_dense"], pr_dense = _time_engine(eng_dense, iters)
 
-    # split-ELL tier
+    # sliced-ELL tier
     eng_ell = PageRankEngine(src, dst, n, d=d, backend="ell")
     results["engine_ell"], pr_ell = _time_engine(eng_ell, iters)
     err = float(jnp.max(jnp.abs(pr_ell - pr_dense)))

@@ -76,12 +76,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.graph import transition as tr
 from repro.graph.delta import GraphDelta, edge_keys
 from repro.kernels.streaming_matvec import streaming_matvec
 from repro.obs.trace import SolveTrace, instrumented_tol_loop
 from repro.pagerank import distributed as dist
-from repro.pagerank import sell
 from repro.pagerank.engine import PageRankEngine, _dedupe_edges, _matvec
 from repro.pagerank.resilience import EngineSnapshot, make_solve_info
 
@@ -352,10 +350,9 @@ class DynamicPageRankEngine(PageRankEngine):
     """A :class:`PageRankEngine` over a *live* graph.
 
     Same constructor, same ``run`` / ``run_tol`` / ``ppr`` surface (the
-    ``ell`` backend transparently swaps in the patchable SELL layout; the
-    ``ell_sharded`` SELL tiers are built with ``slack`` slots of row
-    headroom; ``bsr`` keeps a host block-structure map for in-block value
-    patches), plus:
+    ``ell`` and ``ell_sharded`` SELL tiers are built with ``slack`` slots
+    of row headroom; ``bsr`` keeps a host block-structure map for in-block
+    value patches), plus:
 
     * ``update(delta)`` — fold a :class:`~repro.graph.delta.GraphDelta`
       into the prepared layouts and refresh the ranks; returns
@@ -391,30 +388,12 @@ class DynamicPageRankEngine(PageRankEngine):
 
     # --------------------------- layout prep --------------------------- #
     def _prepare_layout(self, src: np.ndarray, dst: np.ndarray) -> None:
+        # the SELL tiers (``ell``, ``ell_sharded``) are built with this
+        # engine's ``_slack``: the capacity slack is what lets a delta patch
+        # rows in place — a row outgrowing its tier escalates to rebuild
+        super()._prepare_layout(src, dst)
         if self.backend == "bsr":
-            super()._prepare_layout(src, dst)
             self._bsr_index(src, dst)
-            return
-        if self.backend != "ell":
-            # ell_sharded builds its SELL with this engine's ``_slack``
-            super()._prepare_layout(src, dst)
-            return
-        n = self.n
-        self._dang = jnp.asarray(tr.dangling_mask(src, n).astype(np.float32))
-        self.mesh = None
-        self._axes = ()
-        self._n_pad = n
-        self._scales = None
-        self._mv_backend = "sell"     # engine._matvec's tag for this layout
-        # the capacity slack is what lets a delta patch rows in place — a
-        # row outgrowing its tier is what escalates update() to rebuild
-        self._operands, self._sell = sell.build(
-            tr.build_transition_csr(src, dst, n), n, slack=self._slack,
-            precision=self.precision)
-        self.layout = self._sell.describe(self._slack)
-        if self.precision != "f32":
-            self.layout = f"{self.layout}[{self.precision}]"
-        self._record_layout_bytes()
 
     def _bsr_index(self, src: np.ndarray, dst: np.ndarray) -> None:
         """Host map of the prepared BSR block structure: sorted int64
@@ -776,18 +755,23 @@ class DynamicPageRankEngine(PageRankEngine):
         # at once); on the mesh each scatter stays on its tier's row
         # sharding, so every write lands on the device owning the row.
         # Each tier's host rebuild is an ``update.patch.rows`` span; the
-        # scatters it then dispatches run under ``update.patch`` itself
+        # scatters it then dispatches run under ``update.patch`` itself.
+        # A tier holding an eighth of the rows or more (the narrow tiers of
+        # a power-law graph) takes 512-row chunks, the others 64, so a
+        # delta patches each tier in few chunks and few chunk counts
         rows = plan["rows"]
         inv, tiers = self._operands
         tiers = list(tiers)
+        n_rows = sum(self._sell.rows)
         for t, k in enumerate(self._sell.widths):
             sel = rows[self._sell.tier[rows] == t]
             if len(sel) == 0:
                 continue
+            cap = 512 if 8 * self._sell.rows[t] >= n_rows else 64
             with self.metrics.span("update.patch.rows"):
                 data, idx = self._rebuild_rows(sel, k)
                 pos, dat, ix = _stack_chunks(self._sell.pos[sel], data, idx,
-                                             cap=512 if t == 0 else 64)
+                                             cap=cap)
             pos = jnp.asarray(pos)
             d, i = tiers[t]
             sharding = None if self.mesh is None else d.sharding

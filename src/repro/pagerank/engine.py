@@ -60,7 +60,7 @@ from repro.graph import delta as delta_mod
 from repro.graph import transition as tr
 from repro.graph.sparse import BSRMatrix
 from repro.kernels import ops as kops
-from repro.kernels.common import ell_rows, upcast_f32
+from repro.kernels.common import upcast_f32
 from repro.kernels.pagerank_step import (pad_pagerank_operands,
                                          pagerank_step_fused)
 from repro.kernels.streaming_matvec import streaming_matvec
@@ -148,33 +148,6 @@ def _dedupe_edges(src: np.ndarray, dst: np.ndarray,
 # --------------------------------------------------------------------------- #
 # whole-loop compiled runners (XLA backends)                                  #
 # --------------------------------------------------------------------------- #
-def _split_ell(src: np.ndarray, dst: np.ndarray, n: int,
-               k0: int | None = None):
-    """Engine-prepared ELL layout: a tight per-row budget ``k0`` (the 90th
-    degree percentile by default) plus a COO overflow tail for the
-    power-law hub rows.  Classic full-k ELLPACK pads every row to the max
-    degree — on scale-free protein networks that is ~15x more
-    multiply-adds than the nnz; the split keeps the vectorized gather for
-    ~90% of entries and routes the tail through one ``segment_sum``."""
-    csr = tr.build_transition_csr(src, dst, n)
-    counts = np.diff(np.asarray(csr.indptr))
-    if k0 is None:
-        k0 = max(4, int(np.percentile(counts, 90))) if len(counts) else 4
-    cols = np.asarray(csr.indices)
-    vals = np.asarray(csr.data)
-    rows, pos = csr.row_positions()
-    in_ell = pos < k0
-    data = np.zeros((n, k0), np.float32)
-    idx = np.zeros((n, k0), np.int32)
-    data[rows[in_ell], pos[in_ell]] = vals[in_ell]
-    idx[rows[in_ell], pos[in_ell]] = cols[in_ell]
-    ov = ~in_ell
-    return (jnp.asarray(data), jnp.asarray(idx),
-            jnp.asarray(rows[ov], jnp.int32), jnp.asarray(cols[ov],
-                                                          jnp.int32),
-            jnp.asarray(vals[ov], jnp.float32)), k0, int(ov.sum())
-
-
 def _row_scale(y: jax.Array, scales: jax.Array | None) -> jax.Array:
     """Fold an int8 layout's per-row f32 dequantization scales into the
     accumulated f32 row sums (vector or batched-matrix shaped)."""
@@ -188,27 +161,17 @@ def _matvec(backend: str, operands, x: jax.Array) -> jax.Array:
 
     Value arrays may be stored reduced-precision (bf16/f16/int8); they are
     upcast at the multiply (a trace-time no-op on f32 layouts, keeping the
-    f32 tier's program bit-identical) and accumulated in f32.  int8
-    layouts append their per-row f32 scale vectors to the operand tuple —
-    the tuple length is static under jit, so the scaled variants trace to
-    their own programs and the float tiers never pay a branch.
+    f32 tier's program bit-identical) and accumulated in f32.  An int8
+    dense layout appends its per-row f32 scale vector to the operand tuple
+    — the tuple length is static under jit, so the scaled variant traces
+    to its own program and the float tiers never pay a branch.
     """
     if backend == "dense":
         scales = operands[1] if len(operands) == 2 else None
         return _row_scale(upcast_f32(operands[0]) @ x, scales)
-    if backend == "ell":
-        data, idx, ov_r, ov_c, ov_v = operands[:5]
-        scales = operands[5] if len(operands) == 6 else None
-        ov_v = upcast_f32(ov_v)
-        y = ell_rows(data, idx, x)
-        with jax.named_scope("pagerank.coo_tail"):
-            tail = jax.ops.segment_sum(
-                (ov_v if x.ndim == 1 else ov_v[:, None]) * x[ov_c], ov_r,
-                num_segments=data.shape[0])
-        return _row_scale(y + tail, scales)
     if backend == "sell":
-        # sliced ELLPACK, the dynamic engine's patchable ELL tier: the
-        # layout's own module builds and reads it
+        # sliced ELLPACK, the ``ell`` tier: the layout's own module builds
+        # and reads it, int8 scales included
         return sell.sell_rows(operands, x)
     if backend == "bsr":
         # BSRMatrix upcasts its own blocks and owns its row_scales field
@@ -420,9 +383,9 @@ class PageRankEngine:
     ``run_tol`` / ``ppr`` call is a single device dispatch.  Backends:
 
     * ``"dense"``        — dangling-fixed dense H, XLA matmul sweep.
-    * ``"ell"``          — engine-prepared split ELLPACK: a tight per-row
-      budget (``ell_k``, default 90th degree percentile) + a COO overflow
-      tail for hub rows, so the hot loop doesn't pay max-degree padding.
+    * ``"ell"``          — sliced ELLPACK (:mod:`repro.pagerank.sell`):
+      rows in degree tiers of doubling width, each row padded to under
+      twice its degree plus 4, one gather per tier.
     * ``"bsr"``          — MXU-aligned block-sparse rows, explicit leak.
     * ``"pallas_dense"`` — pre-padded dense layout through the fused
       Pallas kernel with the in-kernel dangling reduction.
@@ -441,14 +404,14 @@ class PageRankEngine:
     edges are collapsed up front so every tier sees the same graph.
     """
 
-    # row headroom of each SELL tier (``ell_sharded``); the dynamic engine
-    # reserves more so edge deltas patch rows in place
+    # row headroom of each SELL tier (``ell``, ``ell_sharded``); the
+    # dynamic engine reserves more so edge deltas patch rows in place
     _slack = 0
 
     def __init__(self, src: np.ndarray, dst: np.ndarray, n: int, *,
                  d: float = 0.85, backend: str = "auto",
                  block_n: int = 256, block_m: int = 256,
-                 bsr_block_size: int = 128, ell_k: int | None = None,
+                 bsr_block_size: int = 128,
                  interpret: bool | None = None, mesh: Mesh | None = None,
                  metrics=None, precision: str = "auto"):
         self.n = int(n)
@@ -479,7 +442,6 @@ class PageRankEngine:
                 f"backend {self.backend!r} not in {BACKENDS + ('auto',)}")
         self._block_arg = (block_n, block_m)
         self._bsr_block_size = bsr_block_size
-        self._ell_k = ell_k
         self._mesh_arg = mesh
         # resilience bookkeeping: the last run_tol's SolveInfo and the
         # warn-once latch for silently-exhausted solves
@@ -500,8 +462,7 @@ class PageRankEngine:
         structurally too disruptive — to patch in place."""
         n = self.n
         block_n, block_m = self._block_arg
-        bsr_block_size, ell_k, mesh = (self._bsr_block_size, self._ell_k,
-                                       self._mesh_arg)
+        bsr_block_size, mesh = self._bsr_block_size, self._mesh_arg
         self._dang = jnp.asarray(tr.dangling_mask(src, n).astype(np.float32))
         self._block = self._block_arg
         self.mesh = None
@@ -512,9 +473,9 @@ class PageRankEngine:
         # keeps one per tier); always None for float precisions
         self._scales = None
         # the layout tag the generic jitted runners dispatch _matvec on —
-        # normally the backend itself; the dynamic engine's patchable SELL
-        # tier overrides it while keeping backend == "ell"
+        # the backend itself, but "sell" for the ``ell`` tier
         self._mv_backend = self.backend
+        self._sell = None
         self.layout = self.backend
         if self.backend == "dense":
             if self.precision == "f32":
@@ -536,10 +497,11 @@ class PageRankEngine:
                     self._operands = (
                         jnp.asarray(H).astype(self.storage_dtype),)
         elif self.backend == "ell":
-            self._operands, k0, ov_nnz = _split_ell(src, dst, n, k0=ell_k)
-            self.layout = f"ell(k0={k0})+overflow(nnz={ov_nnz})"
-            if self.precision != "f32":
-                self._operands = self._quantize_split_ell(self._operands)
+            self._operands, self._sell = sell.build(
+                tr.build_transition_csr(src, dst, n), n, slack=self._slack,
+                precision=self.precision)
+            self._mv_backend = "sell"
+            self.layout = self._sell.describe(self._slack)
         elif self.backend == "bsr":
             bsr = tr.build_transition_bsr(src, dst, n, bs=bsr_block_size)
             if self.precision == "int8":
@@ -623,33 +585,21 @@ class PageRankEngine:
             self.layout = f"{self.layout}[{self.precision}]"
         self._record_layout_bytes()
 
-    def _quantize_split_ell(self, operands: tuple) -> tuple:
-        """Cast a prepared split-ELL layout's value arrays to the reduced
-        storage dtype.  int8 scales are computed over the FULL row — the
-        ELL block's entries and the COO overflow tail share the row's
-        abs-max — and appended as a sixth operand."""
-        data, idx, ov_r, ov_c, ov_v = operands
-        if self.precision != "int8":
-            return (data.astype(self.storage_dtype), idx, ov_r, ov_c,
-                    ov_v.astype(self.storage_dtype))
-        data_np, ov_v_np = np.asarray(data), np.asarray(ov_v)
-        ov_r_np = np.asarray(ov_r)
-        absmax = np.abs(data_np).max(axis=1, initial=0.0)
-        np.maximum.at(absmax, ov_r_np, np.abs(ov_v_np))
-        scales = rowmax_scales(absmax)
-        return (jnp.asarray(quantize_int8(data_np, scales[:, None])), idx,
-                ov_r, ov_c,
-                jnp.asarray(quantize_int8(ov_v_np, scales[ov_r_np])),
-                jnp.asarray(scales))
-
     def _record_layout_bytes(self) -> None:
         """Operand-byte accounting of the prepared layout (value vs index
         bytes — precision tiers shrink only the former), exported as the
-        ``layout.bytes`` gauge and kept as ``self.layout_bytes``."""
+        ``layout.bytes`` gauge and kept as ``self.layout_bytes``; its stored
+        value slots, padding included, as the ``layout.slots`` gauge (over
+        ``n_edges``, the padding a sweep gathers)."""
         extras = () if self._scales is None else (self._scales,)
         self.layout_bytes = layout_nbytes(tuple(self._operands) + extras)
         self.metrics.gauge("layout.bytes").set(
             self.layout_bytes["total_bytes"])
+        # a SELL's first leaf is its int32 row order; every other layout's
+        # is its value array (H, the BSR blocks, the padded Pallas H)
+        self.metrics.gauge("layout.slots").set(
+            self._sell.slots if self._sell is not None
+            else int(jax.tree.leaves(self._operands)[0].size))
 
     def _pad_replicated(self, dang: jax.Array) -> jax.Array:
         padded = np.zeros((self._n_pad,), np.float32)

@@ -485,33 +485,36 @@ class FaultInjector:
     # ------------------------------ layout ----------------------------- #
     def corrupt_layout(self, engine, kind: str = "nan", k: int = 4) -> None:
         """Poison ``k`` values of the first float array in the engine's
-        prepared layout pytree (the dense H, the ELL/SELL data tier, the BSR
-        blocks, or a sharded operand — whichever the backend prepared).
+        prepared layout pytree (the dense H, the first SELL tier's data, the
+        BSR blocks, or a sharded operand — whichever the backend prepared).
         ``kind="huge"`` plants finite-but-absurd values and
-        ``kind="scale"`` multiplies the whole array by 32 — a spectral
-        radius ≫ 1, the deterministic way to exercise the residual-growth
-        (``diverged``) watchdog rather than the NaN/Inf check; device
-        sharding is preserved on the write-back."""
+        ``kind="scale"`` multiplies every float array of the layout (each
+        SELL tier, say) by 32 — a spectral radius ≫ 1, the deterministic
+        way to exercise the residual-growth (``diverged``) watchdog rather
+        than the NaN/Inf check; device sharding is preserved on the
+        write-back."""
         leaves, treedef = jax.tree.flatten(engine._operands)
-        target = next((i for i, a in enumerate(leaves)
-                       if jnp.issubdtype(a.dtype, jnp.floating)), None)
-        if target is None:
+        floats = [i for i, a in enumerate(leaves)
+                  if jnp.issubdtype(a.dtype, jnp.floating)]
+        if not floats:
             raise ValueError("no float layout array to corrupt")
-        arr = np.asarray(leaves[target]).copy()
-        flat = arr.reshape(-1)
-        if kind == "scale":
-            arr *= 32.0
-            idx = np.empty(0, np.int64)
-        else:
-            idx = self.rng.choice(flat.shape[0], size=min(k, flat.shape[0]),
-                                  replace=False)
-            # "huge" stays finite long enough for the growth counter to
-            # matter; whether it trips diverged or nonfinite depends on
-            # how fast the corrupt entries feed back
-            flat[idx] = {"nan": np.nan, "inf": np.inf, "huge": 1e4}[kind]
-        leaves[target] = jax.device_put(arr, leaves[target].sharding)
+        idx = np.empty(0, np.int64)
+        for target in (floats if kind == "scale" else floats[:1]):
+            arr = np.asarray(leaves[target]).copy()
+            flat = arr.reshape(-1)
+            if kind == "scale":
+                arr *= 32.0
+            else:
+                idx = self.rng.choice(flat.shape[0],
+                                      size=min(k, flat.shape[0]),
+                                      replace=False)
+                # "huge" stays finite long enough for the growth counter to
+                # matter; whether it trips diverged or nonfinite depends on
+                # how fast the corrupt entries feed back
+                flat[idx] = {"nan": np.nan, "inf": np.inf, "huge": 1e4}[kind]
+            leaves[target] = jax.device_put(arr, leaves[target].sharding)
         engine._operands = jax.tree.unflatten(treedef, leaves)
-        self.log.append(f"layout:{kind}(k={len(idx)},operand={target})")
+        self.log.append(f"layout:{kind}(k={len(idx)},operand={floats[0]})")
 
     # --------------------------- update failures ----------------------- #
     def fail_next_updates(self, engine, times: int = 1,

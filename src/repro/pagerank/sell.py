@@ -1,14 +1,13 @@
 """Sliced ELLPACK (SELL): the patchable, row-shardable sparse layout.
 
-Rows are grouped into tiers by in-degree: the base tier holds the rows up
-to the 90th degree percentile, and each further tier doubles the width up
-to the maximum degree.  Every tier is a dense ``(rows, width)`` ELL block
-padded to its width plus ``slack``, so a sweep is one gather per tier and
-no ``segment_sum``, and a small edge delta rewrites rows in place without
-changing any array shape.  On a 2^20-node protein network the doubling
-tiers hold 3x the edges in padded slots; a base tier plus one hub tier
-padded to the maximum degree holds 26x, and one block padded to the
-maximum degree 262x.
+Rows are grouped into tiers by in-degree: tier bounds double from a width
+of 4 up to the maximum degree, and each row goes to the narrowest tier
+that holds it.  Every tier is a dense ``(rows, width)`` ELL block padded
+to its width plus ``slack``, so a row of degree ``deg`` keeps fewer than
+``2·deg + 4 + slack`` slots (an isolated row keeps ``4 + slack``), a sweep
+is one gather per tier and no ``segment_sum``, and a small edge delta
+rewrites rows in place without changing any array shape.  The tiers
+follow the graph's own degree histogram, so no width is tuned per graph.
 
 Row-sharded over ``shards`` devices, each device holds a self-contained
 SELL of its own contiguous block of rows: every tier array stacks
@@ -43,6 +42,12 @@ class SellIndex:
     pos: np.ndarray               # row -> row of its tier's array
     rows: tuple[int, ...]         # rows of each tier's array
 
+    @property
+    def slots(self) -> int:
+        """Stored slots of the layout, padding included: what each sweep
+        gathers."""
+        return sum(r * w for r, w in zip(self.rows, self.widths))
+
     def describe(self, slack: int) -> str:
         return f"sell(k={list(self.widths)}, rows={list(self.rows)}, " \
                f"slack={slack})"
@@ -57,16 +62,19 @@ def build(csr, n_pad: int, *, shards: int = 1, slack: int = 0,
     n = csr.shape[0]
     counts = np.zeros(n_pad, np.int64)
     counts[:n] = np.diff(np.asarray(csr.indptr))
-    # tier bounds: the 90th degree percentile, then doubling up to the
-    # maximum degree; capacities sit ``slack`` above each bound (the widest
-    # tier >= 16 above, rounded to 32), so every row has patch headroom
-    bounds = [max(4, int(np.percentile(counts[:n], 90)) if n else 0)]
+    # tier bounds double from 4 up to the maximum degree; capacities sit
+    # ``slack`` above each bound.  The widest tier rounds up to a multiple
+    # of 32 with >= 16 slots of patch headroom, but stays under
+    # 2·deg + 4 + slack for its narrowest row (deg > the bound below it,
+    # and maxdeg <= twice that bound)
     maxdeg = int(counts.max()) if n_pad else 0
+    bounds = [4]
     while bounds[-1] < maxdeg:
         bounds.append(min(2 * bounds[-1], maxdeg))
     caps = [b + slack for b in bounds]
     if len(caps) > 1:
-        caps[-1] = -(-(maxdeg + max(16, slack)) // 32) * 32
+        caps[-1] = min(-(-(maxdeg + max(16, slack)) // 32) * 32,
+                       2 * bounds[-2] + 5 + slack)
     tier = np.searchsorted(bounds, counts)
     used = np.unique(tier)                  # drop tiers no row falls in
     tier = np.searchsorted(used, tier)
@@ -84,24 +92,30 @@ def build(csr, n_pad: int, *, shards: int = 1, slack: int = 0,
     pos = group // n_t * per_shard[tier] + rank
     offset = np.concatenate([[0], np.cumsum(per_shard)[:-1]])
     inv = (offset[tier] + rank).astype(np.int32)
-    rows, slot = csr.row_positions()
-    cols, vals = np.asarray(csr.indices), np.asarray(csr.data)
+    # every tier's array is one slice of a flat buffer, so each edge is
+    # placed in one pass: its row's first slot plus its place in the row
+    w = np.asarray(widths)
+    base = np.concatenate([[0], np.cumsum(shards * per_shard * w)])
+    first = base[tier] + pos * w[tier]
+    indptr = np.asarray(csr.indptr).astype(np.int64)
+    dest = (np.repeat(first[:n] - indptr[:-1], np.diff(indptr))
+            + np.arange(indptr[-1]))
+    flat_data = np.zeros(base[-1], np.float32)
+    flat_idx = np.zeros(base[-1], np.int32)
+    flat_data[dest] = np.asarray(csr.data)
+    flat_idx[dest] = np.asarray(csr.indices)
     put = jnp.asarray if sharding is None else (
         lambda a: jax.device_put(a, sharding))
     tiers = []
     for t, k in enumerate(widths):
-        data = np.zeros((shards * per_shard[t], k), np.float32)
-        idx = np.zeros((shards * per_shard[t], k), np.int32)
-        sel = tier[rows] == t
-        data[pos[rows[sel]], slot[sel]] = vals[sel]
-        idx[pos[rows[sel]], slot[sel]] = cols[sel]
+        data = flat_data[base[t]:base[t + 1]].reshape(-1, k)
+        idx = put(flat_idx[base[t]:base[t + 1]].reshape(-1, k))
         if precision == "int8":
             scale = rowmax_scales(np.abs(data).max(axis=1, initial=0.0))
-            tiers.append((put(quantize_int8(data, scale[:, None])),
-                          put(idx), put(scale)))
+            tiers.append((put(quantize_int8(data, scale[:, None])), idx,
+                          put(scale)))
         else:
-            tiers.append((put(data.astype(STORAGE_DTYPES[precision])),
-                          put(idx)))
+            tiers.append((put(data.astype(STORAGE_DTYPES[precision])), idx))
     index = SellIndex(widths, tier, pos,
                       tuple(int(shards * r) for r in per_shard))
     return (put(inv), tuple(tiers)), index

@@ -472,8 +472,9 @@ def test_sharded_stream_of_updates_tracks_scratch(net, multi_device):
 
 
 def test_dynamic_ell_ppr_matches_static(net):
-    """The dynamic SELL layout serves the same batched PPR as the static
-    split-ELL tier (the serve path flushes through engine.ppr)."""
+    """The dynamic SELL layout (slack 8) serves the same batched PPR as the
+    static ``ell`` tier's (slack 0; the serve path flushes through
+    engine.ppr)."""
     n, src, dst = net
     seed_sets = [np.array([1, 2]), np.array([7])]
     got = DynamicPageRankEngine(src, dst, n, backend="ell").ppr(

@@ -497,16 +497,17 @@ def test_named_scopes_change_only_metadata(monkeypatch):
 
     from repro.pagerank.engine import _run_tol
 
-    n = 48
-    src, dst = _graph(n)
-    eng = PageRankEngine(src, dst, n, backend="ell", ell_k=2,
+    n = 96
+    src, dst = gen.barabasi_albert(n, 3, seed=0)   # hub rows: several tiers
+    eng = PageRankEngine(src, dst, n, backend="ell",
                          metrics=NullRegistry())
-    assert "overflow(nnz=0)" not in eng.layout     # the COO tail runs
+    assert len(eng._sell.widths) > 2
 
     def compiled() -> str:
         jax.clear_caches()
         return _run_tol.lower(eng._operands, eng._dang, eng.d,
-                              jnp.float32(1e-6), None, backend="ell", n=n,
+                              jnp.float32(1e-6), None,
+                              backend=eng._mv_backend, n=n,
                               max_iters=100).compile().as_text()
 
     def instructions(text: str) -> list[str]:
@@ -523,7 +524,7 @@ def test_named_scopes_change_only_metadata(monkeypatch):
     monkeypatch.undo()
     jax.clear_caches()
     assert set(re.findall(r"pagerank\.\w+", scoped)) == {
-        "pagerank.ell_gather", "pagerank.coo_tail", "pagerank.vector"}
+        "pagerank.ell_gather", "pagerank.sell_order", "pagerank.vector"}
     assert "pagerank." not in plain
     assert instructions(scoped) == instructions(plain)
 

@@ -138,6 +138,26 @@ def test_fidelity_helpers_are_exact_on_identical_input():
     assert l1(x, x) == 0.0
 
 
+@pytest.mark.parametrize("precision", ["bf16", "int8"])
+def test_sell_tiers_low_precision_on_hub_graph(precision):
+    """The ``ell`` tier's SELL stores its reduced values per degree tier
+    (int8 with per-row scales in every tier); on a graph with hub rows far
+    above the 90th degree percentile it keeps the tier's bounds."""
+    n = 400
+    src, dst = gen.barabasi_albert(n, 3, seed=1)
+    ref = PageRankEngine(src, dst, n, backend="ell")
+    eng = PageRankEngine(src, dst, n, backend="ell", precision=precision)
+    assert len(eng._sell.widths) > 3 and f"[{precision}]" in eng.layout
+    assert all(t[0].dtype == eng.storage_dtype for t in eng.operands[1])
+    assert all(len(t) == (3 if precision == "int8" else 2)
+               for t in eng.operands[1])
+    pr = np.asarray(eng.run_tol(tol=1e-6, max_iters=500)[0], np.float64)
+    assert np.isfinite(pr).all() and pr.min() >= 0.0
+    assert abs(pr.sum() - 1.0) <= SUM_TOL[precision]
+    want = np.asarray(ref.run_tol(tol=1e-8, max_iters=500)[0])
+    assert topk_overlap(pr, want, k=100) >= 0.99
+
+
 # ----------------------------- dynamic tiers ---------------------------- #
 @pytest.mark.parametrize("backend", ["dense", "ell", "bsr", "pallas_dense"])
 def test_dynamic_insert_then_delete_restores_bf16_bitexact(backend, net):
