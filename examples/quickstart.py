@@ -18,7 +18,7 @@ from repro.graph import generators as gen
 from repro.graph import transition as tr
 from repro.kernels import ops
 from repro.pagerank import pagerank_dense_fixed, pagerank_on_fabric
-from repro.pagerank.sparse import top_k_proteins
+from repro.serve.engine import top_k_proteins
 
 print("=" * 64)
 print("1. 64-bit message codec (Fig. 1B) — paper's Fig. 5 values")

@@ -29,7 +29,7 @@ from repro.graph import generators as gen
 from repro.graph import transition as tr
 from repro.launch.compile_cache import use_compile_cache
 from repro.pagerank import PageRankEngine
-from repro.pagerank.sparse import top_k_proteins
+from repro.serve.engine import top_k_proteins
 
 
 def _time_engine(eng: PageRankEngine, iters: int) -> tuple[float, jax.Array]:

@@ -270,10 +270,16 @@ def _run_ppr_dense_sharded(H, dang, V, scales=None, *, mesh, axes, n_true,
                            n_iters, d):
     # H is stored dangling-UNFIXED for this tier, so the PPR schedule can
     # teleport the leak to V directly — no column reconstruction needed.
-    PR = dist.ppr_distributed_dense(H, dang, V, mesh, n_iters=n_iters, d=d,
+    # V (n, Q) is zero-padded to the padded N and to a multiple of the
+    # query shards; pad columns stay zero and are sliced off
+    q = V.shape[1]
+    q_shards = mesh.shape[axes[1]]
+    Vp = jnp.pad(V, ((0, H.shape[0] - n_true),
+                     (0, -(-q // q_shards) * q_shards - q)))
+    PR = dist.ppr_distributed_dense(H, dang, Vp, mesh, n_iters=n_iters, d=d,
                                     row_axis=axes[0], col_axis=axes[1],
                                     scales=scales)
-    return PR[:n_true]
+    return PR[:n_true, :q]
 
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
@@ -298,7 +304,8 @@ def _run_tol_ell_sharded(layout, dang, tol, x0, *, mesh, axes, n_true,
 @partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
 def _run_ppr_ell_sharded(layout, dang, V, *, mesh, axes, n_true, n_iters,
                          d):
-    PR = dist.ppr_distributed_sparse(layout, dang, V, mesh,
+    Vp = jnp.pad(V, ((0, dang.shape[0] - n_true), (0, 0)))
+    PR = dist.ppr_distributed_sparse(layout, dang, Vp, mesh,
                                      n_iters=n_iters, d=d, axes=axes)
     return PR[:n_true]
 
@@ -354,13 +361,15 @@ def _run_tol_pallas(Hp, dangp, tol, x0, scales=None, *, n: int,
 
 @partial(jax.jit, static_argnames=("n", "n_iters", "d", "block_n",
                                    "block_m", "interpret"))
-def _run_ppr_pallas(Hp, dangp, Vp, scales=None, *, n: int, n_iters: int,
+def _run_ppr_pallas(Hp, dangp, V, scales=None, *, n: int, n_iters: int,
                     d: float, block_n: int, block_m: int, interpret: bool):
-    # Vp: (Q, Np) — queries ride the batch axis of streaming_matvec, so all
-    # Q teleport distributions share one sweep over Hp per iteration.  The
-    # kernel upcasts reduced-precision Hp tiles in-register; an int8
-    # layout's (1, Np) row scales fold into the f32 output here (Y's
-    # column axis is Hp's row axis).
+    # V (n, Q) rides transposed and zero-padded, (Q, Mp): queries ride the
+    # batch axis of streaming_matvec, so all Q teleport distributions share
+    # one sweep over Hp per iteration.  The kernel upcasts reduced-precision
+    # Hp tiles in-register; an int8 layout's (1, Np) row scales fold into
+    # the f32 output here (Y's column axis is Hp's row axis).
+    Vp = jnp.pad(V.T, ((0, 0), (0, Hp.shape[1] - n)))
+
     def body(PR, _):
         leak = jnp.sum(PR * dangp, axis=1)                # (Q,)
         Y = streaming_matvec(Hp, PR, block_n=block_n, block_m=block_m,
@@ -779,48 +788,59 @@ class PageRankEngine:
     def ppr(self, seed_sets: Sequence[np.ndarray],
             n_iters: int = 100) -> jax.Array:
         """Batched personalized PageRank: one (N, Q) propagation for Q
-        per-user seed sets; returns the (N, Q) rank matrix.
+        per-user seed sets; returns the (N, Q) rank matrix
+        (:meth:`ppr_columns` of their :func:`seed_matrix`)."""
+        return self.ppr_columns(seed_matrix(self.n, seed_sets), n_iters)
+
+    def ppr_columns(self, V: np.ndarray, n_iters: int = 100) -> jax.Array:
+        """Batched personalized PageRank of the host (N, Q) teleport matrix
+        ``V``, one distribution per column; returns the (N, Q) rank matrix.
+        A zero column of ``V`` comes back an exact zero column, so a caller
+        pads its query axis with zero columns to run one program per width.
 
         On ``dense_sharded`` the query axis is sharded across the mesh
         (padded up to the shard count with zero columns, sliced back); on
         ``ell_sharded`` every device sweeps its own rows for all queries,
         so a multi-user serve flush spreads over devices either way.
 
-        The result is not waited for, so its ``ppr.dispatch`` span times
-        the dispatch alone; the caller's host read ends the solve."""
-        with self.metrics.span("ppr.dispatch", backend=self.backend,
-                               q=len(seed_sets)):
-            self.metrics.counter("engine.ppr_queries").inc(len(seed_sets))
-            return self._ppr(seed_sets, n_iters)
+        Counts ``ppr.sweeps`` (``n_iters``) and ``ppr.column_sweeps``
+        (``n_iters`` per non-zero column).  The result is not waited for,
+        so its ``ppr.dispatch`` span times the dispatch alone; the caller's
+        host read ends the solve."""
+        V = np.asarray(V, np.float32)
+        queries = int(np.count_nonzero(V.any(axis=0)))
+        m = self.metrics
+        with m.span("ppr.dispatch", backend=self.backend, q=queries):
+            m.counter("engine.ppr_queries").inc(queries)
+            m.counter("ppr.sweeps").inc(n_iters)
+            m.counter("ppr.column_sweeps").inc(n_iters * queries)
+            run, args, kw = self._ppr_program(jnp.asarray(V), n_iters)
+            return run(*args, **kw)
 
-    def _ppr(self, seed_sets: Sequence[np.ndarray],
-             n_iters: int) -> jax.Array:
-        V = seed_matrix(self.n, seed_sets)
+    def lower_ppr(self, q: int, n_iters: int = 100):
+        """AOT-lower the batched personalized PageRank of ``q`` columns
+        without running it; its ``.compile()`` leaves a later
+        :meth:`ppr_columns` of that width nothing to compile."""
+        run, args, kw = self._ppr_program(
+            jax.ShapeDtypeStruct((self.n, q), jnp.float32), n_iters)
+        return run.lower(*args, **kw)
+
+    def _ppr_program(self, V, n_iters: int) -> tuple:
+        """The jitted runner of a batched PPR of the (N, Q) ``V`` on this
+        layout, with its arguments and keywords."""
         if self.backend == "dense_sharded":
-            q = V.shape[1]
-            q_shards = self.mesh.shape[self._axes[1]]
-            q_pad = -(-q // q_shards) * q_shards
-            Vp = np.zeros((self._n_pad, q_pad), np.float32)
-            Vp[:self.n, :q] = V
-            PR = _run_ppr_dense_sharded(
-                self._operands[0], self._dang, jnp.asarray(Vp),
-                self._scales, mesh=self.mesh, axes=self._axes,
-                n_true=self.n, n_iters=n_iters, d=self.d)
-            return PR[:, :q]
+            return _run_ppr_dense_sharded, (
+                self._operands[0], self._dang, V, self._scales), dict(
+                mesh=self.mesh, axes=self._axes, n_true=self.n,
+                n_iters=n_iters, d=self.d)
         if self.backend == "ell_sharded":
-            Vp = np.zeros((self._n_pad, V.shape[1]), np.float32)
-            Vp[:self.n] = V
-            return _run_ppr_ell_sharded(
-                self._operands, self._dang, jnp.asarray(Vp), mesh=self.mesh,
-                axes=self._axes, n_true=self.n, n_iters=n_iters, d=self.d)
+            return _run_ppr_ell_sharded, (self._operands, self._dang, V), \
+                dict(mesh=self.mesh, axes=self._axes, n_true=self.n,
+                     n_iters=n_iters, d=self.d)
         if self.backend == "pallas_dense":
-            Hp, dangp = self._operands
-            Vp = np.zeros((V.shape[1], Hp.shape[1]), np.float32)
-            Vp[:, :self.n] = V.T
-            return _run_ppr_pallas(
-                Hp, dangp, jnp.asarray(Vp), self._scales, n=self.n,
-                n_iters=n_iters, d=self.d, block_n=self._block[0],
-                block_m=self._block[1], interpret=self.interpret)
-        return _run_ppr(self._operands, self._dang, jnp.asarray(V), self.d,
-                        backend=self._mv_backend, n=self.n,
-                        n_iters=n_iters)
+            return _run_ppr_pallas, (*self._operands, V, self._scales), dict(
+                n=self.n, n_iters=n_iters, d=self.d,
+                block_n=self._block[0], block_m=self._block[1],
+                interpret=self.interpret)
+        return _run_ppr, (self._operands, self._dang, V, self.d), dict(
+            backend=self._mv_backend, n=self.n, n_iters=n_iters)

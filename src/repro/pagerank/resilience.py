@@ -48,7 +48,7 @@ from repro.graph.delta import GraphDelta
 __all__ = [
     "GROWTH_FACTOR", "GROWTH_PATIENCE", "watchdog_init", "watchdog_update",
     "SolveInfo", "SolveResult", "ConvergenceError", "ranks_healthy",
-    "ppr_healthy", "EngineSnapshot", "RankStore", "RetryPolicy",
+    "ppr_health", "EngineSnapshot", "RankStore", "RetryPolicy",
     "RefreshOutcome", "ResilientRefresher", "FaultInjector", "raw_delta",
 ]
 
@@ -205,14 +205,15 @@ def ranks_healthy(pr, atol: float = 1e-3) -> bool:
                 and abs(float(pr.sum()) - 1.0) <= atol)
 
 
-def ppr_healthy(PPR, atol: float = 1e-3) -> bool:
-    """A servable (N, Q) personalized-PageRank batch: finite, non-negative,
-    every query column a distribution."""
-    PPR = np.asarray(PPR)
-    if PPR.size == 0 or not np.isfinite(PPR).all():
-        return False
-    return bool((PPR >= -1e-6).all()
-                and np.abs(PPR.sum(axis=0) - 1.0).max() <= atol)
+def ppr_health(PPR, atol: float = 1e-3) -> jax.Array:
+    """Per query column of an (N, Q) personalized-PageRank batch, whether
+    it is servable: finite, non-negative, a distribution (mass 1 to
+    ``atol``).  A (Q,) bool array, computed where ``PPR`` lives; under
+    ``jit`` it rides the serving step."""
+    PPR = jnp.asarray(PPR)
+    return (jnp.all(jnp.isfinite(PPR), axis=0)
+            & jnp.all(PPR >= -1e-6, axis=0)
+            & (jnp.abs(jnp.sum(PPR, axis=0) - 1.0) <= atol))
 
 
 # --------------------------------------------------------------------------- #

@@ -62,12 +62,6 @@ def pagerank_sparse_tol(matvec: Callable[[jax.Array], jax.Array], n: int,
                               (pr0, jnp.int32(0), jnp.float32(jnp.inf)))
 
 
-def top_k_proteins(pr: jax.Array, k: int = 10):
-    """Ranked (index, score) of the k most central proteins."""
-    scores, idx = jax.lax.top_k(pr, k)
-    return idx, scores
-
-
 def personalized_pagerank(matvec: Callable[[jax.Array], jax.Array], n: int,
                           seeds: jax.Array,
                           dangling: jax.Array | None = None,

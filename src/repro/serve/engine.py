@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from functools import partial
 from typing import Callable
 
 import jax
@@ -26,8 +27,7 @@ from repro.models import model as M
 from repro.obs.registry import default_registry
 from repro.pagerank.engine import PageRankEngine
 from repro.pagerank.resilience import (RankStore, ResilientRefresher,
-                                       RetryPolicy, ppr_healthy)
-from repro.pagerank.sparse import top_k_proteins
+                                       RetryPolicy, ppr_health)
 from repro.serve.cache import ResultCache
 
 
@@ -169,6 +169,21 @@ class ServeResilience:
     snapshots: int = 4
     healthy_atol: float = 1e-3
     dead_letter_maxlen: int = 256
+
+
+def top_k_proteins(pr, k: int = 10):
+    """Ranked (index, score) of the k highest-ranked vertices of a rank
+    vector (N,), or of every column of an (N, Q) batch as (Q, k) arrays."""
+    scores, idx = jax.lax.top_k(jnp.asarray(pr).T, k)
+    return idx, scores
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _rank_batch(PPR, atol, *, k: int):
+    """One device step over a served (N, Q) batch: every column's top-``k``
+    ids and scores, and its health flag (:func:`ppr_health`)."""
+    idx, scores = top_k_proteins(PPR, k)
+    return idx, scores, ppr_health(PPR, atol)
 
 
 @dataclasses.dataclass
@@ -516,11 +531,16 @@ class PageRankQueryEngine:
         batch, self._queue = self._queue, []
         if not batch:
             return []
-        if self.cache is None:
-            self._serve_queries(batch)
-            return batch
-        # cache-enabled path: answer repeats from the cache (no device
-        # work), solve only the misses, and cache what the misses produced
+        with self.metrics.span("serve", q=len(batch)):
+            if self.cache is None:
+                self._serve_queries(batch)
+            else:
+                self._serve_cached(batch)
+        return batch
+
+    def _serve_cached(self, batch) -> None:
+        """Answer repeats from the cache (no device work), solve only the
+        misses, and cache what the misses produced."""
         precision = str(getattr(self.engine, "precision", "f32"))
         t0 = time.perf_counter()
         hits: list[tuple[PPRQuery, np.ndarray]] = []
@@ -549,42 +569,44 @@ class PageRankQueryEngine:
         if misses:
             t1 = time.perf_counter()
             PPR = self._serve_queries([q for q, _ in misses])
+            # the (N, Q) matrix comes to the host once, for the cache alone
+            host = None if PPR is None else np.asarray(PPR)
             for j, (q, key) in enumerate(misses):
                 q.cache_outcome = "miss"
-                if PPR is not None and q.status != "degraded":
+                if host is not None and q.status != "degraded":
                     st["evictions"] += self.cache.put(
-                        key, np.asarray(PPR[:, j], np.float32),
+                        key, np.ascontiguousarray(host[:, j]),
                         self.graph_version)
             st["miss_ms"] = (time.perf_counter() - t1) * 1e3
         self._last_flush_stats = st
-        return batch
 
-    def _serve_queries(self, batch) -> np.ndarray | None:
+    def _serve_queries(self, batch) -> jax.Array | None:
         """Answer ``batch`` in place (results + resilience tags) with one
-        batched solve; returns the solved (N, Q) matrix so the cache path
-        can keep the full rank vectors (``None`` when the resilient path
-        degraded to global ranks — never cached)."""
+        batched solve and one device step that ranks every query; returns
+        the solved (N, Q) device matrix so the cache path can keep the full
+        rank vectors (``None`` when the resilient path degraded to global
+        ranks — never cached)."""
         if self.resilience is None:
             PPR = self._solve_batch([q.seeds for q in batch])  # (N, Q)
+            idx, scores, _ = self._rank(PPR, batch)
             for j, q in enumerate(batch):
-                idx, scores = top_k_proteins(PPR[:, j], k=q.top_k)
-                q.result = (np.asarray(idx), np.asarray(scores))
+                q.result = (idx[j, :q.top_k], scores[j, :q.top_k])
             return PPR
-        PPR = self._serve_ppr(batch)
-        if PPR is None and self._recoverable():
+        served = self._serve_ppr(batch)
+        if served is None and self._recoverable():
             # one recovery attempt, then one re-serve — bounded work per
             # flush, no retry storm.  Recovery rebuilds/rolls back the
             # engine, so any cached answer may now describe a different
             # graph: flush wholesale (no per-column story exists)
             self.refresher.recover(self.engine, tol=self.refresh_tol)
             self._invalidate_all()
-            PPR = self._serve_ppr(batch)
+            served = self._serve_ppr(batch)
         version = self.refresher.store.version
-        if PPR is not None:
+        if served is not None:
+            PPR, idx, scores = served
             status = "stale" if self._stale else "fresh"
             for j, q in enumerate(batch):
-                idx, scores = top_k_proteins(PPR[:, j], k=q.top_k)
-                q.result = (np.asarray(idx), np.asarray(scores))
+                q.result = (idx[j, :q.top_k], scores[j, :q.top_k])
                 q.status = status
                 q.graph_version = version
             return PPR
@@ -596,34 +618,44 @@ class PageRankQueryEngine:
             ranks = np.asarray(snap.ranks, np.float32)
         else:
             ranks = np.full(self.engine.n, 1.0 / self.engine.n, np.float32)
+        idx, scores = top_k_proteins(ranks, k=max(q.top_k for q in batch))
+        idx, scores = np.asarray(idx), np.asarray(scores)
         for q in batch:
-            idx, scores = top_k_proteins(ranks, k=q.top_k)
-            q.result = (np.asarray(idx), np.asarray(scores))
+            q.result = (idx[:q.top_k], scores[:q.top_k])
             q.status = "degraded"
             q.graph_version = version
         return None
 
-    def _solve_batch(self, seed_sets) -> np.ndarray:
+    def _rank(self, PPR, batch) -> tuple[np.ndarray, ...]:
+        """Every query's top-k ids and scores, (Q, k), and the column
+        health flags, (Q,), of the served (N, Q) batch: one device step,
+        whose small results alone come to the host."""
+        atol = (self.resilience or ServeResilience()).healthy_atol
+        with self.metrics.span("serve.topk", q=len(batch)):
+            out = _rank_batch(PPR, atol, k=max(q.top_k for q in batch))
+            return tuple(np.asarray(a) for a in out)
+
+    def _solve_batch(self, seed_sets) -> jax.Array:
         """The cold-solve choke point: hub-combination + bounded residual
         push when a landmark index is attached (exact-solve fallback per
         column lives inside ``answer``), else the classic batched power
-        iteration."""
+        iteration.  The (N, Q) matrix stays on the device."""
         if self.landmarks is not None:
             self.landmarks.ensure(self.graph_version)
             X, _ = self.landmarks.answer(seed_sets)
             return X
-        return np.asarray(self.engine.ppr(seed_sets,
-                                          n_iters=self.n_iters))
+        return self.engine.ppr(seed_sets, n_iters=self.n_iters)
 
-    def _serve_ppr(self, batch) -> np.ndarray | None:
-        """One batched PPR dispatch, health-checked: the (N, Q) matrix, or
-        ``None`` if the dispatch raised or produced a poisoned batch."""
+    def _serve_ppr(self, batch) -> tuple | None:
+        """One batched PPR dispatch, ranked and health-checked in one
+        device step: ``(PPR, ids, scores)``, or ``None`` if the dispatch
+        raised or produced a poisoned batch."""
         try:
-            PPR = np.asarray(self._solve_batch([q.seeds for q in batch]))
+            PPR = self._solve_batch([q.seeds for q in batch])
+            idx, scores, ok = self._rank(PPR, batch)
         except Exception:       # noqa: BLE001 — degradation contract
             return None
-        atol = self.resilience.healthy_atol
-        return PPR if ppr_healthy(PPR, atol=atol) else None
+        return (PPR, idx, scores) if ok.all() else None
 
     def query_batch(self, seed_sets, top_k: int = 10) -> list[tuple]:
         """One-shot convenience: serve ``seed_sets`` now, return per-user

@@ -13,7 +13,8 @@ from repro.graph import generators as gen
 from repro.graph import transition as tr
 from repro.pagerank import (pagerank_dense, pagerank_dense_fixed,
                             pagerank_on_fabric, pagerank_sparse)
-from repro.pagerank.sparse import pagerank_sparse_tol, top_k_proteins
+from repro.pagerank.sparse import pagerank_sparse_tol
+from repro.serve.engine import top_k_proteins
 
 
 def _numpy_pagerank(H, n_iters=100, d=0.85):

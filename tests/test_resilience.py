@@ -30,7 +30,7 @@ from repro.pagerank import (ConvergenceError, DynamicPageRankEngine,
                             FaultInjector, PageRankEngine, RankStore,
                             ResilientRefresher, RetryPolicy, SolveResult)
 from repro.pagerank.engine import SHARDED_BACKENDS
-from repro.pagerank.resilience import (ppr_healthy, ranks_healthy, raw_delta)
+from repro.pagerank.resilience import (ppr_health, ranks_healthy, raw_delta)
 from repro.serve import PageRankQueryEngine, ServeResilience
 
 DYN_BACKENDS = ["dense", "ell", "pallas_dense"]   # patchable layouts
@@ -494,5 +494,5 @@ def test_noisy_stream_serves_through_every_fault_class(net):
     # every accepted delta is in the graph; parity with a clean engine
     assert ranks_healthy(dyn2.ranks)
     assert _l1(dyn2.ranks, _scratch_ranks(cur[0], cur[1], n)) <= 1e-5
-    assert ppr_healthy(np.asarray(
-        dyn2.ppr(_seed_sets(n, seed=99), n_iters=50)))
+    assert bool(ppr_health(
+        dyn2.ppr(_seed_sets(n, seed=99), n_iters=50)).all())

@@ -84,3 +84,30 @@ def test_run_fixed_pallas_compiles(shape):
                                         d=0.85, block_n=BLOCK,
                                         block_m=BLOCK, interpret=False),
         shape((NP, NP)), shape((1, NP)))
+
+
+def test_ppr_serve_programs_compile(shape):
+    """The teleport matrix, the landmark estimate, the push and the serve
+    path's ranking step compile for a TPU v5e at a batch of 16 on a SELL
+    layout."""
+    import numpy as np
+
+    from repro.graph import generators as gen
+    from repro.pagerank.engine import PageRankEngine
+    from repro.pagerank.landmarks import (_hub_estimate, _hub_push,
+                                          _teleport)
+    from repro.serve.engine import _rank_batch
+
+    src, dst = gen.protein_network(2000, seed=0)
+    eng = PageRankEngine(src, dst, 2000, backend="ell")
+    ops = jax.tree.map(lambda a: shape(a.shape, a.dtype), eng._operands)
+    n, q, h = 2000, 16, 64
+    kw = dict(backend="sell", mesh=None, axes=(), block=(256, 256),
+              interpret=False)
+    V, dang = shape((n, q)), shape((n,))
+    _teleport.lower(shape((q, 4), jnp.int32), shape((q, 4)), n=n).compile()
+    _hub_estimate.lower(ops, dang, None, V, shape((n, h)),
+                        shape((h,), jnp.int32), d=0.85, **kw).compile()
+    _hub_push.lower(ops, dang, None, V, V, np.float32(1e-7), max_pushes=256,
+                    d=0.85, **kw).compile()
+    _rank_batch.lower(V, 1e-3, k=10).compile()
