@@ -215,6 +215,23 @@ def test_update_properties_random_deltas(backend, seed, dseed):
     assert _l1(pr, _scratch_ranks(src, dst, n, delta)) <= 1e-5
 
 
+@pytest.mark.parametrize("strategy", ["push", "warm", "rebuild"])
+def test_update_tol_bounds_the_rank_error(net, strategy):
+    """``update``'s tol bounds the refreshed ranks' L1 distance from the
+    fixed point: every strategy stops at a residual of (1 - d)·tol, not at
+    tol, from which a warm start can exit with a delta half applied."""
+    n, src, dst = net
+    tol = 1e-4
+    dyn = DynamicPageRankEngine(src, dst, n, backend="ell")
+    dyn.run_tol(tol, max_iters=500)
+    iu, iv = _absent_pairs(src, dst, n, 3, seed=6)
+    delta = GraphDelta(iu, iv, np.asarray(src[:2]), np.asarray(dst[:2]))
+    pr, info = dyn.update(delta, tol=tol, strategy=strategy)
+    assert info.strategy == strategy
+    assert info.residual <= (1 - dyn.d) * tol
+    assert _l1(pr, _scratch_ranks(src, dst, n, delta)) <= tol
+
+
 @pytest.mark.parametrize("backend", DYN_BACKENDS)
 def test_insert_then_delete_is_noop(net, backend):
     """Applying a delta and its inverse restores the prepared layout
@@ -500,6 +517,78 @@ def test_stream_of_updates_tracks_scratch(net):
         pr, _ = dyn.update(delta)
         cur = apply_delta(cur[0], cur[1], delta, n)
     assert _l1(pr, _scratch_ranks(cur[0], cur[1], n)) <= 1e-5
+
+
+# --------------------------------------------------------------------------- #
+# host key-set planning                                                       #
+# --------------------------------------------------------------------------- #
+def _edit_case(case):
+    """(sorted unique keys, keys to drop, keys to add) for one edit shape;
+    drop and add come unordered, as ``_plan`` hands over reverse keys."""
+    rng = np.random.default_rng(4)
+    keys = np.unique(rng.integers(0, 10**6, size=3000))
+    pool = np.setdiff1d(np.arange(10**6, dtype=np.int64), keys)
+    drop = rng.choice(keys[1:-1], 12, replace=False)
+    add = rng.choice(pool[(pool > keys[0]) & (pool < keys[-1])], 20,
+                     replace=False)
+    if case == "no_drops":
+        drop = drop[:0]
+    elif case == "no_adds":
+        add = add[:0]
+    elif case == "ends":
+        drop = np.concatenate([drop, keys[[0, -1]]])
+        add = np.concatenate([add, [keys[0] - 1, keys[-1] + 1]])
+    elif case == "drop_then_add_same_key":
+        add = np.concatenate([add, drop[:3]])
+    elif case == "rebuild_sized":
+        drop = rng.choice(keys, len(keys) // 10, replace=False)
+        add = rng.choice(pool, len(keys) // 10, replace=False)
+    return keys, rng.permutation(drop), rng.permutation(add)
+
+
+@pytest.mark.parametrize("case", ["no_drops", "no_adds", "ends",
+                                  "drop_then_add_same_key", "rebuild_sized"])
+def test_edit_sorted_matches_set_ops(case):
+    from repro.pagerank.dynamic import _edit_sorted
+    keys, drop, add = _edit_case(case)
+    before = keys.copy()
+    got = _edit_sorted(keys, drop, add)
+    want = np.union1d(np.setdiff1d(keys, drop, assume_unique=True), add)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(keys, before)     # input left alone
+
+
+@pytest.mark.parametrize("backend", DYN_BACKENDS)
+def test_plan_leaves_keys_and_inverse_delta_restores_them(net, backend):
+    """``_plan`` builds the post-delta key sets without touching the
+    engine's (``update`` rolls back by restoring them); a mixed delta and
+    its inverse, as the delta-stream benchmark cycles them, bring both key
+    arrays back to the originals with ranks on the from-scratch oracle."""
+    n, src, dst = net
+    dyn = DynamicPageRankEngine(src, dst, n, backend=backend)
+    dyn.run_tol(1e-7, max_iters=500)
+    keys0, rkeys0 = dyn._keys, dyn._rkeys
+    keys_copy, rkeys_copy = keys0.copy(), rkeys0.copy()
+    iu, iv = _absent_pairs(src, dst, n, 3, seed=5)
+    delta = GraphDelta(iu, iv, np.asarray(src[:2]), np.asarray(dst[:2]))
+    inverse = GraphDelta(delta.delete_src, delta.delete_dst,
+                         delta.insert_src, delta.insert_dst)
+
+    plan = dyn._plan(delta)
+    assert dyn._keys is keys0 and dyn._rkeys is rkeys0
+    np.testing.assert_array_equal(keys0, keys_copy)
+    np.testing.assert_array_equal(rkeys0, rkeys_copy)
+    s1, d1 = apply_delta(src, dst, delta, n)
+    np.testing.assert_array_equal(plan["keys"], edge_keys(s1, d1, n))
+    np.testing.assert_array_equal(plan["rkeys"], edge_keys(d1, s1, n))
+
+    pr, _ = dyn.update(delta)
+    assert _l1(pr, _scratch_ranks(s1, d1, n)) <= 1e-5
+    pr, _ = dyn.update(inverse)
+    np.testing.assert_array_equal(dyn._keys, keys_copy)
+    np.testing.assert_array_equal(dyn._rkeys, rkeys_copy)
+    assert _l1(pr, _scratch_ranks(src, dst, n)) <= 1e-5
 
 
 # --------------------------------------------------------------------------- #
