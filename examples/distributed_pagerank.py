@@ -8,7 +8,8 @@ re-injection is the diagonal broadcast (DESIGN.md §2).  Since PR 3 the
 whole thing is a :class:`~repro.pagerank.engine.PageRankEngine` backend:
 ``dense_sharded`` builds the blocked ``NamedSharding`` layout once and
 compiles the 100-iteration schedule into a single dispatch; the same
-engine serves query-sharded batched PPR to ``PageRankQueryEngine``.
+engine serves batched PPR over the same sharded H to
+``PageRankQueryEngine``.
 
 Run on a multi-chip host:  PYTHONPATH=src python examples/distributed_pagerank.py
 On the CPU, give it virtual devices first:
@@ -53,15 +54,15 @@ def main() -> None:
           f"(horizontal bus + diagonal re-injection)")
     print(f"distributed == single-device reference: OK")
 
-    # the same prepared engine serves multi-user personalized PageRank with
-    # the (N, Q) batch sharded over the mesh's query axis
+    # the same prepared engine serves multi-user personalized PageRank: each
+    # (N, Q) sweep runs over the sharded H
     qe = PageRankQueryEngine(eng, n_iters=40, max_batch=8)
     rng = np.random.default_rng(0)
     t0 = time.time()
     results = qe.query_batch(
         [rng.choice(n, size=3, replace=False) for _ in range(8)], top_k=5)
     dt = time.time() - t0
-    print(f"8-user PPR batch, query-sharded over the mesh: "
+    print(f"8-user PPR batch over the sharded H: "
           f"{dt * 1e3:.1f} ms -> top-1 proteins "
           f"{[int(idx[0]) for idx, _ in results]}")
 

@@ -53,11 +53,11 @@ the prepared arrays, never a rebuild:
   block stays, harmlessly); only an insert that *materializes a new block*
   escalates to the rebuild fallback.
 
-The push strategy runs shard-local on the sharded tiers
-(:func:`repro.pagerank.distributed.push_distributed_tol` /
-``push_distributed_sparse_tol``): the frontier update is elementwise on
-each device's shard of the rank vector and the residual L1 norm costs a
-single psum per sweep, inside the same ``instrumented_tol_loop`` driver —
+The push strategy is the engine's :func:`~repro.pagerank.engine.push_loop`
+over the prepared layout's affine map (:func:`~repro.pagerank.engine
+.global_push`), on every tier: on the sharded ones the frontier update
+is elementwise on the replicated or sharded rank vector and the residual
+a replicated scalar, inside the same ``instrumented_tol_loop`` driver —
 watchdogs, ``SolveResult.info`` and the residual trace ring work on the
 mesh exactly as they do single-device, and the auto push/warm/rebuild
 policy picks the same strategies sharded as it does single-device.
@@ -79,27 +79,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.graph.delta import GraphDelta, edge_keys
-from repro.kernels.streaming_matvec import streaming_matvec
-from repro.obs.trace import SolveTrace, instrumented_tol_loop
-from repro.pagerank import distributed as dist
-from repro.pagerank.engine import PageRankEngine, _dedupe_edges, _matvec
+from repro.obs.trace import SolveTrace
+from repro.pagerank.engine import PageRankEngine, _dedupe_edges, global_push
 from repro.pagerank.resilience import EngineSnapshot, make_solve_info
 
-__all__ = ["DynamicPageRankEngine", "UpdateInfo", "PATCHABLE_BACKENDS"]
-
-# every backend's prepared layout now accepts in-place edge-delta patches
-# (sharded scatters land on the owning devices under the existing
-# NamedShardings; BSR patches values inside the prepared block structure).
-# Capacity overflow — an ELL/SELL row outgrowing its slack, a BSR insert
-# needing a block the layout doesn't hold — still escalates to rebuild.
-# Reduced-precision tiers patch too: recomputed rows/columns are cast to
-# the layout's storage dtype before the scatter, never widening the
-# prepared arrays.  int8 is the exception — a changed row invalidates its
-# per-row quantization scale, so a value patch alone would dequantize the
-# row's untouched entries wrong; every int8 delta coerces to rebuild
-# (recorded on ``coerced_from``, same as capacity overflow).
-PATCHABLE_BACKENDS = ("dense", "ell", "pallas_dense", "bsr",
-                      "dense_sharded", "ell_sharded")
+__all__ = ["DynamicPageRankEngine", "UpdateInfo"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,9 +223,8 @@ def _scatter_rows(A, pos, rows, *, sharding=None):
 
 @jax.jit
 def _scatter_mask(mask, ci, flags):
-    """mask[..., ci_c] = flags_c for every chunk c; ci, flags (k, cap):
-    the dangling-mask patch, of a (n,) mask or the Pallas tier's (1, Mp)
-    one."""
+    """mask[ci_c] = flags_c for every chunk c; ci, flags (k, cap): the
+    dangling-mask patch of a layout's (rows,) mask."""
     def body(m, args):
         i, f = args
         return m.at[..., i].set(f), None
@@ -280,110 +263,6 @@ def _scatter_block_vals(B, br, sl, lr, lc, vals):
     with jax.named_scope("pagerank.row_patch"):
         B, _ = jax.lax.scan(body, B, (br, sl, lr, lc, vals))
     return B
-
-
-# --------------------------------------------------------------------------- #
-# Gauss–Southwell push: frontier-masked residual sweeps in one while_loop     #
-#                                                                             #
-# The SELL layout itself needs no runners of its own: engine._matvec knows    #
-# the "sell" tag, so the engine's generic whole-loop dispatchers (run /       #
-# run_tol / ppr) drive it unchanged via self._mv_backend.                     #
-# --------------------------------------------------------------------------- #
-def _push_loop(Ab, x0, tol, n, max_pushes, trace=False):
-    """Shared frontier loop.  ``Ab(x) = A·x + b`` is the damped PageRank
-    affine operator; the invariant solved for is the fixed point
-    ``x = Ab(x)``.  Every sweep pushes the whole frontier mask
-    ``|r| ≥ tol/n`` (whenever ``‖r‖₁ > tol`` at least one entry qualifies,
-    so the loop cannot stall) and refreshes the residual from scratch —
-    one operator sweep per push round, same cost as an incremental
-    residual update but immune to float drift in the bookkeeping.
-
-    Runs on the same instrumented driver as the engine's tolerance loops
-    (:func:`repro.obs.trace.instrumented_tol_loop`: NaN/Inf and
-    sustained-growth watchdog — a corrupted layout makes the push residual
-    *grow* every sweep, so without it the loop spins all ``max_pushes`` —
-    plus the optional residual-trajectory ring).  The real initial
-    residual seeds the loop, so an already-converged frontier exits in
-    zero sweeps.  Returns ``(x, iters, residual, grow, ring)``."""
-    thresh = tol / n
-
-    def step(state):
-        x, r = state
-        with jax.named_scope("pagerank.push"):
-            x = x + r * (jnp.abs(r) >= thresh).astype(x.dtype)
-            r = Ab(x) - x
-            return (x, r), jnp.sum(jnp.abs(r))
-
-    with jax.named_scope("pagerank.push"):
-        r0 = Ab(x0) - x0
-    (x, _), iters, res, grow, ring = instrumented_tol_loop(
-        step, (x0, r0), tol=tol, max_iters=max_pushes, watchdog=True,
-        trace=trace, res0=jnp.sum(jnp.abs(r0)))
-    return x, iters, res, grow, ring
-
-
-@partial(jax.jit, static_argnames=("backend", "n", "max_pushes", "trace"))
-def _push_tol(operands, dang, d, tol, x0, *, backend: str, n: int,
-              max_pushes: int, trace: bool = False):
-    if (backend == "dense" and len(operands) == 1
-            and operands[0].dtype == jnp.float32):
-        # the f32 dense operand is dangling-FIXED: the uniform leak columns
-        # are already folded in, so A·x is just d·H·x.  Reduced-precision
-        # dense tiers store H *unfixed* (and int8 appends a scale operand),
-        # so they take the generic explicit-leak branch below — the arity/
-        # dtype test is static under jit, so the f32 program is unchanged.
-        def Ab(x):
-            return d * (operands[0] @ x) + (1.0 - d) / n
-    else:
-        def Ab(x):
-            with jax.named_scope("pagerank.vector"):
-                return d * (_matvec(backend, operands, x)
-                            + jnp.sum(x * dang) / n) + (1.0 - d) / n
-
-    return _push_loop(Ab, x0, tol, n, max_pushes, trace=trace)
-
-
-@partial(jax.jit, static_argnames=("n", "block_n", "block_m", "interpret",
-                                   "max_pushes", "trace"))
-def _push_pallas(Hp, dangp, d, tol, x0, *, n: int, block_n: int,
-                 block_m: int, interpret: bool, max_pushes: int,
-                 trace: bool = False):
-    # state lives in the pre-padded (1, Mp) layout; pad entries of H, dang
-    # and x0 are zero, so the residual is identically zero on the pad tail
-    # and the frontier never touches it
-    Mp = Hp.shape[1]
-    real = (jnp.arange(Mp) < n).astype(jnp.float32)[None, :]
-    xp0 = jnp.pad(x0, (0, Mp - n))[None, :]
-
-    def Ab(xp):
-        y = streaming_matvec(Hp, xp, block_n=block_n, block_m=block_m,
-                             interpret=interpret)
-        leak = jnp.sum(xp * dangp)
-        return d * (y + leak / n * real) + (1.0 - d) / n * real
-
-    xp, iters, res, grow, ring = _push_loop(Ab, xp0, tol, n, max_pushes,
-                                            trace=trace)
-    return xp[0, :n], iters, res, grow, ring
-
-
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_pushes",
-                                   "d", "trace"))
-def _push_dense_sharded(H, dang, tol, x0, *, mesh, axes, n_true, max_pushes,
-                        d, trace: bool = False):
-    x, sweeps, res, grow, ring = dist.push_distributed_tol(
-        H, mesh, x0, tol=tol, max_pushes=max_pushes, d=d, row_axis=axes[0],
-        col_axis=axes[1], dangling=dang, n_true=n_true, trace=trace)
-    return x[:n_true], sweeps, res, grow, ring
-
-
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_pushes",
-                                   "d", "trace"))
-def _push_ell_sharded(layout, dang, tol, x0, *, mesh, axes, n_true,
-                      max_pushes, d, trace: bool = False):
-    x, sweeps, res, grow, ring = dist.push_distributed_sparse_tol(
-        layout, mesh, x0, tol=tol, max_pushes=max_pushes, d=d,
-        dangling=dang, axes=axes, n_true=n_true, trace=trace)
-    return x[:n_true], sweeps, res, grow, ring
 
 
 # --------------------------------------------------------------------------- #
@@ -448,7 +327,7 @@ class DynamicPageRankEngine(PageRankEngine):
         back.  Patches only zero/overwrite entries of existing blocks
         (structure never changes between rebuilds), so the map stays valid
         until the next ``_prepare_layout``."""
-        bsr = self._operands[0]
+        bsr = self.operands[0]
         bs = int(bsr.block_size)
         self._bsr_nbc = -(-self.n // bs)
         pairs = np.unique((np.asarray(dst, np.int64) // bs)
@@ -468,8 +347,7 @@ class DynamicPageRankEngine(PageRankEngine):
         return self._pr
 
     def run(self, n_iters: int = 100) -> jax.Array:
-        # the engine's generic runners drive the SELL layout through
-        # _mv_backend — these overrides only stash the latest ranks
+        # these overrides only stash the latest ranks
         pr = super().run(n_iters)
         self._pr = pr
         return pr
@@ -581,12 +459,11 @@ class DynamicPageRankEngine(PageRankEngine):
                 self.run_tol(tol=tol, max_iters=max_iters)
             return self._pr, UpdateInfo("noop", 0, 0, 0, 0, 0, 0.0, False)
         # validate BEFORE committing any bookkeeping, so a raise leaves the
-        # engine exactly as it was (no half-applied delta).  int8 layouts
-        # never patch: a changed row needs a new quantization scale, and
+        # engine exactly as it was (no half-applied delta).  Every layout
+        # patches in place up to its capacity, but int8 layouts never
+        # patch: a changed row needs a new quantization scale, and
         # re-scaling re-quantizes the whole row — a rebuild in disguise.
-        patchable = (self.backend in PATCHABLE_BACKENDS
-                     and not plan["overflow"]
-                     and self.precision != "int8")
+        patchable = not plan["overflow"] and self.precision != "int8"
         coerced_from = None
         if strategy == "auto":
             if (plan["n_changed"] > self.rebuild_frac
@@ -605,9 +482,9 @@ class DynamicPageRankEngine(PageRankEngine):
                     strategy, coerced_from = "rebuild", want
         elif strategy in ("push", "warm") and not patchable:
             raise ValueError(
-                f"strategy {strategy!r} needs a patchable layout "
-                f"(backend in {PATCHABLE_BACKENDS}, no capacity overflow "
-                f"or BSR block-structure change, precision != 'int8')")
+                f"strategy {strategy!r} needs a patchable layout (no "
+                f"capacity overflow or BSR block-structure change, "
+                f"precision != 'int8')")
         elif strategy == "push" and self._pr is None:
             raise ValueError("push needs previous ranks; run/run_tol first")
 
@@ -715,7 +592,7 @@ class DynamicPageRankEngine(PageRankEngine):
                 # the post-delta sets can demand a block the structure
                 # doesn't hold — that is the genuine structure change that
                 # forces a rebuild.
-                bs = int(self._operands[0].block_size)
+                bs = int(self.operands[0].block_size)
                 old_nbrs = [_key_slice(self._keys, int(u), n) for u in cols]
                 new_nbrs = [_key_slice(new_keys, int(u), n) for u in cols]
                 need = [(vv // bs) * np.int64(self._bsr_nbc) + int(u) // bs
@@ -765,43 +642,37 @@ class DynamicPageRankEngine(PageRankEngine):
         n = self.n
         cols = plan["cols"]
         flags = (self._outdeg[cols] == 0).astype(np.float32)
-        mask = tuple(jnp.asarray(a)
-                     for a in _stack_chunks(cols, flags, cap=32))
-        dang = _scatter_mask(self._dang, *mask)
+        dang = _scatter_mask(self.prepared.dang,
+                             *(jnp.asarray(a)
+                               for a in _stack_chunks(cols, flags, cap=32)))
         if self.mesh is not None:
             # the sharded tiers keep the dangling mask replicated; pin the
-            # patched copy back to P() so no runner pays a reshard
+            # patched copy back to P() so no sweep pays a reshard
             dang = jax.device_put(dang, NamedSharding(self.mesh, P()))
-        self._dang = dang
-        if self.backend in ("dense", "dense_sharded"):
-            # the sharded and reduced-precision H are stored dangling-
-            # UNFIXED (explicit leak), the single-device f32 dense operand
-            # dangling-fixed; patch columns are cast to the layout's
-            # storage dtype (a no-op on f32) so the scatter never widens it
-            H0 = self._operands[0]
+        self.prepared = dataclasses.replace(self.prepared, dang=dang)
+        if self.backend == "bsr":
+            self._patch_bsr(plan)
+            return 0, len(cols)
+        if self.backend in ("dense", "dense_sharded", "pallas_dense"):
+            # the sharded, Pallas and reduced-precision H are stored
+            # dangling-UNFIXED (explicit leak), the single-device f32 dense
+            # operand dangling-fixed; patch columns are cast to the
+            # layout's storage dtype (a no-op on f32) so the scatter never
+            # widens it, and land under the fabric mesh's P(row, col)
+            H0 = self.operands[0]
             mat = np.stack([self._column(int(u), fix_dangling=self.backend
                                          == "dense"
                                          and self.precision == "f32")
                             for u in cols], axis=0)        # (C, n)
             ci, mats = _stack_chunks(cols, mat, cap=32)
             sharding = (None if self.mesh is None
-                        else NamedSharding(self.mesh, P(*self._axes)))
+                        else NamedSharding(self.mesh,
+                                           P(*self.mesh.axis_names)))
             H = _scatter_cols(H0, jnp.asarray(ci),
                               jnp.asarray(mats).astype(H0.dtype), n=n,
                               sharding=sharding)
-            self._operands = (H,)
-            return 0, len(cols)
-        if self.backend == "bsr":
-            self._patch_bsr(plan)
-            return 0, len(cols)
-        if self.backend == "pallas_dense":
-            Hp, dangp = self._operands
-            mat = np.stack([self._column(int(u), fix_dangling=False)
-                            for u in cols], axis=0)        # (C, n)
-            ci, mats = _stack_chunks(cols, mat, cap=32)
-            Hp = _scatter_cols(Hp, jnp.asarray(ci),
-                               jnp.asarray(mats).astype(Hp.dtype), n=n)
-            self._operands = (Hp, _scatter_mask(dangp, *mask))
+            self.prepared = dataclasses.replace(
+                self.prepared, operands=(H,) + self.operands[1:])
             return 0, len(cols)
         # ell / ell_sharded: rewrite every affected SELL row in its tier
         # (vectorized: one gather over the reverse key set builds all rows
@@ -813,7 +684,7 @@ class DynamicPageRankEngine(PageRankEngine):
         # a power-law graph) takes 512-row chunks, the others 64, so a
         # delta patches each tier in few chunks and few chunk counts
         rows = plan["rows"]
-        inv, tiers = self._operands
+        inv, tiers = self.operands
         tiers = list(tiers)
         n_rows = sum(self._sell.rows)
         for t, k in enumerate(self._sell.widths):
@@ -833,7 +704,8 @@ class DynamicPageRankEngine(PageRankEngine):
                                       sharding=sharding),
                         _scatter_rows(i, pos, jnp.asarray(ix),
                                       sharding=sharding))
-        self._operands = (inv, tuple(tiers))
+        self.prepared = dataclasses.replace(self.prepared,
+                                            operands=(inv, tuple(tiers)))
         return len(rows), len(cols)
 
     def _patch_bsr(self, plan: dict) -> None:
@@ -846,7 +718,7 @@ class DynamicPageRankEngine(PageRankEngine):
         touched block exists (a miss is the structure change that forces a
         rebuild), so the host (block-row, block-col) -> slot map resolves
         every coordinate."""
-        bsr = self._operands[0]
+        bsr = self.operands[0]
         bs = int(bsr.block_size)
         parts = []
         for u, old, new in zip(plan["cols"], plan["bsr_old"],
@@ -868,7 +740,8 @@ class DynamicPageRankEngine(PageRankEngine):
         blocks = _scatter_block_vals(
             bsr.blocks, jnp.asarray(b), jnp.asarray(s), jnp.asarray(r),
             jnp.asarray(c), jnp.asarray(v).astype(bsr.blocks.dtype))
-        self._operands = (dataclasses.replace(bsr, blocks=blocks),)
+        self.prepared = dataclasses.replace(
+            self.prepared, operands=(dataclasses.replace(bsr, blocks=blocks),))
 
     def _rebuild_rows(self, sel: np.ndarray, k: int
                       ) -> tuple[np.ndarray, np.ndarray]:
@@ -897,27 +770,6 @@ class DynamicPageRankEngine(PageRankEngine):
     # ------------------------------ push -------------------------------- #
     def _push(self, x0: jax.Array, tol: float, max_pushes: int,
               trace: bool = True):
-        if self.backend == "dense_sharded":
-            return _push_dense_sharded(
-                self._operands[0], self._dang, jnp.float32(tol),
-                self._pad_x0(jnp.asarray(x0, jnp.float32)),
-                mesh=self.mesh, axes=self._axes, n_true=self.n,
-                max_pushes=max_pushes, d=self.d, trace=trace)
-        if self.backend == "ell_sharded":
-            return _push_ell_sharded(
-                self._operands, self._dang, jnp.float32(tol),
-                self._pad_x0(jnp.asarray(x0, jnp.float32)),
-                mesh=self.mesh, axes=self._axes, n_true=self.n,
-                max_pushes=max_pushes, d=self.d, trace=trace)
-        if self.backend == "pallas_dense":
-            Hp, dangp = self._operands
-            return _push_pallas(Hp, dangp, self.d, jnp.float32(tol),
-                                jnp.asarray(x0), n=self.n,
-                                block_n=self._block[0],
-                                block_m=self._block[1],
-                                interpret=self.interpret,
-                                max_pushes=max_pushes, trace=trace)
-        return _push_tol(self._operands, self._dang, self.d,
-                         jnp.float32(tol), jnp.asarray(x0),
-                         backend=self._mv_backend, n=self.n,
-                         max_pushes=max_pushes, trace=trace)
+        return global_push(self.prepared, self.d, jnp.float32(tol),
+                           jnp.asarray(x0, jnp.float32),
+                           max_pushes=max_pushes, trace=trace)

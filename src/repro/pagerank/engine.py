@@ -7,45 +7,49 @@ dangling leak.  The paper's headline number (213.6 ms for 5k nodes x 100
 iterations) comes from keeping the entire power iteration on the fabric
 with no host intervention; :class:`PageRankEngine` is the JAX analogue:
 
-* **Prepare once** — the padded/blocked layout (dense, ELL, BSR, or the
-  Pallas pre-padded dense layout) is built at construction; nothing in the
-  hot loop pads or reshapes.
-* **Whole-loop compilation** — fixed schedules run as a single
-  ``lax.scan`` and tolerance-terminated runs as a single
-  ``lax.while_loop``, so 100 iterations are one dispatch, not 100
-  dispatches + syncs.
-* **In-kernel dangling fusion** — the Pallas tier uses
+* **Prepare once** — the backend's layout is built at construction into
+  one :class:`Layout`, a pytree of its device arrays whose static fields
+  (node count, mesh, Pallas blocks) key the compiled programs; nothing in
+  the hot loop pads or reshapes.  The layout decision lives in
+  ``PageRankEngine._prepare_layout`` alone: every other caller asks the
+  layout for its matvec ``mv`` and its global step.
+* **Four loop drivers, written once** — :func:`fixed_loop` (one
+  ``lax.scan``), :func:`tol_loop` (one ``lax.while_loop`` with the
+  convergence watchdog), :func:`ppr_loop` (the batched (N, Q) personalized
+  scan) and :func:`push_loop` (the Gauss–Southwell frontier push of an
+  (N,) vector or an (N, Q) batch), each over any layout, so 100
+  iterations are one dispatch, not 100 dispatches + syncs.
+* **In-kernel dangling fusion** — the Pallas tier's step is
   :func:`repro.kernels.pagerank_step.pagerank_step_fused`, which emits
   ``sum(y * dangling)`` from the same epilogue that applies the affine
-  term; the scan carries it as the next iteration's scalar ``t``, deleting
+  term; the loop carries it as the next iteration's scalar ``t``, deleting
   the per-iteration extra pass over the rank vector.
 * **Backend auto-selection** — by graph density and the active JAX
   device (``interpret`` for the Pallas tiers is derived from the device,
   not an import-time constant).
 * **Batched personalized PageRank** — Q personalization queries propagate
   as one (N, Q) rank matrix sharing a single sweep over H per iteration
-  (the MELOPPR-style batching; the Pallas tier rides the already-batched
-  ``streaming_matvec``).
-* **Sharded multi-device tiers** — ``dense_sharded`` runs the paper's
-  fabric schedule (:mod:`repro.pagerank.distributed` over
+  (the MELOPPR-style batching).
+* **Sharded multi-device tiers** — ``dense_sharded`` steps the paper's
+  fabric schedule (:func:`repro.pagerank.distributed.fabric_step` over
   :mod:`repro.core.fabric_matvec`) with H blocked ``P(row, col)`` over a
   2-D device mesh; ``ell_sharded`` row-shards a sliced-ELL layout
   (:mod:`repro.pagerank.sell`) over the flattened mesh with one
-  ``all_gather`` per iteration.  Both build their ``NamedSharding``
-  layouts once at construction and keep tolerance-based early exit
-  working across the mesh (the residual is a replicated scalar); batched
-  (N, Q) PPR shards the query axis (``dense_sharded``) or the rows, as the
-  global solve does (``ell_sharded``).
+  ``all_gather`` per sweep (:func:`repro.pagerank.distributed
+  .sell_mv_sharded`).  Both build their ``NamedSharding`` layouts once at
+  construction, zero-pad N to the mesh, and keep tolerance-based early
+  exit working across the mesh (the residual is a replicated scalar).
 
 The canonical per-iteration step functions live in
 :mod:`repro.pagerank.steps` and are shared with ``repro.pagerank.dense`` /
 ``repro.pagerank.sparse``, so every tier (and every test oracle) runs
-literally the same arithmetic; the engine's dense tier dispatches the very
-same jitted ``pagerank_dense_fixed`` program as the reference, making the
-two bit-identical.
+literally the same arithmetic; the engine's f32 dense tier steps the
+reference's own ``dense_step``, making the two bit-identical.
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import math
 import warnings
 from functools import partial
@@ -69,7 +73,6 @@ from repro.pagerank import distributed as dist
 from repro.pagerank import sell
 from repro.obs.registry import default_registry
 from repro.obs.trace import SolveTrace, instrumented_tol_loop
-from repro.pagerank.dense import pagerank_dense, pagerank_dense_fixed
 from repro.pagerank.precision import (PRECISIONS, STORAGE_DTYPES,
                                       layout_nbytes, quantize_int8,
                                       resolve_precision, rowmax_scales,
@@ -80,7 +83,9 @@ from repro.pagerank.steps import (dense_step, ppr_step, ppr_step_batched,
                                   seed_matrix, sparse_step)
 
 __all__ = ["PageRankEngine", "select_backend", "dense_step", "sparse_step",
-           "ppr_step", "ppr_step_batched", "seed_matrix", "PRECISIONS"]
+           "ppr_step", "ppr_step_batched", "seed_matrix", "PRECISIONS",
+           "Layout", "fixed_loop", "tol_loop", "ppr_loop", "push_loop",
+           "global_push"]
 
 BACKENDS = ("dense", "ell", "bsr", "pallas_dense", "dense_sharded",
             "ell_sharded")
@@ -145,9 +150,6 @@ def _dedupe_edges(src: np.ndarray, dst: np.ndarray,
     return delta_mod.dedupe_directed(src, dst, n, drop_self_loops=False)
 
 
-# --------------------------------------------------------------------------- #
-# whole-loop compiled runners (XLA backends)                                  #
-# --------------------------------------------------------------------------- #
 def _row_scale(y: jax.Array, scales: jax.Array | None) -> jax.Array:
     """Fold an int8 layout's per-row f32 dequantization scales into the
     accumulated f32 row sums (vector or batched-matrix shaped)."""
@@ -156,230 +158,297 @@ def _row_scale(y: jax.Array, scales: jax.Array | None) -> jax.Array:
     return y * (scales if y.ndim == 1 else scales[:, None])
 
 
-def _matvec(backend: str, operands, x: jax.Array) -> jax.Array:
-    """Dispatch y = H @ x on the prepared layout tag.
-
-    Value arrays may be stored reduced-precision (bf16/f16/int8); they are
-    upcast at the multiply (a trace-time no-op on f32 layouts, keeping the
-    f32 tier's program bit-identical) and accumulated in f32.  An int8
-    dense layout appends its per-row f32 scale vector to the operand tuple
-    — the tuple length is static under jit, so the scaled variant traces
-    to its own program and the float tiers never pay a branch.
-    """
-    if backend == "dense":
-        scales = operands[1] if len(operands) == 2 else None
-        return _row_scale(upcast_f32(operands[0]) @ x, scales)
-    if backend == "sell":
-        # sliced ELLPACK, the ``ell`` tier: the layout's own module builds
-        # and reads it, int8 scales included
-        return sell.sell_rows(operands, x)
-    if backend == "bsr":
-        # BSRMatrix upcasts its own blocks and owns its row_scales field
-        bsr = operands[0]
-        return bsr.matvec(x) if x.ndim == 1 else bsr.matmat(x)
-    raise ValueError(f"unknown backend {backend!r}")
+def _real(v: jax.Array, n: int) -> jax.Array:
+    """The first ``n`` rows of a padded vector or batch: the graph's
+    nodes (no slice where nothing is padded)."""
+    return v if v.shape[0] == n else v[:n]
 
 
-@partial(jax.jit, static_argnames=("backend", "n", "n_iters"))
-def _run_fixed(operands, dang, d, *, backend: str, n: int, n_iters: int):
-    pr0 = jnp.full((n,), 1.0 / n, jnp.float32)
-
-    def body(pr, _):
-        return sparse_step(lambda v: _matvec(backend, operands, v),
-                           pr, dang, d, n), None
-
-    pr, _ = jax.lax.scan(body, pr0, None, length=n_iters)
-    return pr
+def _stored(H: np.ndarray, precision: str, put, put_scales) -> tuple:
+    """A dense H's operands in its storage precision: ``(H,)``, or
+    ``(Hq, scales)`` for int8 with its per-row f32 dequantization scales."""
+    if precision == "int8":
+        scales = rowmax_scales(np.abs(H).max(axis=1, initial=0.0))
+        return put(quantize_int8(H, scales[:, None])), put_scales(scales)
+    return (put(jnp.asarray(H).astype(STORAGE_DTYPES[precision])),)
 
 
-@partial(jax.jit, static_argnames=("backend", "n", "max_iters", "watchdog",
-                                   "trace"))
-def _run_tol(operands, dang, d, tol, x0, *, backend: str, n: int,
-             max_iters: int, watchdog: bool = True, trace: bool = False):
-    """Returns ``(pr, iters, residual, grow, ring)`` — ``grow`` is the
-    convergence watchdog's consecutive-growth counter at exit (0 with
-    ``watchdog=False``, the overhead-measurement baseline) and ``ring``
-    the on-device residual-trajectory ring (``None`` with
-    ``trace=False``)."""
-    pr0 = jnp.full((n,), 1.0 / n, jnp.float32) if x0 is None else x0
+# --------------------------------------------------------------------------- #
+# prepared layouts: one pytree per backend family                             #
+# --------------------------------------------------------------------------- #
+def _static():
+    return dataclasses.field(metadata={"static": True})
 
-    def step(pr):
-        new = sparse_step(lambda v: _matvec(backend, operands, v),
-                          pr, dang, d, n)
+
+def _pytree(cls):
+    """A frozen dataclass registered as a pytree: its array fields are the
+    leaves, its ``_static()`` fields ride in the treedef, the jit key."""
+    return jax.tree_util.register_dataclass(
+        dataclasses.dataclass(frozen=True, kw_only=True)(cls))
+
+
+@_pytree
+class Layout:
+    """A prepared transition matrix H over ``rows`` rows, the first ``n``
+    of them the graph's nodes.  Pad rows and columns of H are zero, so pad
+    entries never feed back into real ones.
+
+    The leaves are the device arrays: ``operands`` (int8 scales among
+    them) and the (rows,) dangling mask ``dang``.  ``mv`` is H·X without
+    the dangling fix, for a (rows,) vector or a (rows, Q) batch.  The
+    global iteration runs ``start`` → ``step``… and reads its iterate with
+    ``vector``; by default a step is one ``sparse_step`` of ``mv`` (an
+    engine-module global, looked up when a solve is traced).  ``affine`` is
+    the same damped map as the push sweeps it."""
+    operands: tuple
+    dang: jax.Array
+    n: int = _static()
+
+    @property
+    def rows(self) -> int:
+        return self.dang.shape[0]
+
+    def mv(self, X: jax.Array) -> jax.Array:
+        raise NotImplementedError
+
+    def pad(self, X: jax.Array) -> jax.Array:
+        """Zero rows of a vector or batch up to ``rows``."""
+        if X.shape[0] == self.rows:
+            return X
+        return jnp.pad(X, ((0, self.rows - X.shape[0]),)
+                       + ((0, 0),) * (X.ndim - 1))
+
+    def start(self, x0: jax.Array | None):
+        """The loop state of ``x0`` (the uniform vector for ``None``)."""
+        return self.pad(jnp.full((self.n,), 1.0 / self.n, jnp.float32)
+                        if x0 is None else x0)
+
+    def step(self, state, d):
+        return sparse_step(self.mv, state, self.dang, d, self.n)
+
+    def vector(self, state) -> jax.Array:
+        """The (rows,) iterate of a loop state."""
+        return state
+
+    def affine(self, x: jax.Array, d) -> jax.Array:
+        """``x -> A·x + b`` of a (rows,) vector, as the push sweeps it:
+        the matvec before the leak, the operand order of the compiled
+        refresh push."""
         with jax.named_scope("pagerank.vector"):
-            return new, jnp.sum(jnp.abs(new - pr))
-
-    return instrumented_tol_loop(step, pr0, tol=tol, max_iters=max_iters,
-                                 watchdog=watchdog, trace=trace)
+            return (d * (self.mv(x) + jnp.sum(x * self.dang) / self.n)
+                    + (1.0 - d) / self.n)
 
 
-@partial(jax.jit, static_argnames=("backend", "n", "n_iters"))
-def _run_ppr(operands, dang, V, d, *, backend: str, n: int, n_iters: int):
-    if backend == "dense":
-        # the f32 dense operand is the dangling-FIXED H (uniform 1/n leak
-        # folded into the dangling columns — right for global PageRank,
-        # wrong for PPR where the leak teleports to V).  Zeroing those
-        # columns reconstructs the unfixed H exactly; hoisted out of the
-        # scan as a loop invariant.  Reduced-precision dense tiers store H
-        # *unfixed* (their dangling columns are already zero), so the same
-        # masking is a mathematical no-op and one program serves both.
-        scales = operands[1] if len(operands) == 2 else None
-        H = upcast_f32(operands[0]) * (1.0 - dang)[None, :]
-        mv = lambda X: _row_scale(H @ X, scales)
-    else:
-        mv = lambda X: _matvec(backend, operands, X)
+@_pytree
+class SellLayout(Layout):
+    """The sliced ELLPACK of :mod:`repro.pagerank.sell` (the ``ell``
+    tier): one gather per degree tier."""
 
-    def body(PR, _):
-        return ppr_step_batched(mv, PR, V, dang, d), None
+    def mv(self, X):
+        return sell.sell_rows(self.operands, X)
 
-    PR, _ = jax.lax.scan(body, V, None, length=n_iters)
-    return PR
+
+@_pytree
+class ShardedSellLayout(SellLayout):
+    """A SELL row-sharded over the flattened mesh (``ell_sharded``): each
+    device sweeps its own rows and one tiled ``all_gather`` re-assembles
+    the replicated image."""
+    mesh: Mesh = _static()
+    axes: tuple = _static()
+
+    def mv(self, X):
+        return dist.sell_mv_sharded(self.operands, X, self.mesh, self.axes)
+
+
+@_pytree
+class BsrLayout(Layout):
+    """MXU-aligned block-sparse rows; ``BSRMatrix`` upcasts its own blocks
+    and owns its row scales."""
+
+    def mv(self, X):
+        bsr = self.operands[0]
+        return bsr.matvec(X) if X.ndim == 1 else bsr.matmat(X)
+
+
+@_pytree
+class DenseLayout(Layout):
+    """Dense H stored dangling-UNFIXED in a reduced precision (the fix
+    would densify the dangling columns with 1/n values that quantize
+    poorly): ``(H,)``, or ``(Hq, scales)`` for int8."""
+
+    def mv(self, X):
+        H, *scales = self.operands
+        return _row_scale(upcast_f32(H) @ X, scales[0] if scales else None)
+
+
+@_pytree
+class FixedDenseLayout(DenseLayout):
+    """f32 dense H stored dangling-FIXED (the uniform leak folded into the
+    dangling columns): the global step is the reference's ``dense_step``
+    itself.  Zeroing the dangling columns gives back the unfixed H that
+    the personalized operator needs."""
+
+    def mv(self, X):
+        return (self.operands[0] * (1.0 - self.dang)[None, :]) @ X
+
+    def step(self, x, d):
+        return dense_step(self.operands[0], x, d)
+
+    affine = step
+
+
+@_pytree
+class DenseShardedLayout(DenseLayout):
+    """Dense H stored dangling-unfixed, blocked ``P(row, col)`` over a 2-D
+    mesh (``dense_sharded``): the global step is the paper's fabric
+    iteration; the batched matvec is GSPMD's."""
+    mesh: Mesh = _static()
+    axes: tuple = _static()
+
+    def start(self, x0):
+        return jax.lax.with_sharding_constraint(
+            super().start(x0), NamedSharding(self.mesh, P(self.axes[1])))
+
+    def step(self, x, d):
+        H, *scales = self.operands
+        return dist.fabric_step(H, x, self.dang, self.mesh, *self.axes, d,
+                                self.n, scales[0] if scales else None)
+
+    affine = step
+
+
+@_pytree
+class PallasLayout(Layout):
+    """The pre-padded dense layout of the fused Pallas kernel
+    (``pallas_dense``): ``(Hp,)`` or ``(Hp, scales)`` with (1, rows) int8
+    scales.  The global step carries ``(xp, t)``, the (1, rows) iterate
+    and the next step's leak term, which the kernel's epilogue emits; the
+    kernel compiles ``d`` in, so this layout carries it."""
+    d: float = _static()
+    block: tuple = _static()
+    interpret: bool = _static()
+
+    def _t(self, leak):
+        return self.d * leak / self.n + (1.0 - self.d) / self.n
+
+    def mv(self, X):
+        Hp, *scales = self.operands
+        Y = streaming_matvec(Hp, X[None, :] if X.ndim == 1 else X.T,
+                             block_n=self.block[0], block_m=self.block[1],
+                             interpret=self.interpret)
+        if scales:
+            Y = Y * scales[0]
+        return Y[0] if X.ndim == 1 else Y.T
+
+    def start(self, x0):
+        xp = super().start(x0)[None, :]
+        return xp, self._t(jnp.sum(xp * self.dang))
+
+    def step(self, state, d):
+        xp, t = state
+        yp, leak = pagerank_step_fused(
+            self.operands[0], xp, self.dang[None, :], t, *self.operands[1:],
+            d=self.d, block_n=self.block[0], block_m=self.block[1],
+            interpret=self.interpret)
+        return yp, self._t(leak)
+
+    def vector(self, state):
+        return state[0][0]
 
 
 # --------------------------------------------------------------------------- #
-# whole-loop compiled runners (sharded multi-device tiers)                    #
-#                                                                             #
-# The mesh, axis names, true node count, and schedule length are all static: #
-# one compiled program per (mesh, schedule), every call one dispatch.  The   #
-# distributed schedules themselves live in repro.pagerank.distributed.       #
+# the loop drivers                                                            #
 # --------------------------------------------------------------------------- #
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_fixed_dense_sharded(H, dang, scales=None, *, mesh, axes, n_true,
-                             n_iters, d):
-    pr = dist.pagerank_distributed(H, mesh, n_iters=n_iters, d=d,
-                                   row_axis=axes[0], col_axis=axes[1],
-                                   dangling=dang, n_true=n_true,
-                                   scales=scales)
-    return pr[:n_true]
+@partial(jax.jit, static_argnames=("n_iters",))
+def fixed_loop(lay: Layout, d, *, n_iters: int) -> jax.Array:
+    """``n_iters`` global iterations from the uniform start; the ranks."""
+    state, _ = jax.lax.scan(lambda s, _: (lay.step(s, d), None),
+                            lay.start(None), None, length=n_iters)
+    return _real(lay.vector(state), lay.n)
 
 
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_iters",
-                                   "d", "watchdog", "trace"))
-def _run_tol_dense_sharded(H, dang, tol, x0, scales=None, *, mesh, axes,
-                           n_true, max_iters, d, watchdog: bool = True,
-                           trace: bool = False):
-    pr, iters, res, grow, ring = dist.pagerank_distributed_tol(
-        H, mesh, tol=tol, max_iters=max_iters, d=d, row_axis=axes[0],
-        col_axis=axes[1], dangling=dang, n_true=n_true, x0=x0,
-        watchdog=watchdog, trace=trace, scales=scales)
-    return pr[:n_true], iters, res, grow, ring
+@partial(jax.jit, static_argnames=("max_iters", "watchdog", "trace"))
+def tol_loop(lay: Layout, d, tol, x0, *, max_iters: int,
+             watchdog: bool = True, trace: bool = False):
+    """Global iterations from ``x0`` (uniform for ``None``) until the L1
+    step over the real nodes is at most ``tol``.  Returns ``(pr, iters,
+    residual, grow, ring)`` — ``grow`` is the convergence watchdog's
+    consecutive-growth counter at exit (0 with ``watchdog=False``) and
+    ``ring`` the on-device residual-trajectory ring (``None`` with
+    ``trace=False``)."""
+    def step(state):
+        new = lay.step(state, d)
+        with jax.named_scope("pagerank.vector"):
+            return new, jnp.sum(jnp.abs(_real(
+                lay.vector(new) - lay.vector(state), lay.n)))
+
+    state, iters, res, grow, ring = instrumented_tol_loop(
+        step, lay.start(x0), tol=tol, max_iters=max_iters,
+        watchdog=watchdog, trace=trace)
+    return _real(lay.vector(state), lay.n), iters, res, grow, ring
 
 
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_ppr_dense_sharded(H, dang, V, scales=None, *, mesh, axes, n_true,
-                           n_iters, d):
-    # H is stored dangling-UNFIXED for this tier, so the PPR schedule can
-    # teleport the leak to V directly — no column reconstruction needed.
-    # V (n, Q) is zero-padded to the padded N and to a multiple of the
-    # query shards; pad columns stay zero and are sliced off
-    q = V.shape[1]
-    q_shards = mesh.shape[axes[1]]
-    Vp = jnp.pad(V, ((0, H.shape[0] - n_true),
-                     (0, -(-q // q_shards) * q_shards - q)))
-    PR = dist.ppr_distributed_dense(H, dang, Vp, mesh, n_iters=n_iters, d=d,
-                                    row_axis=axes[0], col_axis=axes[1],
-                                    scales=scales)
-    return PR[:n_true, :q]
+@partial(jax.jit, static_argnames=("n_iters",))
+def ppr_loop(lay: Layout, V: jax.Array, d, *, n_iters: int) -> jax.Array:
+    """``n_iters`` batched personalized iterations of the (n, Q) teleport
+    columns ``V``, starting from ``V``; the (n, Q) ranks."""
+    Vp = lay.pad(V)
+    PR, _ = jax.lax.scan(
+        lambda PR, _: (ppr_step_batched(lay.mv, PR, Vp, lay.dang, d), None),
+        Vp, None, length=n_iters)
+    return _real(PR, lay.n)
 
 
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_fixed_ell_sharded(layout, dang, *, mesh, axes, n_true, n_iters, d):
-    pr = dist.pagerank_distributed_sparse(layout, mesh, n_iters=n_iters,
-                                          d=d, dangling=dang, axes=axes,
-                                          n_true=n_true)
-    return pr[:n_true]
+def push_loop(Ab, x0: jax.Array, tol, n: int, max_pushes: int,
+              trace: bool = False):
+    """Gauss–Southwell frontier push to the fixed point ``x = Ab(x)`` of
+    an affine map, from ``x0``: an (N,) vector or an (N, Q) batch.  Every
+    sweep pushes the whole frontier mask ``|r| ≥ tol/n`` (whenever the
+    residual exceeds ``tol`` at least one entry qualifies, so the loop
+    cannot stall) and refreshes the residual ``r = Ab(x) − x`` from
+    scratch — one operator sweep per round, immune to float drift in the
+    bookkeeping.  The residual is the L1 norm over the first ``n`` rows,
+    for a batch the largest column's, so exit means every column met
+    ``tol``.  The (N,) push of the refresh runs under the
+    ``pagerank.push`` scope.
+
+    Runs on :func:`repro.obs.trace.instrumented_tol_loop` (NaN/Inf and
+    sustained-growth watchdog — a corrupted layout makes the residual
+    *grow* every sweep — plus the optional residual ring); the real
+    initial residual seeds it, so an already-converged start exits in zero
+    sweeps.  Returns ``(x, r, iters, residual, grow, ring)``."""
+    thresh = tol / n
+    scope = ((lambda: jax.named_scope("pagerank.push")) if x0.ndim == 1
+             else contextlib.nullcontext)
+
+    def l1(r):
+        r = jnp.abs(_real(r, n))
+        return jnp.sum(r) if r.ndim == 1 else jnp.max(jnp.sum(r, axis=0))
+
+    def step(state):
+        x, r = state
+        with scope():
+            x = x + r * (jnp.abs(r) >= thresh).astype(x.dtype)
+            r = Ab(x) - x
+            return (x, r), l1(r)
+
+    with scope():
+        r0 = Ab(x0) - x0
+    (x, r), iters, res, grow, ring = instrumented_tol_loop(
+        step, (x0, r0), tol=tol, max_iters=max_pushes, watchdog=True,
+        trace=trace, res0=l1(r0))
+    return x, r, iters, res, grow, ring
 
 
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "max_iters",
-                                   "d", "watchdog", "trace"))
-def _run_tol_ell_sharded(layout, dang, tol, x0, *, mesh, axes, n_true,
-                         max_iters, d, watchdog: bool = True,
-                         trace: bool = False):
-    pr, iters, res, grow, ring = dist.pagerank_distributed_sparse_tol(
-        layout, mesh, tol=tol, max_iters=max_iters, d=d, dangling=dang,
-        axes=axes, n_true=n_true, x0=x0, watchdog=watchdog, trace=trace)
-    return pr[:n_true], iters, res, grow, ring
-
-
-@partial(jax.jit, static_argnames=("mesh", "axes", "n_true", "n_iters", "d"))
-def _run_ppr_ell_sharded(layout, dang, V, *, mesh, axes, n_true, n_iters,
-                         d):
-    Vp = jnp.pad(V, ((0, dang.shape[0] - n_true), (0, 0)))
-    PR = dist.ppr_distributed_sparse(layout, dang, Vp, mesh,
-                                     n_iters=n_iters, d=d, axes=axes)
-    return PR[:n_true]
-
-
-# --------------------------------------------------------------------------- #
-# whole-loop compiled runners (Pallas pre-padded dense tier)                  #
-# --------------------------------------------------------------------------- #
-@partial(jax.jit, static_argnames=("n", "n_iters", "d", "block_n",
-                                   "block_m", "interpret"))
-def _run_fixed_pallas(Hp, dangp, scales=None, *, n: int, n_iters: int,
-                      d: float, block_n: int, block_m: int,
-                      interpret: bool):
-    Mp = Hp.shape[1]
-    xp0 = jnp.pad(jnp.full((n,), 1.0 / n, jnp.float32), (0, Mp - n))[None, :]
-    t0 = d * jnp.sum(xp0 * dangp) / n + (1.0 - d) / n
-
-    def body(carry, _):
-        xp, t = carry
-        yp, leak = pagerank_step_fused(Hp, xp, dangp, t, scales, d=d,
-                                       block_n=block_n, block_m=block_m,
-                                       interpret=interpret)
-        return (yp, d * leak / n + (1.0 - d) / n), None
-
-    (yp, _), _ = jax.lax.scan(body, (xp0, t0), None, length=n_iters)
-    return yp[0, :n]
-
-
-@partial(jax.jit, static_argnames=("n", "max_iters", "d", "block_n",
-                                   "block_m", "interpret", "watchdog",
-                                   "trace"))
-def _run_tol_pallas(Hp, dangp, tol, x0, scales=None, *, n: int,
-                    max_iters: int, d: float, block_n: int, block_m: int,
-                    interpret: bool, watchdog: bool = True,
-                    trace: bool = False):
-    Mp = Hp.shape[1]
-    x0 = jnp.full((n,), 1.0 / n, jnp.float32) if x0 is None else x0
-    xp0 = jnp.pad(x0, (0, Mp - n))[None, :]
-    t0 = d * jnp.sum(xp0 * dangp) / n + (1.0 - d) / n
-
-    def step(carry):
-        xp, t = carry
-        yp, leak = pagerank_step_fused(Hp, xp, dangp, t, scales, d=d,
-                                       block_n=block_n, block_m=block_m,
-                                       interpret=interpret)
-        res = jnp.sum(jnp.abs(yp[0, :n] - xp[0, :n]))
-        return (yp, d * leak / n + (1.0 - d) / n), res
-
-    (xp, _), iters, res, grow, ring = instrumented_tol_loop(
-        step, (xp0, t0), tol=tol, max_iters=max_iters, watchdog=watchdog,
-        trace=trace)
-    return xp[0, :n], iters, res, grow, ring
-
-
-@partial(jax.jit, static_argnames=("n", "n_iters", "d", "block_n",
-                                   "block_m", "interpret"))
-def _run_ppr_pallas(Hp, dangp, V, scales=None, *, n: int, n_iters: int,
-                    d: float, block_n: int, block_m: int, interpret: bool):
-    # V (n, Q) rides transposed and zero-padded, (Q, Mp): queries ride the
-    # batch axis of streaming_matvec, so all Q teleport distributions share
-    # one sweep over Hp per iteration.  The kernel upcasts reduced-precision
-    # Hp tiles in-register; an int8 layout's (1, Np) row scales fold into
-    # the f32 output here (Y's column axis is Hp's row axis).
-    Vp = jnp.pad(V.T, ((0, 0), (0, Hp.shape[1] - n)))
-
-    def body(PR, _):
-        leak = jnp.sum(PR * dangp, axis=1)                # (Q,)
-        Y = streaming_matvec(Hp, PR, block_n=block_n, block_m=block_m,
-                             interpret=interpret)
-        if scales is not None:
-            Y = Y * scales
-        return d * (Y + Vp * leak[:, None]) + (1.0 - d) * Vp, None
-
-    PR, _ = jax.lax.scan(body, Vp, None, length=n_iters)
-    return PR[:, :n].T                                    # (n, Q)
+@partial(jax.jit, static_argnames=("max_pushes", "trace"))
+def global_push(lay: Layout, d, tol, x0, *, max_pushes: int,
+                trace: bool = False):
+    """:func:`push_loop` of the global PageRank map from the (n,) ranks
+    ``x0``; returns ``(x, sweeps, residual, grow, ring)``."""
+    x, _, iters, res, grow, ring = push_loop(
+        lambda x: lay.affine(x, d), lay.pad(x0), tol, lay.n, max_pushes,
+        trace)
+    return _real(x, lay.n), iters, res, grow, ring
 
 
 # --------------------------------------------------------------------------- #
@@ -407,10 +476,10 @@ class PageRankEngine:
     * ``"auto"``         — :func:`select_backend` by density + device
       topology (multi-device processes pick the sharded tiers).
 
-    The sharded tiers zero-pad N (and the PPR query axis) up to the mesh
-    divisibility requirement at construction; pad entries never feed back
-    into real ranks and results are sliced back to N.  Duplicate directed
-    edges are collapsed up front so every tier sees the same graph.
+    The sharded and Pallas tiers zero-pad N at construction; pad entries
+    never feed back into real ranks and results are sliced back to N.
+    Duplicate directed edges are collapsed up front so every tier sees the
+    same graph.  ``prepared`` is the :class:`Layout`.
     """
 
     # row headroom of each SELL tier (``ell``, ``ell_sharded``); the
@@ -464,56 +533,54 @@ class PageRankEngine:
             self._prepare_layout(src, dst)
 
     def _prepare_layout(self, src: np.ndarray, dst: np.ndarray) -> None:
-        """Build (or rebuild) the backend's prepared device layout from a
+        """Build (or rebuild) the backend's :class:`Layout` from a
         deduplicated COO edge list.  Split out of ``__init__`` so the
         dynamic-graph subsystem (:mod:`repro.pagerank.dynamic`) can fall
         back to a full layout rebuild when an edge delta is too large — or
         structurally too disruptive — to patch in place."""
-        n = self.n
-        block_n, block_m = self._block_arg
-        bsr_block_size, mesh = self._bsr_block_size, self._mesh_arg
-        self._dang = jnp.asarray(tr.dangling_mask(src, n).astype(np.float32))
-        self._block = self._block_arg
-        self.mesh = None
-        self._axes: tuple[str, ...] = ()
-        self._n_pad = self.n
-        # int8 per-row dequantization scales of the pallas/dense_sharded
-        # tiers (the XLA tiers append theirs to the operand tuple, SELL
-        # keeps one per tier); always None for float precisions
-        self._scales = None
-        # the layout tag the generic jitted runners dispatch _matvec on —
-        # the backend itself, but "sell" for the ``ell`` tier
-        self._mv_backend = self.backend
+        n, backend, precision = self.n, self.backend, self.precision
+        dang = tr.dangling_mask(src, n).astype(np.float32)
         self._sell = None
-        self.layout = self.backend
-        if self.backend == "dense":
-            if self.precision == "f32":
-                self._operands = (tr.build_transition_dense(src, dst, n),)
-            else:
-                # reduced tiers store H dangling-UNFIXED (the fix would
-                # densify the dangling columns with 1/n values that
-                # quantize poorly) and pay the explicit scalar leak via
-                # the generic runners' sparse_step
-                H = np.asarray(tr.build_transition_dense(
-                    src, dst, n, fix_dangling=False))
-                if self.precision == "int8":
-                    scales = rowmax_scales(
-                        np.abs(H).max(axis=1, initial=0.0))
-                    self._operands = (
-                        jnp.asarray(quantize_int8(H, scales[:, None])),
-                        jnp.asarray(scales))
-                else:
-                    self._operands = (
-                        jnp.asarray(H).astype(self.storage_dtype),)
-        elif self.backend == "ell":
-            self._operands, self._sell = sell.build(
-                tr.build_transition_csr(src, dst, n), n, slack=self._slack,
-                precision=self.precision)
-            self._mv_backend = "sell"
+        self.layout = backend
+        self.mesh = None
+        if backend in SHARDED_BACKENDS:
+            self.mesh = (self._mesh_arg if self._mesh_arg is not None
+                         else _default_mesh(backend))
+            axes = tuple(self.mesh.axis_names)
+            replicated = NamedSharding(self.mesh, P())
+        if backend == "dense":
+            H = np.asarray(tr.build_transition_dense(
+                src, dst, n, fix_dangling=precision == "f32"))
+            cls = FixedDenseLayout if precision == "f32" else DenseLayout
+            lay = cls(operands=_stored(H, precision, jnp.asarray,
+                                       jnp.asarray),
+                      dang=jnp.asarray(dang), n=n)
+        elif backend in ("ell", "ell_sharded"):
+            shards = 1 if self.mesh is None else self.mesh.size
+            n_pad = -(-n // shards) * shards
+            # sliced ELL, not one block padded to the maximum degree; on
+            # the mesh each device holds a self-contained SELL of its own
+            # rows, so it sweeps them with one gather per degree tier
+            operands, self._sell = sell.build(
+                tr.build_transition_csr(src, dst, n), n_pad, shards=shards,
+                slack=self._slack, precision=precision,
+                sharding=(None if self.mesh is None
+                          else NamedSharding(self.mesh, P(axes))))
             self.layout = self._sell.describe(self._slack)
-        elif self.backend == "bsr":
-            bsr = tr.build_transition_bsr(src, dst, n, bs=bsr_block_size)
-            if self.precision == "int8":
+            if self.mesh is None:
+                lay = SellLayout(operands=operands, dang=jnp.asarray(dang),
+                                 n=n)
+            else:
+                lay = ShardedSellLayout(
+                    operands=operands, n=n, mesh=self.mesh, axes=axes,
+                    dang=jax.device_put(np.pad(dang, (0, n_pad - n)),
+                                        replicated))
+                self.layout = (f"ell_sharded({self.layout}, "
+                               f"shards={shards}, n_pad={n_pad})")
+        elif backend == "bsr":
+            bsr = tr.build_transition_bsr(src, dst, n,
+                                          bs=self._bsr_block_size)
+            if precision == "int8":
                 blocks = np.asarray(bsr.blocks)
                 nb_r, _, bs, _ = blocks.shape
                 # per-row abs-max across the block budget: axis 2 is the
@@ -525,73 +592,45 @@ class PageRankEngine:
                         blocks, scales.reshape(nb_r, 1, bs, 1))),
                     bsr.block_cols, shape=bsr.shape,
                     row_scales=jnp.asarray(scales))
-            elif self.precision != "f32":
+            elif precision != "f32":
                 bsr = BSRMatrix(bsr.blocks.astype(self.storage_dtype),
                                 bsr.block_cols, shape=bsr.shape)
-            self._operands = (bsr,)
-        elif self.backend == "dense_sharded":
-            self.mesh = mesh if mesh is not None else _default_mesh(
-                self.backend)
-            self._axes = tuple(self.mesh.axis_names)
-            if len(self._axes) != 2:
+            lay = BsrLayout(operands=(bsr,), dang=jnp.asarray(dang), n=n)
+        elif backend == "dense_sharded":
+            if len(axes) != 2:
                 raise ValueError("dense_sharded needs a 2-D mesh, got axes "
-                                 f"{self._axes}")
-            r, c = (self.mesh.shape[a] for a in self._axes)
-            self._n_pad = -(-self.n // math.lcm(r, c)) * math.lcm(r, c)
-            Hp = np.zeros((self._n_pad, self._n_pad), np.float32)
+                                 f"{axes}")
+            r, c = (self.mesh.shape[a] for a in axes)
+            n_pad = -(-n // math.lcm(r, c)) * math.lcm(r, c)
+            Hp = np.zeros((n_pad, n_pad), np.float32)
             Hp[:n, :n] = np.asarray(tr.build_transition_dense(
                 src, dst, n, fix_dangling=False))
-            blk = NamedSharding(self.mesh, P(*self._axes))
-            if self.precision == "int8":
-                scales = rowmax_scales(np.abs(Hp).max(axis=1, initial=0.0))
-                self._operands = (jax.device_put(
-                    quantize_int8(Hp, scales[:, None]), blk),)
-                # replicated: _dense_iter folds it into the P(row)-sharded
-                # accumulated row sums
-                self._scales = jax.device_put(
-                    scales, NamedSharding(self.mesh, P()))
-            elif self.precision != "f32":
-                self._operands = (jax.device_put(
-                    jnp.asarray(Hp).astype(self.storage_dtype), blk),)
-            else:
-                self._operands = (jax.device_put(Hp, blk),)
-            self._dang = self._pad_replicated(self._dang)
-            self.layout = (f"dense_sharded({r}x{c} mesh, "
-                           f"n_pad={self._n_pad})")
-        elif self.backend == "ell_sharded":
-            self.mesh = mesh if mesh is not None else _default_mesh(
-                self.backend)
-            self._axes = tuple(self.mesh.axis_names)
-            ndev = self.mesh.size
-            self._n_pad = -(-self.n // ndev) * ndev
-            # sliced ELL, not one block padded to the maximum degree: each
-            # device holds a self-contained SELL of its own rows, so it
-            # sweeps them with one gather per degree tier
-            self._operands, self._sell = sell.build(
-                tr.build_transition_csr(src, dst, n), self._n_pad,
-                shards=ndev, slack=self._slack, precision=self.precision,
-                sharding=NamedSharding(self.mesh, P(self._axes)))
-            self._dang = self._pad_replicated(self._dang)
-            self.layout = (f"ell_sharded({self._sell.describe(self._slack)}"
-                           f", shards={ndev}, n_pad={self._n_pad})")
+            blk = NamedSharding(self.mesh, P(*axes))
+            # int8 scales stay replicated: fabric_step folds them into
+            # the P(row)-sharded accumulated row sums
+            lay = DenseShardedLayout(
+                operands=_stored(Hp, precision,
+                                 lambda a: jax.device_put(a, blk),
+                                 lambda s: jax.device_put(s, replicated)),
+                dang=jax.device_put(np.pad(dang, (0, n_pad - n)),
+                                    replicated),
+                n=n, mesh=self.mesh, axes=axes)
+            self.layout = f"dense_sharded({r}x{c} mesh, n_pad={n_pad})"
         else:                                   # pallas_dense
             H = tr.build_transition_dense(src, dst, n, fix_dangling=False)
             Hp, dangp, bn, bm = pad_pagerank_operands(
-                H, self._dang, block_n=block_n, block_m=block_m)
-            if self.precision == "int8":
-                Hp_np = np.asarray(Hp)
-                scales = rowmax_scales(
-                    np.abs(Hp_np).max(axis=1, initial=0.0))
-                Hp = jnp.asarray(quantize_int8(Hp_np, scales[:, None]))
-                # (1, Np): the fused kernel applies it per row-block in
-                # the same drain epilogue as the affine term
-                self._scales = jnp.asarray(scales)[None, :]
-            elif self.precision != "f32":
-                Hp = Hp.astype(self.storage_dtype)
-            self._operands = (Hp, dangp)
-            self._block = (bn, bm)
-        if self.precision != "f32":
-            self.layout = f"{self.layout}[{self.precision}]"
+                H, jnp.asarray(dang), block_n=self._block_arg[0],
+                block_m=self._block_arg[1])
+            # int8 scales are (1, Np): the fused kernel applies them per
+            # row-block in the same drain epilogue as the affine term
+            lay = PallasLayout(
+                operands=_stored(np.asarray(Hp), precision, jnp.asarray,
+                                 lambda s: jnp.asarray(s)[None, :]),
+                dang=dangp[0], n=n, d=self.d, block=(bn, bm),
+                interpret=self.interpret)
+        if precision != "f32":
+            self.layout = f"{self.layout}[{precision}]"
+        self.prepared = lay
         self._record_layout_bytes()
 
     def _record_layout_bytes(self) -> None:
@@ -600,79 +639,31 @@ class PageRankEngine:
         ``layout.bytes`` gauge and kept as ``self.layout_bytes``; its stored
         value slots, padding included, as the ``layout.slots`` gauge (over
         ``n_edges``, the padding a sweep gathers)."""
-        extras = () if self._scales is None else (self._scales,)
-        self.layout_bytes = layout_nbytes(tuple(self._operands) + extras)
+        self.layout_bytes = layout_nbytes(tuple(self.operands))
         self.metrics.gauge("layout.bytes").set(
             self.layout_bytes["total_bytes"])
         # a SELL's first leaf is its int32 row order; every other layout's
         # is its value array (H, the BSR blocks, the padded Pallas H)
         self.metrics.gauge("layout.slots").set(
             self._sell.slots if self._sell is not None
-            else int(jax.tree.leaves(self._operands)[0].size))
-
-    def _pad_replicated(self, dang: jax.Array) -> jax.Array:
-        padded = np.zeros((self._n_pad,), np.float32)
-        padded[:self.n] = np.asarray(dang)
-        return jax.device_put(padded, NamedSharding(self.mesh, P()))
+            else int(jax.tree.leaves(self.operands)[0].size))
 
     @property
     def operands(self) -> tuple:
         """The prepared (already padded/sharded) layout arrays — read-only
         access for inspection (shard shapes, memory accounting)."""
-        return self._operands
+        return self.prepared.operands
 
     def lower_run(self, n_iters: int = 100):
         """AOT-lower the fixed-schedule ``run`` without executing it, for
         collective audits / HLO dumps of the sharded tiers (e.g. counting
         all-reduces in ``.compile().as_text()``)."""
-        if self.backend == "dense_sharded":
-            return _run_fixed_dense_sharded.lower(
-                self._operands[0], self._dang, self._scales,
-                mesh=self.mesh, axes=self._axes, n_true=self.n,
-                n_iters=n_iters, d=self.d)
-        if self.backend == "ell_sharded":
-            return _run_fixed_ell_sharded.lower(
-                self._operands, self._dang, mesh=self.mesh, axes=self._axes,
-                n_true=self.n, n_iters=n_iters, d=self.d)
-        if self.backend == "dense" and self.precision == "f32":
-            return pagerank_dense_fixed.lower(
-                self._operands[0], n_iters=n_iters, d=self.d)
-        if self.backend == "pallas_dense":
-            return _run_fixed_pallas.lower(
-                *self._operands, self._scales, n=self.n, n_iters=n_iters,
-                d=self.d, block_n=self._block[0], block_m=self._block[1],
-                interpret=self.interpret)
-        return _run_fixed.lower(self._operands, self._dang, self.d,
-                                backend=self._mv_backend, n=self.n,
-                                n_iters=n_iters)
+        return fixed_loop.lower(self.prepared, self.d, n_iters=n_iters)
 
     # ------------------------------ queries ------------------------------ #
     def run(self, n_iters: int = 100) -> jax.Array:
         """Fixed-schedule power iteration; one compiled dispatch."""
-        if self.backend == "dense_sharded":
-            return _run_fixed_dense_sharded(
-                self._operands[0], self._dang, self._scales,
-                mesh=self.mesh, axes=self._axes, n_true=self.n,
-                n_iters=n_iters, d=self.d)
-        if self.backend == "ell_sharded":
-            return _run_fixed_ell_sharded(
-                self._operands, self._dang, mesh=self.mesh, axes=self._axes,
-                n_true=self.n, n_iters=n_iters, d=self.d)
-        if self.backend == "pallas_dense":
-            Hp, dangp = self._operands
-            return _run_fixed_pallas(
-                Hp, dangp, self._scales, n=self.n, n_iters=n_iters,
-                d=self.d, block_n=self._block[0], block_m=self._block[1],
-                interpret=self.interpret)
-        if self.backend == "dense" and self.precision == "f32":
-            # the reference program itself -> bit-identical to it; the
-            # reduced-precision dense tiers store H unfixed and take the
-            # generic explicit-leak runner below instead
-            return pagerank_dense_fixed(self._operands[0], n_iters=n_iters,
-                                        d=self.d)
-        return _run_fixed(self._operands, self._dang, self.d,
-                          backend=self._mv_backend, n=self.n,
-                          n_iters=n_iters)
+        return fixed_loop(self.prepared, self.d, n_iters=n_iters)
 
     def run_tol(self, tol: float = 1e-6, max_iters: int = 1000,
                 x0: np.ndarray | jax.Array | None = None, *,
@@ -711,35 +702,9 @@ class PageRankEngine:
         x0 = solve_dtype(x0, name="x0")
         tol_f32 = solve_dtype(tol, name="tol")
         with self.metrics.span("solve", backend=self.backend):
-            if self.backend == "dense_sharded":
-                out = _run_tol_dense_sharded(
-                    self._operands[0], self._dang, tol_f32,
-                    self._pad_x0(x0), self._scales, mesh=self.mesh,
-                    axes=self._axes, n_true=self.n, max_iters=max_iters,
-                    d=self.d, watchdog=watchdog, trace=trace)
-            elif self.backend == "ell_sharded":
-                out = _run_tol_ell_sharded(
-                    self._operands, self._dang, tol_f32, self._pad_x0(x0),
-                    mesh=self.mesh, axes=self._axes, n_true=self.n,
-                    max_iters=max_iters, d=self.d, watchdog=watchdog,
-                    trace=trace)
-            elif self.backend == "pallas_dense":
-                Hp, dangp = self._operands
-                out = _run_tol_pallas(
-                    Hp, dangp, tol_f32, x0, self._scales, n=self.n,
-                    max_iters=max_iters, d=self.d, block_n=self._block[0],
-                    block_m=self._block[1], interpret=self.interpret,
-                    watchdog=watchdog, trace=trace)
-            elif self.backend == "dense" and self.precision == "f32":
-                out = pagerank_dense(self._operands[0], d=self.d,
-                                     tol=tol_f32, max_iters=max_iters,
-                                     x0=x0, watchdog=watchdog, trace=trace)
-            else:
-                out = _run_tol(self._operands, self._dang, self.d,
-                               tol_f32, x0,
-                               backend=self._mv_backend, n=self.n,
-                               max_iters=max_iters, watchdog=watchdog,
-                               trace=trace)
+            out = tol_loop(self.prepared, self.d, tol_f32, x0,
+                           max_iters=max_iters, watchdog=watchdog,
+                           trace=trace)
             return self._finish_solve(out, tol, max_iters, raise_on_fail)
 
     def _finish_solve(self, out, tol: float, max_iters: int,
@@ -778,13 +743,6 @@ class PageRankEngine:
                     RuntimeWarning, stacklevel=3)
         return SolveResult(pr, iters, res, info)
 
-    def _pad_x0(self, x0: jax.Array | None) -> jax.Array | None:
-        """Zero-pad a warm-start vector up to the sharded tiers' padded N
-        (pad entries never feed back into real ranks)."""
-        if x0 is None or self._n_pad == self.n:
-            return x0
-        return jnp.pad(x0, (0, self._n_pad - self.n))
-
     def ppr(self, seed_sets: Sequence[np.ndarray],
             n_iters: int = 100) -> jax.Array:
         """Batched personalized PageRank: one (N, Q) propagation for Q
@@ -797,11 +755,8 @@ class PageRankEngine:
         ``V``, one distribution per column; returns the (N, Q) rank matrix.
         A zero column of ``V`` comes back an exact zero column, so a caller
         pads its query axis with zero columns to run one program per width.
-
-        On ``dense_sharded`` the query axis is sharded across the mesh
-        (padded up to the shard count with zero columns, sliced back); on
-        ``ell_sharded`` every device sweeps its own rows for all queries,
-        so a multi-user serve flush spreads over devices either way.
+        On the sharded tiers every device sweeps its share of H for all
+        queries, so a multi-user serve flush spreads over devices.
 
         Counts ``ppr.sweeps`` (``n_iters``) and ``ppr.column_sweeps``
         (``n_iters`` per non-zero column).  The result is not waited for,
@@ -814,33 +769,13 @@ class PageRankEngine:
             m.counter("engine.ppr_queries").inc(queries)
             m.counter("ppr.sweeps").inc(n_iters)
             m.counter("ppr.column_sweeps").inc(n_iters * queries)
-            run, args, kw = self._ppr_program(jnp.asarray(V), n_iters)
-            return run(*args, **kw)
+            return ppr_loop(self.prepared, jnp.asarray(V), self.d,
+                            n_iters=n_iters)
 
     def lower_ppr(self, q: int, n_iters: int = 100):
         """AOT-lower the batched personalized PageRank of ``q`` columns
         without running it; its ``.compile()`` leaves a later
         :meth:`ppr_columns` of that width nothing to compile."""
-        run, args, kw = self._ppr_program(
-            jax.ShapeDtypeStruct((self.n, q), jnp.float32), n_iters)
-        return run.lower(*args, **kw)
-
-    def _ppr_program(self, V, n_iters: int) -> tuple:
-        """The jitted runner of a batched PPR of the (N, Q) ``V`` on this
-        layout, with its arguments and keywords."""
-        if self.backend == "dense_sharded":
-            return _run_ppr_dense_sharded, (
-                self._operands[0], self._dang, V, self._scales), dict(
-                mesh=self.mesh, axes=self._axes, n_true=self.n,
-                n_iters=n_iters, d=self.d)
-        if self.backend == "ell_sharded":
-            return _run_ppr_ell_sharded, (self._operands, self._dang, V), \
-                dict(mesh=self.mesh, axes=self._axes, n_true=self.n,
-                     n_iters=n_iters, d=self.d)
-        if self.backend == "pallas_dense":
-            return _run_ppr_pallas, (*self._operands, V, self._scales), dict(
-                n=self.n, n_iters=n_iters, d=self.d,
-                block_n=self._block[0], block_m=self._block[1],
-                interpret=self.interpret)
-        return _run_ppr, (self._operands, self._dang, V, self.d), dict(
-            backend=self._mv_backend, n=self.n, n_iters=n_iters)
+        return ppr_loop.lower(
+            self.prepared, jax.ShapeDtypeStruct((self.n, q), jnp.float32),
+            self.d, n_iters=n_iters)

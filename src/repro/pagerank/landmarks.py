@@ -49,59 +49,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import F32_DOT, upcast_f32
-from repro.kernels.streaming_matvec import streaming_matvec
+from repro.kernels.common import F32_DOT
 from repro.obs.registry import default_registry
-from repro.obs.trace import instrumented_tol_loop
-from repro.pagerank.distributed import sell_mv_sharded
-from repro.pagerank.engine import SHARDED_BACKENDS, _matvec, _row_scale
+from repro.pagerank.engine import push_loop
 from repro.pagerank.steps import ppr_step_batched
 
 __all__ = ["LandmarkIndex"]
-
-# the static arguments that pick a layout's batched matvec
-_LAYOUT = ("backend", "mesh", "axes", "block", "interpret")
-
-
-def _batched_mv(operands, dang, scales, *, backend, mesh, axes, block,
-                interpret):
-    """``X -> H·X`` for a (rows, Q) batch on an engine's prepared layout,
-    ``H`` the transition matrix without the dangling fix (the PPR leak
-    teleports to the seeds, not 1/n); ``rows`` is the layout's row count,
-    the padded N on the sharded tiers."""
-    if backend == "dense":
-        # the f32 dense operand is dangling-FIXED; masking the dangling
-        # columns reconstructs the unfixed H (a no-op on the reduced
-        # tiers, which store H unfixed) — same trick as engine._run_ppr
-        op_scales = operands[1] if len(operands) == 2 else None
-        H = upcast_f32(operands[0]) * (1.0 - dang)[None, :]
-        return lambda X: _row_scale(H @ X, op_scales)
-    if backend == "dense_sharded":
-        # stored dangling-unfixed; GSPMD propagates the P(row, col) layout
-        return lambda X: _row_scale(upcast_f32(operands[0]) @ X, scales)
-    if backend == "ell_sharded":
-        # every device sweeps its own SELL rows for all query columns
-        return lambda X: sell_mv_sharded(operands, X, mesh, axes)
-    if backend == "pallas_dense":
-        # the pre-padded unfixed Hp streams the transposed, zero-padded
-        # (Q, Mp) batch, as engine._run_ppr_pallas does
-        Hp = operands[0]
-
-        def mv(X):
-            rows = X.shape[0]
-            Y = streaming_matvec(Hp, jnp.pad(X.T, ((0, 0),
-                                                   (0, Hp.shape[1] - rows))),
-                                 block_n=block[0], block_m=block[1],
-                                 interpret=interpret)
-            if scales is not None:
-                Y = Y * scales
-            return Y[:, :rows].T
-        return mv
-    return lambda X: _matvec(backend, operands, X)
-
-
-def _pad_rows(X, rows: int):
-    return jnp.pad(X, ((0, rows - X.shape[0]), (0, 0)))
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -136,22 +89,19 @@ def _resolvent_columns(X, dang, *, d: float):
     return X / c[None, :]
 
 
-@partial(jax.jit, static_argnames=("d",) + _LAYOUT)
-def _hub_estimate(operands, dang, scales, V, Y, hubs, *, d: float, backend,
-                  mesh=None, axes=(), block=(0, 0), interpret=False):
+@partial(jax.jit, static_argnames=("d",))
+def _hub_estimate(lay, d: float, V, Y, hubs):
     """Hub-combination warm starts of the (n, Q) query columns ``V`` from
-    the (n, H) resolvent columns ``Y`` of the ``hubs``: each column a
-    distribution (clipped at zero, renormalized)."""
+    the (n, H) resolvent columns ``Y`` of the ``hubs``, on the engine's
+    prepared layout ``lay``: each column a distribution (clipped at zero,
+    renormalized)."""
     with jax.named_scope("pagerank.ppr_estimate"):
         n = V.shape[0]
-        mv = _batched_mv(operands, dang, scales, backend=backend, mesh=mesh,
-                         axes=axes, block=block, interpret=interpret)
         hub = jnp.zeros((n, 1), bool).at[hubs].set(True)
         # a non-hub seed expands one step, R·e_s = e_s + d·R·H·e_s; a
         # dangling one has no step (R·e_s = e_s exactly)
         tail = jnp.where(hub, 0.0, V)
-        Z = d * mv(_pad_rows(tail * (1.0 - dang[:n, None]),
-                             dang.shape[0]))[:n]
+        Z = d * lay.mv(lay.pad(tail * (1.0 - lay.dang[:n, None])))[:n]
         # hub out-neighbors (and hub seeds) take their stored columns; tail
         # out-neighbors truncate to R·e_t ≈ e_t
         coef = V[hubs] + Z[hubs]                                 # (H, Q)
@@ -160,51 +110,22 @@ def _hub_estimate(operands, dang, scales, V, Y, hubs, *, d: float, backend,
         return jnp.maximum(y, 0.0) / jnp.maximum(jnp.sum(y, axis=0), 1e-30)
 
 
-# --------------------------------------------------------------------------- #
-# batched Gauss–Southwell residual push on the personalized operator          #
-#                                                                             #
-# Same masked-sweep shape as repro.pagerank.dynamic._push_loop, lifted to     #
-# the batched (N, Q) personalized affine operator                             #
-# Ab(X) = d·(H·X + V·leak) + (1−d)·V, on the same instrumented while_loop     #
-# driver.  The loop residual is the MAX per-column L1 residual, so exit       #
-# means every query met the bound; per-column residuals come back so the      #
-# caller can fall back per query when the loop exhausted max_pushes.          #
-# --------------------------------------------------------------------------- #
-def _batched_push(Ab, X0, tol, n, max_pushes):
-    thresh = tol / n
-
-    def step(state):
-        X, R = state
-        X = X + R * (jnp.abs(R) >= thresh).astype(X.dtype)
-        R = Ab(X) - X
-        return (X, R), jnp.max(jnp.sum(jnp.abs(R), axis=0))
-
-    R0 = Ab(X0) - X0
-    (X, R), iters, res, grow, _ = instrumented_tol_loop(
-        step, (X0, R0), tol=tol, max_iters=max_pushes, watchdog=True,
-        trace=False, res0=jnp.max(jnp.sum(jnp.abs(R0), axis=0)))
-    return X, jnp.sum(jnp.abs(R), axis=0), iters, res, grow
-
-
-@partial(jax.jit, static_argnames=("max_pushes", "d") + _LAYOUT)
-def _hub_push(operands, dang, scales, V, X0, tol, *, max_pushes: int,
-              d: float, backend, mesh=None, axes=(), block=(0, 0),
-              interpret=False):
+@partial(jax.jit, static_argnames=("d", "max_pushes"))
+def _hub_push(lay, d: float, max_pushes: int, V, X0, tol):
     """Pushes the (n, Q) warm starts ``X0`` of the teleport columns ``V``
-    below ``tol``; returns the (n, Q) matrix, the per-column residuals and
-    the sweeps run.  Pad rows (the sharded tiers' N padding) and zero
-    columns keep a zero residual, so they never move the exit test."""
+    below ``tol`` on the batched personalized operator
+    ``Ab(X) = d·(H·X + V·leak) + (1−d)·V`` of the engine's prepared layout
+    ``lay``; returns the (n, Q) matrix, the per-column residuals (so the
+    caller can fall back per query when the push exhausted
+    ``max_pushes``) and the sweeps run.  Pad rows (the sharded tiers' N
+    padding) and zero columns keep a zero residual, so they never move
+    the exit test."""
     n = V.shape[0]
-    mv = _batched_mv(operands, dang, scales, backend=backend, mesh=mesh,
-                     axes=axes, block=block, interpret=interpret)
-    Vp = _pad_rows(V, dang.shape[0])
-
-    def Ab(X):
-        return ppr_step_batched(mv, X, Vp, dang, d)
-
-    X, res_col, iters, _, _ = _batched_push(
-        Ab, _pad_rows(X0, dang.shape[0]), tol, n, max_pushes)
-    return X[:n], res_col, iters
+    Vp = lay.pad(V)
+    X, R, iters, *_ = push_loop(
+        lambda X: ppr_step_batched(lay.mv, X, Vp, lay.dang, d),
+        lay.pad(X0), tol, n, max_pushes)
+    return X[:n], jnp.sum(jnp.abs(R), axis=0), iters
 
 
 @partial(jax.jit, static_argnames=("q",))
@@ -278,7 +199,7 @@ class LandmarkIndex:
             deg = e._outdeg + e._indeg
             hubs = np.sort(np.argpartition(deg, -k)[-k:].astype(np.int64))
             X = e.ppr([[int(h)] for h in hubs], n_iters=self.n_iters)
-            self._Y = _resolvent_columns(X, e._dang,
+            self._Y = _resolvent_columns(X, e.prepared.dang,
                                          d=e.d).block_until_ready()
             self._hub_ids = jnp.asarray(hubs, jnp.int32)
             self.hubs = hubs
@@ -305,8 +226,7 @@ class LandmarkIndex:
 
     def _estimate(self, V: jax.Array) -> jax.Array:
         e = self.engine
-        return _hub_estimate(e._operands, e._dang, e._scales, V, self._Y,
-                             self._hub_ids, d=e.d, **self._layout())
+        return _hub_estimate(e.prepared, e.d, V, self._Y, self._hub_ids)
 
     # ----------------------------- answer ------------------------------ #
     def answer(self, seed_sets, tol: float | None = None,
@@ -335,9 +255,8 @@ class LandmarkIndex:
                 V = self._teleport(seed_sets, q_pad)
                 X0 = self._estimate(V)
             with m.span("landmarks.push", q=q):
-                X, res_col, sweeps = _hub_push(
-                    e._operands, e._dang, e._scales, V, X0, tol,
-                    max_pushes=max_pushes, d=e.d, **self._layout())
+                X, res_col, sweeps = _hub_push(e.prepared, e.d, max_pushes,
+                                               V, X0, tol)
                 res = np.asarray(res_col)[:q]
                 sweeps = int(sweeps)
             m.counter("ppr.sweeps").inc(sweeps)
@@ -356,15 +275,6 @@ class LandmarkIndex:
             "fallbacks": int(bad.sum()),
             "paths": ["exact" if b else "hub" for b in bad[:q]]}
         return X, self.last_info
-
-    def _layout(self) -> dict:
-        """The static arguments that pick the engine's batched matvec."""
-        e = self.engine
-        sharded_or_pallas = SHARDED_BACKENDS + ("pallas_dense",)
-        return dict(backend=(e.backend if e.backend in sharded_or_pallas
-                             else e._mv_backend),
-                    mesh=e.mesh, axes=e._axes, block=tuple(e._block),
-                    interpret=e.interpret)
 
 
 def _padded(q: int) -> int:

@@ -494,7 +494,7 @@ class FaultInjector:
         way to exercise the residual-growth (``diverged``) watchdog rather
         than the NaN/Inf check; device sharding is preserved on the
         write-back."""
-        leaves, treedef = jax.tree.flatten(engine._operands)
+        leaves, treedef = jax.tree.flatten(engine.operands)
         floats = [i for i, a in enumerate(leaves)
                   if jnp.issubdtype(a.dtype, jnp.floating)]
         if not floats:
@@ -514,7 +514,8 @@ class FaultInjector:
                 # how fast the corrupt entries feed back
                 flat[idx] = {"nan": np.nan, "inf": np.inf, "huge": 1e4}[kind]
             leaves[target] = jax.device_put(arr, leaves[target].sharding)
-        engine._operands = jax.tree.unflatten(treedef, leaves)
+        engine.prepared = dataclasses.replace(
+            engine.prepared, operands=jax.tree.unflatten(treedef, leaves))
         self.log.append(f"layout:{kind}(k={len(idx)},operand={floats[0]})")
 
     # --------------------------- update failures ----------------------- #

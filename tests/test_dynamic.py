@@ -241,13 +241,13 @@ def test_insert_then_delete_is_noop(net, backend):
     dyn = DynamicPageRankEngine(src, dst, n, backend=backend)
     pr0 = dyn.run_tol(1e-7, max_iters=500)[0]
     before = [np.asarray(o) for o in jax.tree_util.tree_leaves(dyn.operands)]
-    dang_before = np.asarray(dyn._dang)
+    dang_before = np.asarray(dyn.prepared.dang)
     edges = _absent_pairs(src, dst, n, 3, seed=2)
     dyn.update(GraphDelta.inserts(*edges))
     pr2, _ = dyn.update(GraphDelta.deletes(*edges))
     for a, b in zip(before, jax.tree_util.tree_leaves(dyn.operands)):
         np.testing.assert_array_equal(a, np.asarray(b))
-    np.testing.assert_array_equal(dang_before, np.asarray(dyn._dang))
+    np.testing.assert_array_equal(dang_before, np.asarray(dyn.prepared.dang))
     assert _l1(pr0, pr2) <= 1e-5
 
 
@@ -391,13 +391,13 @@ def test_sharded_insert_then_delete_is_noop(net, backend, multi_device):
     dyn = DynamicPageRankEngine(src, dst, n, backend=backend)
     pr0 = dyn.run_tol(1e-7, max_iters=500)[0]
     before = [np.asarray(o) for o in jax.tree_util.tree_leaves(dyn.operands)]
-    dang_before = np.asarray(dyn._dang)
+    dang_before = np.asarray(dyn.prepared.dang)
     edges = _absent_pairs(src, dst, n, 3, seed=2)
     dyn.update(GraphDelta.inserts(*edges))
     pr2, _ = dyn.update(GraphDelta.deletes(*edges))
     for a, b in zip(before, jax.tree_util.tree_leaves(dyn.operands)):
         np.testing.assert_array_equal(a, np.asarray(b))
-    np.testing.assert_array_equal(dang_before, np.asarray(dyn._dang))
+    np.testing.assert_array_equal(dang_before, np.asarray(dyn.prepared.dang))
     assert _l1(pr0, pr2) <= 1e-5
 
 

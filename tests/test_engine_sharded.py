@@ -2,7 +2,7 @@
 
 Every sharded backend must agree with the single-device dense reference to
 <= 1e-5 on fixed seeds — including dangling nodes, tolerance-based early
-exit across the mesh, uneven N/Q padding, and the query-sharded batched
+exit across the mesh, uneven N padding, and the sharded batched
 PPR path that backs ``serve.PageRankQueryEngine``.
 """
 import jax
@@ -63,7 +63,7 @@ def test_sharded_uneven_n_pads_and_slices(multi_device, backend):
     n = 203
     src, dst = gen.protein_network(n, seed=5)
     eng = PageRankEngine(src, dst, n, backend=backend)
-    assert eng._n_pad > n                      # padding actually exercised
+    assert eng.prepared.rows > n               # padding actually exercised
     pr = eng.run(n_iters=80)
     ref = pagerank_dense_fixed(tr.build_transition_dense(src, dst, n),
                                n_iters=80)
@@ -103,7 +103,7 @@ def test_ell_sharded_on_2d_mesh_flattens_axes(net):
     n, src, dst, H = net
     mesh = make_mesh((2, 4), ("data", "model"))
     eng = PageRankEngine(src, dst, n, backend="ell_sharded", mesh=mesh)
-    assert eng._axes == ("data", "model")
+    assert eng.prepared.axes == ("data", "model")
     pr = eng.run(n_iters=100)
     ref = pagerank_dense_fixed(H, n_iters=100)
     assert float(jnp.max(jnp.abs(pr - ref))) <= TOL
@@ -157,7 +157,7 @@ def test_distributed_dangling_regression_2d_mesh(net):
 @pytest.mark.parametrize("backend", SHARDED_BACKENDS)
 def test_serve_query_engine_on_sharded_backend(net, backend):
     """serve.PageRankQueryEngine flushes multi-user batches onto the mesh
-    unchanged — the flush is one query-sharded device dispatch."""
+    unchanged — the flush is one sharded device dispatch."""
     from repro.serve import PageRankQueryEngine
     n, src, dst, _ = net
     eng = PageRankEngine(src, dst, n, backend=backend)

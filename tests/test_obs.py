@@ -486,29 +486,52 @@ def test_watch_compiles_counts_real_compiles_and_names_the_span(tmp_path):
     assert null.as_dict()["counters"] == {} and null.events == []
 
 
-def test_named_scopes_change_only_metadata(monkeypatch):
+# the ``ell`` tier's programs with the named-scope set each has carried
+# since the scopes were introduced
+_ELL_PROGRAMS = {
+    "tol": {"pagerank.ell_gather", "pagerank.sell_order", "pagerank.vector"},
+    "fixed": {"pagerank.ell_gather", "pagerank.sell_order",
+              "pagerank.vector"},
+    "ppr": {"pagerank.ell_gather", "pagerank.sell_order", "pagerank.vector"},
+    "push": {"pagerank.ell_gather", "pagerank.sell_order", "pagerank.vector",
+             "pagerank.push"},
+}
+
+
+@pytest.mark.parametrize("program", sorted(_ELL_PROGRAMS))
+def test_named_scopes_change_only_metadata(monkeypatch, program):
     """The ``pagerank.*`` scopes are op metadata: the compiled program of
-    a tolerance solve is the unscoped one, instruction for instruction."""
+    each of the ``ell`` tier's loop drivers (tolerance solve, fixed run,
+    batched PPR, refresh push) is the unscoped one, instruction for
+    instruction."""
     import contextlib
     import re
 
     import jax
     import jax.numpy as jnp
 
-    from repro.pagerank.engine import _run_tol
+    from repro.pagerank.engine import (fixed_loop, global_push, ppr_loop,
+                                       tol_loop)
 
     n = 96
     src, dst = gen.barabasi_albert(n, 3, seed=0)   # hub rows: several tiers
     eng = PageRankEngine(src, dst, n, backend="ell",
                          metrics=NullRegistry())
     assert len(eng._sell.widths) > 2
+    lay, d, x0 = eng.prepared, eng.d, jnp.full((n,), 1.0 / n, jnp.float32)
+    lower = {
+        "tol": lambda: tol_loop.lower(lay, d, jnp.float32(1e-6), None,
+                                      max_iters=100),
+        "fixed": lambda: fixed_loop.lower(lay, d, n_iters=100),
+        "ppr": lambda: ppr_loop.lower(
+            lay, jax.ShapeDtypeStruct((n, 16), jnp.float32), d, n_iters=100),
+        "push": lambda: global_push.lower(lay, d, jnp.float32(1e-6), x0,
+                                          max_pushes=100),
+    }[program]
 
     def compiled() -> str:
         jax.clear_caches()
-        return _run_tol.lower(eng._operands, eng._dang, eng.d,
-                              jnp.float32(1e-6), None,
-                              backend=eng._mv_backend, n=n,
-                              max_iters=100).compile().as_text()
+        return lower().compile().as_text()
 
     def instructions(text: str) -> list[str]:
         text = re.sub(r",? metadata=\{[^}]*\}", "", text)
@@ -523,8 +546,7 @@ def test_named_scopes_change_only_metadata(monkeypatch):
     plain = compiled()
     monkeypatch.undo()
     jax.clear_caches()
-    assert set(re.findall(r"pagerank\.\w+", scoped)) == {
-        "pagerank.ell_gather", "pagerank.sell_order", "pagerank.vector"}
+    assert set(re.findall(r"pagerank\.\w+", scoped)) == _ELL_PROGRAMS[program]
     assert "pagerank." not in plain
     assert instructions(scoped) == instructions(plain)
 

@@ -14,6 +14,7 @@ from repro.graph import transition as tr
 from repro.graph.delta import GraphDelta, apply_delta, edge_keys
 from repro.obs.registry import MetricsRegistry
 from repro.pagerank import DynamicPageRankEngine, PageRankEngine, sell
+from repro.pagerank.engine import SellLayout
 
 
 def _l1(a, b) -> float:
@@ -75,7 +76,7 @@ def test_static_ell_tier_matches_dense(hubs, op):
     src, dst, n = hubs
     ell = PageRankEngine(src, dst, n, backend="ell")
     dense = PageRankEngine(src, dst, n, backend="dense")
-    assert ell._mv_backend == "sell" and ell._sell is not None
+    assert isinstance(ell.prepared, SellLayout) and ell._sell is not None
     if op == "run":
         assert _l1(ell.run(60), dense.run(60)) <= 1e-6
     elif op == "run_tol":

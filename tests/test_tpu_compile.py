@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.bsr_spmv import bsr_spmv
 from repro.kernels.pagerank_step import pagerank_step_fused
 from repro.kernels.streaming_matvec import streaming_matvec
-from repro.pagerank.engine import _run_fixed_pallas
+from repro.pagerank.engine import PallasLayout, fixed_loop
 
 N, NP, BLOCK = 5000, 5120, 256
 
@@ -79,11 +79,9 @@ def test_bsr_spmv_compiles(shape):
 
 
 def test_run_fixed_pallas_compiles(shape):
-    _compiled_kernel(
-        lambda h, dg: _run_fixed_pallas(h, dg, None, n=N, n_iters=100,
-                                        d=0.85, block_n=BLOCK,
-                                        block_m=BLOCK, interpret=False),
-        shape((NP, NP)), shape((1, NP)))
+    lay = PallasLayout(operands=(shape((NP, NP)),), dang=shape((NP,)), n=N,
+                       d=0.85, block=(BLOCK, BLOCK), interpret=False)
+    _compiled_kernel(lambda lay: fixed_loop(lay, 0.85, n_iters=100), lay)
 
 
 def test_ppr_serve_programs_compile(shape):
@@ -100,14 +98,11 @@ def test_ppr_serve_programs_compile(shape):
 
     src, dst = gen.protein_network(2000, seed=0)
     eng = PageRankEngine(src, dst, 2000, backend="ell")
-    ops = jax.tree.map(lambda a: shape(a.shape, a.dtype), eng._operands)
+    lay = jax.tree.map(lambda a: shape(a.shape, a.dtype), eng.prepared)
     n, q, h = 2000, 16, 64
-    kw = dict(backend="sell", mesh=None, axes=(), block=(256, 256),
-              interpret=False)
-    V, dang = shape((n, q)), shape((n,))
+    V = shape((n, q))
     _teleport.lower(shape((q, 4), jnp.int32), shape((q, 4)), n=n).compile()
-    _hub_estimate.lower(ops, dang, None, V, shape((n, h)),
-                        shape((h,), jnp.int32), d=0.85, **kw).compile()
-    _hub_push.lower(ops, dang, None, V, V, np.float32(1e-7), max_pushes=256,
-                    d=0.85, **kw).compile()
+    _hub_estimate.lower(lay, 0.85, V, shape((n, h)),
+                        shape((h,), jnp.int32)).compile()
+    _hub_push.lower(lay, 0.85, 256, V, V, np.float32(1e-7)).compile()
     _rank_batch.lower(V, 1e-3, k=10).compile()
