@@ -36,12 +36,15 @@ the prepared arrays, never a rebuild:
   (:mod:`repro.pagerank.sell`): rows grouped into degree tiers of doubling
   width, each padded to its width plus ``slack``, one device-local SELL
   per shard on ``ell_sharded``.  The sweep is one dense gather per tier
-  and **no** ``segment_sum``, and every affected row is rewritten with one
-  row-scatter per tier; on ``ell_sharded`` the scatter output is pinned to
-  the tier's row ``NamedSharding`` (a ``with_sharding_constraint``), so
-  each write lands on the device owning the row.  The capacity slack means
-  small deltas never change any array shape; a row outgrowing its tier
-  triggers the rebuild fallback.
+  and **no** ``segment_sum``.  The rows hold the structure alone (1 per
+  in-edge) and the layout the column scale ``1/outdeg``
+  (``SellLayout.col_scale``), so a delta rewrites only its edges'
+  destination rows, with one row-scatter per tier, and the changed
+  columns' entries of the scale; on ``ell_sharded`` the scatter output is
+  pinned to the tier's row ``NamedSharding`` (a
+  ``with_sharding_constraint``), so each write lands on the device owning
+  the row.  The capacity slack means small deltas never change any array
+  shape; a row outgrowing its tier triggers the rebuild fallback.
 * **dense_sharded** — the changed columns are scattered under the 2-D
   fabric ``P(row, col)`` sharding, so each write lands on the mesh column
   that owns it; the padded tail rows/columns stay zero.
@@ -64,7 +67,8 @@ policy picks the same strategies sharded as it does single-device.
 
 Host-side bookkeeping is a sorted int64 edge-key set (plus its reverse for
 in-neighbor queries) and the degree vectors, so computing affected
-columns/rows for a Δ-edge delta costs ``O(Δ·maxdeg + log E)``, not
+columns/rows for a Δ-edge delta costs ``O(Δ·log E)`` probes (the BSR
+and dense tiers add their changed columns' out-neighbors), not
 ``O(E)``.
 """
 from __future__ import annotations
@@ -80,7 +84,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.graph.delta import GraphDelta, edge_keys
 from repro.obs.trace import SolveTrace
-from repro.pagerank.engine import PageRankEngine, _dedupe_edges, global_push
+from repro.pagerank.engine import (PageRankEngine, _dedupe_edges,
+                                   global_push, inv_outdeg)
 from repro.pagerank.resilience import EngineSnapshot, make_solve_info
 
 __all__ = ["DynamicPageRankEngine", "UpdateInfo"]
@@ -222,16 +227,17 @@ def _scatter_rows(A, pos, rows, *, sharding=None):
 
 
 @jax.jit
-def _scatter_mask(mask, ci, flags):
-    """mask[ci_c] = flags_c for every chunk c; ci, flags (k, cap): the
-    dangling-mask patch of a layout's (rows,) mask."""
-    def body(m, args):
+def _scatter_entries(vec, ci, vals):
+    """vec[ci_c] = vals_c for every chunk c; ci, vals (k, cap): the patch
+    of a layout's (rows,) vector, the dangling mask or the column
+    scale."""
+    def body(v, args):
         i, f = args
-        return m.at[..., i].set(f), None
+        return v.at[..., i].set(f), None
 
     with jax.named_scope("pagerank.row_patch"):
-        mask, _ = jax.lax.scan(body, mask, (ci, flags))
-    return mask
+        vec, _ = jax.lax.scan(body, vec, (ci, vals))
+    return vec
 
 
 @partial(jax.jit, static_argnames=("n", "sharding"))
@@ -273,8 +279,9 @@ class DynamicPageRankEngine(PageRankEngine):
 
     Same constructor, same ``run`` / ``run_tol`` / ``ppr`` surface (the
     ``ell`` and ``ell_sharded`` SELL tiers are built with ``slack`` slots
-    of row headroom; ``bsr`` keeps a host block-structure map for in-block
-    value patches), plus:
+    of row headroom, their rows holding the structure and the layout the
+    column scale, int8 excepted; ``bsr`` keeps a host block-structure map
+    for in-block value patches), plus:
 
     * ``update(delta)`` — fold a :class:`~repro.graph.delta.GraphDelta`
       into the prepared layouts and refresh the ranks; returns
@@ -291,6 +298,8 @@ class DynamicPageRankEngine(PageRankEngine):
     other while spending an order of magnitude less work than a cold 1e-8
     solve.
     """
+
+    _scale_cols = True
 
     def __init__(self, src: np.ndarray, dst: np.ndarray, n: int, *,
                  slack: int = 8, push_max_changed: int = 64,
@@ -424,8 +433,10 @@ class DynamicPageRankEngine(PageRankEngine):
         ``update.plan`` (children ``update.plan.keys`` and
         ``update.plan.rows``) and ``update.commit``, per-strategy
         ``span.update.patch`` (child ``update.patch.rows``, the host row
-        rebuild) / ``span.update.rebuild`` layout timings, and one
-        ``update`` event with the delta size and solve verdict.
+        rebuild) / ``span.update.rebuild`` layout timings, the
+        ``update.rows_patched`` counter (the layout rows each patch
+        rewrote, ``UpdateInfo.rows_patched``), and one ``update`` event
+        with the delta size and solve verdict.
         When capacity overflow forces the auto policy to rebuild where the
         size policy wanted a patch, the coercion is recorded on
         ``UpdateInfo.coerced_from`` plus an ``update.coerced`` counter and
@@ -436,6 +447,7 @@ class DynamicPageRankEngine(PageRankEngine):
             pr, info = self._update(delta, tol=(1.0 - self.d) * tol,
                                     max_iters=max_iters, strategy=strategy)
         self.metrics.counter(f"update.{info.strategy}").inc()
+        self.metrics.counter("update.rows_patched").inc(info.rows_patched)
         if info.coerced_from is not None:
             self.metrics.counter("update.coerced").inc()
             self.metrics.event("update_coerced",
@@ -542,12 +554,13 @@ class DynamicPageRankEngine(PageRankEngine):
 
         Its two host steps are the spans ``update.plan.keys`` (the delta's
         effective edges and the post-delta key sets and degrees) and
-        ``update.plan.rows`` (the rows or blocks each changed column
-        touches, and the capacity test).  ``update.plan.keys`` costs two
-        O(E) copies of each of ``_keys`` and ``_rkeys`` (``_edit_sorted``,
-        in parallel parts: no sort, whatever the delta's size) plus
-        O(delta · log E) ``searchsorted`` probes; the fresh arrays are what
-        lets ``update`` roll back by restoring the old ones."""
+        ``update.plan.rows`` (the SELL rows the changed edges end in, or
+        the blocks each changed column touches, and the capacity test).
+        ``update.plan.keys`` costs two O(E) copies of each of ``_keys``
+        and ``_rkeys`` (``_edit_sorted``, in parallel parts: no sort,
+        whatever the delta's size) plus O(delta · log E) ``searchsorted``
+        probes; the fresh arrays are what lets ``update`` roll back by
+        restoring the old ones."""
         n = self.n
         with self.metrics.span("update.plan.keys"):
             delta = delta.canonical(n, symmetric=self.symmetric)
@@ -575,13 +588,10 @@ class DynamicPageRankEngine(PageRankEngine):
             extra: dict = {}
             if self.backend in ("ell", "ell_sharded"):
                 # only the row-major layouts patch rows (dense tiers
-                # rewrite whole columns, BSR individual block entries), so
-                # only they pay the neighbor scans
-                parts = [changed % n]
-                for u in cols:
-                    parts.append(_key_slice(self._keys, int(u), n))
-                    parts.append(_key_slice(new_keys, int(u), n))
-                rows = np.unique(np.concatenate(parts))
+                # rewrite whole columns, BSR individual block entries); a
+                # changed out-degree lives in the column scale, so the
+                # rows to rewrite are the changed edges' destinations
+                rows = np.unique(changed % n)
                 cap = np.asarray(self._sell.widths)[self._sell.tier[rows]]
                 overflow = bool((indeg[rows] > cap).any())
             elif self.backend == "bsr":
@@ -642,9 +652,9 @@ class DynamicPageRankEngine(PageRankEngine):
         n = self.n
         cols = plan["cols"]
         flags = (self._outdeg[cols] == 0).astype(np.float32)
-        dang = _scatter_mask(self.prepared.dang,
-                             *(jnp.asarray(a)
-                               for a in _stack_chunks(cols, flags, cap=32)))
+        dang = _scatter_entries(self.prepared.dang,
+                                *(jnp.asarray(a) for a in
+                                  _stack_chunks(cols, flags, cap=32)))
         if self.mesh is not None:
             # the sharded tiers keep the dangling mask replicated; pin the
             # patched copy back to P() so no sweep pays a reshard
@@ -674,15 +684,23 @@ class DynamicPageRankEngine(PageRankEngine):
             self.prepared = dataclasses.replace(
                 self.prepared, operands=(H,) + self.operands[1:])
             return 0, len(cols)
-        # ell / ell_sharded: rewrite every affected SELL row in its tier
-        # (vectorized: one gather over the reverse key set builds all rows
-        # at once); on the mesh each scatter stays on its tier's row
-        # sharding, so every write lands on the device owning the row.
-        # Each tier's host rebuild is an ``update.patch.rows`` span; the
-        # scatters it then dispatches run under ``update.patch`` itself.
-        # A tier holding an eighth of the rows or more (the narrow tiers of
-        # a power-law graph) takes 512-row chunks, the others 64, so a
-        # delta patches each tier in few chunks and few chunk counts
+        # ell / ell_sharded: the changed columns' new 1/outdeg go into the
+        # column scale, and every SELL row an edge of the delta ends in is
+        # rewritten in its tier (vectorized: one gather over the reverse
+        # key set builds all rows at once); on the mesh each scatter stays
+        # on its tier's row sharding, so every write lands on the device
+        # owning the row.  Each tier's host rebuild is an
+        # ``update.patch.rows`` span; the scatters it then dispatches run
+        # under ``update.patch`` itself.  A tier holding an eighth of the
+        # rows or more (the narrow tiers of a power-law graph) takes
+        # 512-row chunks, the others 64, so a delta patches each tier in
+        # few chunks and few chunk counts
+        scale = self.prepared.col_scale
+        scale = _scatter_entries(
+            scale, *(jnp.asarray(a) for a in _stack_chunks(
+                cols, inv_outdeg(self._outdeg[cols], scale.dtype), cap=32)))
+        if self.mesh is not None:
+            scale = jax.device_put(scale, NamedSharding(self.mesh, P()))
         rows = plan["rows"]
         inv, tiers = self.operands
         tiers = list(tiers)
@@ -704,7 +722,7 @@ class DynamicPageRankEngine(PageRankEngine):
                                       sharding=sharding),
                         _scatter_rows(i, pos, jnp.asarray(ix),
                                       sharding=sharding))
-        self.prepared = dataclasses.replace(self.prepared,
+        self.prepared = dataclasses.replace(self.prepared, col_scale=scale,
                                             operands=(inv, tuple(tiers)))
         return len(rows), len(cols)
 
@@ -746,9 +764,9 @@ class DynamicPageRankEngine(PageRankEngine):
     def _rebuild_rows(self, sel: np.ndarray, k: int
                       ) -> tuple[np.ndarray, np.ndarray]:
         """Recompute the SELL rows ``sel`` (width ``k``) from the current
-        edge set — no per-row Python loop: one vectorized slice-gather over
-        the sorted reverse keys yields every (row, slot, col, val) at
-        once."""
+        edge set: 1 and the column per in-edge, 0 in the padding — no
+        per-row Python loop: one vectorized slice-gather over the sorted
+        reverse keys yields every (row, slot, col) at once."""
         n = self.n
         sel64 = sel.astype(np.int64)
         lo = np.searchsorted(self._rkeys, sel64 * n)
@@ -762,9 +780,8 @@ class DynamicPageRankEngine(PageRankEngine):
             slot = np.arange(total) - np.repeat(starts, cnt)
             flat = np.repeat(lo, cnt) + slot
             j = np.repeat(np.arange(len(sel)), cnt)
-            u = self._rkeys[flat] % n
-            data[j, slot] = 1.0 / self._outdeg[u]
-            idx[j, slot] = u
+            data[j, slot] = 1.0
+            idx[j, slot] = self._rkeys[flat] % n
         return data, idx
 
     # ------------------------------ push -------------------------------- #

@@ -164,6 +164,15 @@ def _real(v: jax.Array, n: int) -> jax.Array:
     return v if v.shape[0] == n else v[:n]
 
 
+def inv_outdeg(outdeg: np.ndarray, dtype) -> np.ndarray:
+    """``1/outdeg`` per column in ``dtype``, 0 for a dangling column: the
+    float32 quotient the transition builders store, then rounded as a
+    layout rounds its values."""
+    deg = np.asarray(outdeg, np.float32)
+    return np.divide(np.float32(1.0), deg, out=np.zeros_like(deg),
+                     where=deg > 0).astype(dtype)
+
+
 def _stored(H: np.ndarray, precision: str, put, put_scales) -> tuple:
     """A dense H's operands in its storage precision: ``(H,)``, or
     ``(Hq, scales)`` for int8 with its per-row f32 dequantization scales."""
@@ -242,10 +251,26 @@ class Layout:
 @_pytree
 class SellLayout(Layout):
     """The sliced ELLPACK of :mod:`repro.pagerank.sell` (the ``ell``
-    tier): one gather per degree tier."""
+    tier): one gather per degree tier.
+
+    ``col_scale``, where the layout has one, is the (rows,) column scale
+    ``1/outdeg`` in the storage dtype (0 for dangling and pad columns),
+    and the rows hold the structure alone, 1 per edge: the dynamic
+    engine's layout, where a changed out-degree rewrites one entry of the
+    scale instead of every row that holds the column.  ``mv`` scales the
+    iterate once, then gathers it as the valued rows would."""
+    col_scale: jax.Array | None = None
+
+    def scaled(self, X: jax.Array) -> jax.Array:
+        """``X`` with row ``u`` times ``col_scale[u]`` (``X`` itself where
+        the rows hold their values)."""
+        if self.col_scale is None:
+            return X
+        s = upcast_f32(self.col_scale)
+        return X * (s if X.ndim == 1 else s[:, None])
 
     def mv(self, X):
-        return sell.sell_rows(self.operands, X)
+        return sell.sell_rows(self.operands, self.scaled(X))
 
 
 @_pytree
@@ -257,7 +282,8 @@ class ShardedSellLayout(SellLayout):
     axes: tuple = _static()
 
     def mv(self, X):
-        return dist.sell_mv_sharded(self.operands, X, self.mesh, self.axes)
+        return dist.sell_mv_sharded(self.operands, self.scaled(X), self.mesh,
+                                    self.axes)
 
 
 @_pytree
@@ -485,6 +511,10 @@ class PageRankEngine:
     # row headroom of each SELL tier (``ell``, ``ell_sharded``); the
     # dynamic engine reserves more so edge deltas patch rows in place
     _slack = 0
+    # whether those SELL rows hold the structure alone and the layout the
+    # column scale (``SellLayout.col_scale``): the dynamic engine's, so a
+    # changed out-degree patches one entry, not every row of its column
+    _scale_cols = False
 
     def __init__(self, src: np.ndarray, dst: np.ndarray, n: int, *,
                  d: float = 0.85, backend: str = "auto",
@@ -560,21 +590,34 @@ class PageRankEngine:
             n_pad = -(-n // shards) * shards
             # sliced ELL, not one block padded to the maximum degree; on
             # the mesh each device holds a self-contained SELL of its own
-            # rows, so it sweeps them with one gather per degree tier
+            # rows, so it sweeps them with one gather per degree tier.
+            # int8 rows keep their values: a patch would re-quantize them,
+            # so they never patch
+            csr = tr.build_transition_csr(src, dst, n)
+            scaled = self._scale_cols and precision != "int8"
+            if scaled:
+                csr = dataclasses.replace(csr,
+                                          data=np.ones(csr.nnz, np.float32))
             operands, self._sell = sell.build(
-                tr.build_transition_csr(src, dst, n), n_pad, shards=shards,
-                slack=self._slack, precision=precision,
+                csr, n_pad, shards=shards, slack=self._slack,
+                precision=precision,
                 sharding=(None if self.mesh is None
                           else NamedSharding(self.mesh, P(axes))))
             self.layout = self._sell.describe(self._slack)
+            col_scale = (np.pad(inv_outdeg(np.bincount(src, minlength=n),
+                                           self.storage_dtype),
+                                (0, n_pad - n)) if scaled else None)
             if self.mesh is None:
-                lay = SellLayout(operands=operands, dang=jnp.asarray(dang),
-                                 n=n)
+                lay = SellLayout(
+                    operands=operands, dang=jnp.asarray(dang), n=n,
+                    col_scale=(None if col_scale is None
+                               else jnp.asarray(col_scale)))
             else:
+                put = lambda a: jax.device_put(a, replicated)
                 lay = ShardedSellLayout(
                     operands=operands, n=n, mesh=self.mesh, axes=axes,
-                    dang=jax.device_put(np.pad(dang, (0, n_pad - n)),
-                                        replicated))
+                    dang=put(np.pad(dang, (0, n_pad - n))),
+                    col_scale=None if col_scale is None else put(col_scale))
                 self.layout = (f"ell_sharded({self.layout}, "
                                f"shards={shards}, n_pad={n_pad})")
         elif backend == "bsr":

@@ -6,6 +6,8 @@ The load-bearing oracle throughout: an incremental update must match a
 from-scratch engine built on the post-delta edge list (``apply_delta``)
 to ≤1e-5 L1 — the acceptance bound for the whole subsystem.
 """
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ from repro.graph import generators as gen
 from repro.graph.delta import (EdgeStream, GraphDelta, apply_delta, compose,
                                edge_keys)
 from repro.pagerank import DynamicPageRankEngine, PageRankEngine
+from repro.pagerank.engine import inv_outdeg
 
 DYN_BACKENDS = ["dense", "ell", "pallas_dense", "bsr"]  # patchable layouts
 ALL_LOCAL = ["dense", "ell", "bsr", "pallas_dense"]
@@ -589,6 +592,62 @@ def test_plan_leaves_keys_and_inverse_delta_restores_them(net, backend):
     np.testing.assert_array_equal(dyn._keys, keys_copy)
     np.testing.assert_array_equal(dyn._rkeys, rkeys_copy)
     assert _l1(pr, _scratch_ranks(src, dst, n)) <= 1e-5
+
+
+def _assert_col_scale_follows_the_edges(dyn):
+    """The SELL's column scale is ``1/outdeg`` of the engine's current
+    edges in its storage dtype, 0 on dangling and pad columns."""
+    lay = dyn.prepared
+    want = np.zeros(lay.rows, dyn.storage_dtype)
+    want[:dyn.n] = inv_outdeg(dyn._outdeg, dyn.storage_dtype)
+    assert lay.col_scale.dtype == dyn.storage_dtype
+    np.testing.assert_array_equal(np.asarray(lay.col_scale), want)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("path", ["inverse", "restore", "rebuild_and_solve",
+                                  "rebuild"])
+def test_col_scale_follows_the_edges(net, path, precision):
+    """A patched delta writes its columns' new ``1/outdeg``; its inverse
+    writes back the old bits; a snapshot's restore, the recovery rebuild
+    (over a corrupted scale) and the rebuild strategy all remake the scale
+    from the edges, and the ranks follow."""
+    n, src, dst = net
+    dyn = DynamicPageRankEngine(src, dst, n, backend="ell",
+                                precision=precision)
+    dyn.run_tol(1e-7, max_iters=500)
+    scale0 = np.asarray(dyn.prepared.col_scale)
+    snap = dyn.snapshot()
+    iu, iv = _absent_pairs(src, dst, n, 3, seed=7)
+    delta = GraphDelta(iu, iv, np.asarray(src[:2]), np.asarray(dst[:2]))
+    _, info = dyn.update(delta, tol=1e-7)
+    assert info.strategy == "push"
+    _assert_col_scale_follows_the_edges(dyn)
+    assert not np.array_equal(np.asarray(dyn.prepared.col_scale), scale0)
+    if path == "inverse":
+        dyn.update(GraphDelta(delta.delete_src, delta.delete_dst,
+                              delta.insert_src, delta.insert_dst), tol=1e-7)
+        np.testing.assert_array_equal(np.asarray(dyn.prepared.col_scale),
+                                      scale0)
+    elif path == "restore":
+        dyn.restore(snap)
+        np.testing.assert_array_equal(np.asarray(dyn.prepared.col_scale),
+                                      scale0)
+    elif path == "rebuild_and_solve":
+        dyn.prepared = dataclasses.replace(
+            dyn.prepared, col_scale=jnp.zeros_like(dyn.prepared.col_scale))
+        dyn.rebuild_and_solve(tol=1e-7)
+    else:
+        iu, iv = _absent_pairs(src, dst, n, 2, seed=8)
+        _, info = dyn.update(GraphDelta.inserts(iu, iv), tol=1e-7,
+                             strategy="rebuild")
+        assert info.strategy == "rebuild" and info.rows_patched == 0
+    _assert_col_scale_follows_the_edges(dyn)
+    keys = dyn._keys
+    s1, d1 = (keys // n).astype(np.int32), (keys % n).astype(np.int32)
+    same = PageRankEngine(s1, d1, n, backend="ell", precision=precision)
+    pr = dyn.run_tol(1e-7, max_iters=500)[0]
+    assert _l1(pr, same.run_tol(1e-7, max_iters=500)[0]) <= 1e-5
 
 
 # --------------------------------------------------------------------------- #

@@ -106,3 +106,38 @@ def test_ppr_serve_programs_compile(shape):
                         shape((h,), jnp.int32)).compile()
     _hub_push.lower(lay, 0.85, 256, V, V, np.float32(1e-7)).compile()
     _rank_batch.lower(V, 1e-3, k=10).compile()
+
+
+def test_scaled_sell_push_gathers_only_the_iterate(shape):
+    """The dynamic SELL's refresh push applies its column scale once per
+    matvec: compiled for a v5e, every fused gather reads one (N,) float
+    vector, the scaled iterate, and the program has as many fusions as the
+    same push over rows that hold their values (no scale)."""
+    import dataclasses
+    import re
+
+    import numpy as np
+
+    from repro.graph import generators as gen
+    from repro.obs.registry import NullRegistry
+    from repro.pagerank import DynamicPageRankEngine
+    from repro.pagerank.engine import global_push
+
+    src, dst = gen.barabasi_albert(N, 8, seed=0)
+    dyn = DynamicPageRankEngine(src, dst, N, backend="ell",
+                                metrics=NullRegistry())
+    scaled = jax.tree.map(lambda a: shape(a.shape, a.dtype), dyn.prepared)
+    assert scaled.col_scale is not None
+
+    def fusions(lay):
+        txt = global_push.lower(lay, 0.85, np.float32(1e-6), shape((N,)),
+                                max_pushes=100).compile().as_text()
+        gathers = [re.findall(r"= (f32\[[\d,]*\])\S* parameter\(", c)
+                   for c in txt.split("\n\n") if " gather(" in c]
+        return txt.count(" fusion("), gathers
+
+    n_scaled, gathers = fusions(scaled)
+    n_valued, valued = fusions(dataclasses.replace(scaled, col_scale=None))
+    assert n_scaled == n_valued
+    assert len(gathers) == len(valued) >= len(dyn._sell.widths)
+    assert all(g == [f"f32[{N}]"] for g in gathers)
